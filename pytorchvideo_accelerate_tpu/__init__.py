@@ -21,7 +21,7 @@ functions, and sharding declared on a ``jax.sharding.Mesh``:
   (``parallel.distributed``, ``launch.py``)
 """
 
-__version__ = "0.4.0"  # keep in sync with pyproject.toml
+__version__ = "0.5.0"  # keep in sync with pyproject.toml
 
 from pytorchvideo_accelerate_tpu.config import (  # noqa: F401
     CheckpointConfig,

@@ -74,8 +74,8 @@ def _prod(xs) -> int:
 # Pallas kernel would silently undercount `mfu_analytic` — exactly the
 # lying-numerator failure this pass exists to prevent. Every in-tree
 # kernel therefore registers a per-kernel FLOPs hook here, keyed by the
-# kernel FUNCTION name (eqn.params["name_and_src_info"].name), computing
-# from the eqn's avals; a pallas_call with no hook becomes a finding in
+# kernel FUNCTION name (the kernel jaxpr's debug_info.func_name, or the
+# `name=` given to pallas_call), computing from the eqn's avals; a pallas_call with no hook becomes a finding in
 # `check_flops` (and `--selftest` seeds one to prove the detector works).
 
 PALLAS_FLOPS_HOOKS: Dict[str, Callable[[Any], float]] = {}
@@ -89,9 +89,8 @@ def register_pallas_flops(kernel_name: str,
 
 
 def pallas_kernel_name(eqn) -> str:
-    nsi = eqn.params.get("name_and_src_info")
-    return (getattr(nsi, "name", None) or eqn.params.get("name")
-            or "<unknown>")
+    return (eqn.params.get("name")
+            or eqn.params["jaxpr"].debug_info.func_name)
 
 
 def _pw_kernel_flops(eqn) -> float:

@@ -15,8 +15,8 @@ at every `log_every` drain and epoch end, and surfaces the total in its
 perf dict as `train_recompiles` — which bench.py carries on the
 headline line and asserts == 0 in `--smoke`.
 
-`_cache_size` is a private-but-stable jax API (0.4.x); if a future jax
-drops it the guard degrades to inert (reports None) rather than lying
+`_cache_size` is a private-but-stable jax API (present in 0.9.0); if a
+future jax drops it the guard degrades to inert (reports None) rather than lying
 with a zero, and the static rule keeps standing watch.
 """
 

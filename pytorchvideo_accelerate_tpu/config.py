@@ -583,9 +583,6 @@ class TrainConfig:
     # can't complete — a wedged PJRT client-create otherwise hangs the job
     # forever with no error (utils/device_doctor.py; SURVEY §5). 0 = off.
     device_init_timeout: int = 0
-    # persistent XLA compilation cache dir ("" = off): pays the 1-2 min
-    # model compile once per config instead of once per restart
-    compilation_cache_dir: str = ""
     profile: bool = False  # jax.profiler trace of a step window (SURVEY §5)
     profile_dir: str = "/tmp/pva_tpu_profile"
     debug_nans: bool = False  # jax.config debug_nans (SURVEY §5 sanitizers)

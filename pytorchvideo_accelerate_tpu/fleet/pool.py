@@ -438,15 +438,14 @@ def spawn_serving_process(artifact: str, *, port: int = 0,
     `(subprocess.Popen, HttpReplica)` once it reports its bound address.
 
     `n_devices` forces a CPU slice via `utils/forcehost.py` — the CI path
-    for exercising process replicas on one host. The CALLER owns the
-    process (terminate/kill + reap); the pool only routes around it."""
-    import os
-
+    for exercising process replicas on one host. Without it the child
+    inherits the environment as it is and takes whatever backend JAX
+    finds there (its first stdout line and `/healthz` say which). The
+    CALLER owns the process (terminate/kill + reap); the pool only routes
+    around it."""
     from pytorchvideo_accelerate_tpu.utils.forcehost import forced_host_env
 
-    env = (forced_host_env(n_devices) if n_devices
-           else {**os.environ, "JAX_PLATFORMS":
-                 os.environ.get("JAX_PLATFORMS", "cpu")})
+    env = forced_host_env(n_devices) if n_devices else None
     cmd = [sys.executable, "-m", "pytorchvideo_accelerate_tpu.serving.server",
            "--serve.checkpoint", artifact, "--serve.port", str(port),
            *extra_args]

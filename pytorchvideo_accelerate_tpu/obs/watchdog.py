@@ -1,6 +1,6 @@
 """Heartbeat hang watchdog: dump evidence BEFORE the external kill.
 
-The failure mode this exists for (PROBES_r05.md, tier-1's 870s cap): a
+The failure mode this exists for (tier-1's 870s cap is one instance): a
 wedged PJRT handshake, a stuck H2D copy, or a deadlocked queue leaves the
 process silently idle until an external `timeout -k` kills it blind — no
 stack, no timeline, nothing to diagnose. Each asynchronous component (train

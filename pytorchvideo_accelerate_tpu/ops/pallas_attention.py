@@ -42,11 +42,6 @@ from pytorchvideo_accelerate_tpu.precision import f32_island
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the pinned jax 0.4.37 ships this as TPUCompilerParams; newer jax
-# renamed it CompilerParams — accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 LANES = 128  # broadcast width for per-row stats (min f32 lane tile)
 
@@ -178,7 +173,7 @@ def _fwd_call(q, k, v, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -232,7 +227,7 @@ def _flash_bhnd_bwd(scale, block_q, block_k, interpret, res, dout):
         in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -257,7 +252,7 @@ def _flash_bhnd_bwd(scale, block_q, block_k, interpret, res, dout):
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

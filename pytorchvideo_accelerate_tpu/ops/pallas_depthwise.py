@@ -21,7 +21,12 @@ tile is written once.
 Honest bandwidth accounting: the wrapper pre-pads the input with
 `jnp.pad` (pallas_call is opaque to XLA, so the padded tensor
 materializes in HBM — one extra read+write of x per call, ~2x on top of
-the kernel's own traffic). Net: ~3.5x input reads vs the shift path's
+the kernel's own traffic). The same pad also rounds W (halo included) up
+to the dtype's sublane tile and C up to the 128 lanes: the v5e compiler
+takes the window DMA's HBM slice only when its two minor dims are whole
+tiles, and no X3D width (54/108/216/432) is one — at C=54 the padded
+tensor is 2.4x the bytes (docs/KERNELS.md § what the v5e compiler
+accepts). Net at C a multiple of 128: ~3.5x input reads vs the shift path's
 up-to-27x if XLA's tap fusion re-reads per tap — still the bandwidth
 favorite on paper, but the pad copy is why this is an A/B candidate and
 not a default. In-kernel clamped DMA windows would remove the copy at
@@ -54,13 +59,14 @@ from pytorchvideo_accelerate_tpu.precision import f32_island
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128  # minor-dim tile of every TPU memory layout
 
 def _dw_kernel(x_hbm, k_ref, o_ref, win_ref, sem, *,
                tb: int, hb: int, ow: int, kt: int, kh: int, kw: int):
     b = pl.program_id(0)
     ti = pl.program_id(1)
     hi = pl.program_id(2)
-    # one DMA: the output tile's input window incl. halo (full W, full C)
+    # one DMA: the output tile's input window incl. halo (padded W and C)
     dma = pltpu.make_async_copy(
         x_hbm.at[b, pl.ds(ti * tb, tb + kt - 1),
                  pl.ds(hi * hb, hb + kh - 1)],
@@ -68,11 +74,12 @@ def _dw_kernel(x_hbm, k_ref, o_ref, win_ref, sem, *,
     dma.start()
     dma.wait()
 
+    c = o_ref.shape[-1]  # the window's C is padded to the lane tile
     acc = jnp.zeros(o_ref.shape[1:], jnp.float32)  # (tb, hb, ow, C)
     for dt in range(kt):
         for dh in range(kh):
             for dw in range(kw):
-                tap = win_ref[dt:dt + tb, dh:dh + hb, dw:dw + ow, :]
+                tap = win_ref[dt:dt + tb, dh:dh + hb, dw:dw + ow, :c]
                 acc += f32_island(tap) * f32_island(k_ref[
                     (dt * kh + dh) * kw + dw])
     o_ref[0] = acc.astype(o_ref.dtype)
@@ -80,10 +87,11 @@ def _dw_kernel(x_hbm, k_ref, o_ref, win_ref, sem, *,
 
 def _dw_call(xp, kernel, dims, out_t: int, out_h: int, out_w: int,
              tb: int, hb: int, interpret: bool):
-    """xp: pre-padded (B, Tp, Hp, Wp, C) with Tp >= n_t*tb + kt - 1 and
-    Hp >= n_h*hb + kh - 1 (caller guarantees); kernel (kt*kh*kw, C)."""
-    B, _, _, wp, c = xp.shape
-    taps, _ = kernel.shape
+    """xp: `_pad_for_tiles` output (B, Tp, Hp, Wp, Cp) with Tp >= n_t*tb +
+    kt - 1 and Hp >= n_h*hb + kh - 1; kernel (kt*kh*kw, C) at the true C,
+    which is also the output's."""
+    B = xp.shape[0]
+    taps, c = kernel.shape
     kt, kh, kw = dims
     n_t = -(-out_t // tb)
     n_h = -(-out_h // hb)
@@ -100,7 +108,7 @@ def _dw_call(xp, kernel, dims, out_t: int, out_h: int, out_w: int,
         out_specs=pl.BlockSpec((1, tb, hb, out_w, c),
                                lambda b, ti, hi: (b, ti, hi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((tb + kt - 1, hb + kh - 1, wp, c), xp.dtype),
+            pltpu.VMEM((tb + kt - 1, hb + kh - 1, *xp.shape[3:]), xp.dtype),
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=interpret,
@@ -109,17 +117,20 @@ def _dw_call(xp, kernel, dims, out_t: int, out_h: int, out_w: int,
 
 def _pad_for_tiles(x, kt, kh, kw, tb, hb):
     """SAME-pad plus tail padding so every (tb, hb) output tile's input
-    window exists in the array."""
+    window exists in the array, with the two minor dims rounded up to whole
+    (sublane, lane) tiles — W+halo to 8 rows of 32 bits (16 for bf16), C to
+    128 — which is what Mosaic asks of the slice the window DMA reads."""
     b, t, h, w, c = x.shape
     n_t = -(-t // tb)
     n_h = -(-h // hb)
     pt, ph, pw = kt // 2, kh // 2, kw // 2
+    sublanes = 8 * (4 // jnp.dtype(x.dtype).itemsize)
     return jnp.pad(x, (
         (0, 0),
         (pt, pt + (n_t * tb - t)),
         (ph, ph + (n_h * hb - h)),
-        (pw, pw),
-        (0, 0),
+        (pw, pw + (-(w + 2 * pw)) % sublanes),
+        (0, (-c) % LANES),
     ))
 
 

@@ -17,7 +17,9 @@ graph, byte for byte):
   act. The halo-tile lowering of ops/pallas_depthwise.py generalized to
   channel-mixing convs: the grid tiles the OUTPUT over (batch, t, h),
   each program DMAs ONE overlapping input window (tile + (k-1)-halo,
-  full W and Cin) HBM->VMEM, then runs the kt*kh*kw taps as MXU
+  W and Cin padded to whole (sublane, lane) tiles by `_pad_for_tiles`,
+  which is what the v5e compiler asks of the DMA's slice) HBM->VMEM,
+  then runs the kt*kh*kw taps as MXU
   matmuls against a single f32 VMEM accumulator — input crosses
   HBM->VMEM once per tile, the output is written once, already
   normalized and activated.
@@ -180,6 +182,12 @@ _pw_pallas.defvjp(_pw_fwd, _pw_bwd)
 # --- dense small-kernel stride-1 SAME conv + epilogue -----------------------
 
 
+# v5e's default scoped-VMEM limit is 16 MiB of the core's 128 MiB; the
+# f32 dx pass of slowfast_r50's slow res4 conv_a (3,1,1) 256->1024 needs
+# 18.8 MiB (weights + output tile double-buffered, plus the accumulator)
+_CONV_VMEM_LIMIT = 64 * 2 ** 20
+
+
 def _conv_bn_act_kernel(x_hbm, w_ref, b_ref, o_ref, win_ref, sem, *,
                         tb: int, hb: int, ow: int,
                         kt: int, kh: int, kw: int, act: str):
@@ -194,14 +202,14 @@ def _conv_bn_act_kernel(x_hbm, w_ref, b_ref, o_ref, win_ref, sem, *,
     dma.start()
     dma.wait()
 
-    cin = win_ref.shape[-1]
+    cin = w_ref.shape[1]  # the window's C is padded to the lane tile
     cout = o_ref.shape[-1]
     rows = tb * hb * ow
     acc = jnp.zeros((rows, cout), jnp.float32)
     for dt in range(kt):
         for dh in range(kh):
             for dw in range(kw):
-                tap = win_ref[dt:dt + tb, dh:dh + hb, dw:dw + ow, :]
+                tap = win_ref[dt:dt + tb, dh:dh + hb, dw:dw + ow, :cin]
                 acc += jnp.dot(tap.reshape(rows, cin),
                                w_ref[(dt * kh + dh) * kw + dw],
                                preferred_element_type=jnp.float32)
@@ -214,7 +222,6 @@ def _conv_call(x, wf, b2d, act: str, interpret: bool):
     b, t, h, w, _ = x.shape
     tb, hb = _tile_sizes(t, h)
     xp = _pad_for_tiles(x, kt, kh, kw, tb, hb)
-    wp = xp.shape[3]
     n_t = -(-t // tb)
     n_h = -(-h // hb)
     wflat = wf.reshape(kt * kh * kw, cin, cout)
@@ -232,9 +239,12 @@ def _conv_call(x, wf, b2d, act: str, interpret: bool):
         out_specs=pl.BlockSpec((1, tb, hb, w, cout),
                                lambda bi, ti, hi: (bi, ti, hi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((tb + kt - 1, hb + kh - 1, wp, cin), xp.dtype),
+            pltpu.VMEM((tb + kt - 1, hb + kh - 1, *xp.shape[3:]),
+                       xp.dtype),
             pltpu.SemaphoreType.DMA(()),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_CONV_VMEM_LIMIT),
         interpret=interpret,
     )(xp, wflat, b2d)
 
@@ -296,11 +306,12 @@ def _dw_bn_act_kernel(x_hbm, k_ref, b_ref, o_ref, win_ref, sem, *,
     dma.start()
     dma.wait()
 
+    c = o_ref.shape[-1]  # the window's C is padded to the lane tile
     acc = jnp.zeros(o_ref.shape[1:], jnp.float32)  # (tb, hb, ow, C)
     for dt in range(kt):
         for dh in range(kh):
             for dw in range(kw):
-                tap = win_ref[dt:dt + tb, dh:dh + hb, dw:dw + ow, :]
+                tap = win_ref[dt:dt + tb, dh:dh + hb, dw:dw + ow, :c]
                 acc += f32_island(tap) * f32_island(
                     k_ref[(dt * kh + dh) * kw + dw])
     acc = apply_act(acc + f32_island(b_ref[0]), act)
@@ -312,7 +323,6 @@ def _dw_call(x, kf, b2d, act: str, interpret: bool):
     b, t, h, w, _ = x.shape
     tb, hb = _tile_sizes(t, h)
     xp = _pad_for_tiles(x, kt, kh, kw, tb, hb)
-    wp = xp.shape[3]
     n_t = -(-t // tb)
     n_h = -(-h // hb)
     kflat = kf.reshape(kt * kh * kw, c)
@@ -329,7 +339,8 @@ def _dw_call(x, kf, b2d, act: str, interpret: bool):
         out_specs=pl.BlockSpec((1, tb, hb, w, c),
                                lambda bi, ti, hi: (bi, ti, hi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((tb + kt - 1, hb + kh - 1, wp, c), xp.dtype),
+            pltpu.VMEM((tb + kt - 1, hb + kh - 1, *xp.shape[3:]),
+                       xp.dtype),
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=interpret,

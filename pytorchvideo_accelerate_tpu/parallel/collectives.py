@@ -26,32 +26,6 @@ import numpy as np
 from jax import lax
 
 
-def axis_size(axis_name) -> int:
-    """`lax.axis_size` with a pinned-jax (0.4.x) fallback: `psum(1, axis)`
-    is the classic static axis-size idiom (folds to a trace-time constant —
-    no runtime collective)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: `jax.shard_map(..., check_vma=)` is the
-    current API, but the pinned jax (0.4.x) only ships
-    `jax.experimental.shard_map.shard_map(..., check_rep=)` — and its
-    deprecation shim raises AttributeError rather than forwarding.
-    Replication checking stays off either way: the context-parallel kernels
-    do their own masking, and the check rejects the padded-K path (see the
-    all_gather note above)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def psum(x: Any, axis_name) -> Any:
     return lax.psum(x, axis_name)
 
@@ -75,7 +49,7 @@ def all_gather(x: Any, axis_name, axis: int = 0, tiled: bool = True) -> Any:
 
 def ppermute_ring(x: Any, axis_name, shift: int = 1) -> Any:
     """Rotate values around the mesh axis ring (ring-attention building block)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

@@ -65,7 +65,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pytorchvideo_accelerate_tpu.parallel.collectives import shard_map
 from pytorchvideo_accelerate_tpu.parallel.mesh import batch_axes, model_axis
 
 
@@ -365,8 +364,8 @@ def pipeline_blocks(block_fn: Callable[[Any, Any], Any],
         # carries the stage dim explicitly (zeros elsewhere)
         return out.reshape((1,) + xl.shape)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(tiled_specs, x_spec),
-                   out_specs=out_spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(tiled_specs, x_spec),
+                       out_specs=out_spec, check_vma=False)
     # reduce the stage dim instead of slicing it: non-last stages are
     # zeros, so the sum IS stage P-1's value, and a reduce over a sharded
     # dim lowers to the local reduce + all-reduce that hands every model

@@ -42,10 +42,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorchvideo_accelerate_tpu.parallel.collectives import (
-    axis_size,
-    shard_map as _shard_map,
-)
 from pytorchvideo_accelerate_tpu.parallel.mesh import (
     AXIS_CONTEXT,
     batch_axes,
@@ -90,7 +86,7 @@ def ring_attention(q, k, v, axis_name: str = AXIS_CONTEXT,
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    steps = axis_size(axis_name)
+    steps = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     blk = k.shape[1]
 
@@ -158,10 +154,13 @@ def make_cp_attention(mesh: Mesh, local_fn,
     def build(batch_divisible: bool, nk_valid: int, nk_padded: int):
         spec = P(daxes if batch_divisible else None, axis_name, None, None)
         mask = None if nk_valid == nk_padded else nk_valid
-        return _shard_map(
+        # replication checking off: the CP kernels do their own masking,
+        # and the check rejects the padded-K path
+        return jax.shard_map(
             lambda q, k, v: local_fn(q, k, v, axis_name=axis_name,
                                      nk_valid=mask),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
         )
 
     def attn(q, k, v):

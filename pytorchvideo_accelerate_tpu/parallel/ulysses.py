@@ -30,7 +30,6 @@ from jax import lax
 from jax.sharding import Mesh
 
 from pytorchvideo_accelerate_tpu.ops.attention import fused_attention
-from pytorchvideo_accelerate_tpu.parallel.collectives import axis_size
 from pytorchvideo_accelerate_tpu.parallel.mesh import AXIS_CONTEXT, mesh_memo
 
 
@@ -40,7 +39,7 @@ def ulysses_attention(q, k, v, axis_name: str = AXIS_CONTEXT,
     """All-to-all attention. Must run inside `shard_map` with `axis_name`
     bound; q/k/v are local token shards (B, N/cp, H, D). `nk_valid`: global
     count of real (unpadded) keys. Falls back to ring when H % cp != 0."""
-    cp = axis_size(axis_name)
+    cp = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % cp != 0:
         from pytorchvideo_accelerate_tpu.parallel.ring_attention import ring_attention

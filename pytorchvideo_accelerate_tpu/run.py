@@ -24,6 +24,7 @@ from typing import Optional, Sequence
 
 from pytorchvideo_accelerate_tpu.config import parse_cli
 from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
+from pytorchvideo_accelerate_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main(argv: Optional[Sequence[str]] = None) -> dict:
@@ -59,6 +60,7 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
         print(f"wrote resolved config to {write_to} "
               f"(reuse with --config {write_to})")
         return {"config_written": write_to}
+    enable_compile_cache()
     trainer = Trainer(cfg)
     if cfg.export_inference:
         # checkpoint -> serving artifact, no training: resume (when

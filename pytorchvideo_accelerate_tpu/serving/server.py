@@ -61,6 +61,7 @@ from pytorchvideo_accelerate_tpu.serving.admission import (
 from pytorchvideo_accelerate_tpu.serving.batcher import MicroBatcher, QueueFullError
 from pytorchvideo_accelerate_tpu.serving.engine import CLIP_KEYS, InferenceEngine
 from pytorchvideo_accelerate_tpu.serving.stats import ServingStats
+from pytorchvideo_accelerate_tpu.utils.hw import device_summary
 from pytorchvideo_accelerate_tpu.utils.logging import get_logger
 
 logger = get_logger("pva_tpu")
@@ -114,7 +115,10 @@ class _Handler(BaseHTTPRequestHandler):
                 "num_classes": eng.num_classes,
                 "input_dtype": eng.input_dtype,
                 "buckets": list(eng.buckets),
-                "platform": srv.platform,
+                # what JAX gave this process (utils/hw.device_summary)
+                "platform": srv.device["platform"],
+                "device_kind": srv.device["kind"],
+                "device_count": srv.device["count"],
                 "queue_depth": srv.batcher.queue_depth(),
                 # streaming capability: whether /stream serves sessions
                 # here (routers/load balancers may key affinity on it)
@@ -468,8 +472,6 @@ class InferenceServer:
                  expected_spec: Optional[dict] = None,
                  watchdog=None, admission: Optional[AdmissionController] = None,
                  drain_grace_s: float = 10.0):
-        import jax
-
         self.engine = engine
         self.batcher = batcher
         self.stats = stats
@@ -487,7 +489,8 @@ class InferenceServer:
         # clip-name -> (1, T, H, W, C) from the artifact's config (None =
         # accept any geometry; direct/bench construction)
         self.expected_spec = expected_spec
-        self.platform = jax.devices()[0].platform
+        self.device = device_summary()
+        logger.info("device: %s", json.dumps(self.device))
         self.httpd = ThreadingHTTPServer((host, port), _Handler)
         self.httpd.daemon_threads = True
         self.httpd.owner = self  # handler back-reference
@@ -789,15 +792,19 @@ def build_server(cfg) -> InferenceServer:
 def main(argv: Optional[Sequence[str]] = None) -> None:
     """`pva-tpu-serve --serve.checkpoint PATH [--serve.port N ...]`."""
     from pytorchvideo_accelerate_tpu.config import parse_cli
+    from pytorchvideo_accelerate_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
 
     cfg = parse_cli(argv)
+    enable_compile_cache()
     server = build_server(cfg)
     host, port = server.address
     logger.info("serving %s on http://%s:%d (/predict /healthz /stats)",
                 server.engine.model_name, host, port)
     print(f"pva-tpu-serve: http://{host}:{port}  model="
-          f"{server.engine.model_name} buckets={server.engine.buckets}",
-          flush=True)
+          f"{server.engine.model_name} buckets={server.engine.buckets} "
+          f"device={json.dumps(server.device)}", flush=True)
     server.serve_forever()
 
 

@@ -339,10 +339,11 @@ class Checkpointer:
         # (.copy() preserves sharding). Orbax hands back arrays backed by
         # tensorstore-owned host memory; with the persistent compilation
         # cache enabled, donating those into the deserialized train step
-        # corrupts the heap in the pinned jaxlib ("corrupted double-linked
+        # corrupted the heap on jaxlib 0.4.37 ("corrupted double-linked
         # list" / segfault a step or two after resume — reproduced by
         # pva-tpu-chaos's preempt leg, which resumes mid-epoch and trains).
-        # One whole-state copy at resume time is noise next to restore IO.
+        # Not re-verified on jaxlib 0.9.0, so the copy stays. One
+        # whole-state copy at resume time is noise next to restore IO.
         state = jax.tree.map(
             lambda a: a.copy() if isinstance(a, jax.Array) else a,
             restored["state"],

@@ -9,6 +9,7 @@ the pure-step runtime: mesh + sharded batches + compiled steps + orbax.
 
 from __future__ import annotations
 
+import json
 import os
 import time
 from contextlib import nullcontext
@@ -85,6 +86,7 @@ from pytorchvideo_accelerate_tpu.trainer.tracking import (
 )
 from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
 from pytorchvideo_accelerate_tpu.utils.bench_setup import fetch_loss
+from pytorchvideo_accelerate_tpu.utils.hw import device_summary
 from pytorchvideo_accelerate_tpu.utils.logging import get_logger
 from pytorchvideo_accelerate_tpu.utils.rng import RngManager, set_seed
 
@@ -169,10 +171,12 @@ class Trainer:
             jax.config.update("jax_platforms", "cpu")
         if cfg.device_init_timeout > 0 and not cfg.cpu:
             # fail loudly instead of wedging: device backend init can hang
-            # forever (observed: PJRT client-create never returning while
-            # the process shows no error — PROBES_r05.md). Probe in a
-            # disposable subprocess first; raises with the diagnosis
-            # recipe if init can't complete in time. (SURVEY §5 failure
+            # forever (a PJRT client-create that never returns while the
+            # process shows no error, e.g. while another process holds
+            # the chip). Probe in a disposable subprocess first — before
+            # this process touches JAX, since the chip belongs to one
+            # process at a time; raises with the diagnosis recipe if init
+            # can't complete in time. (SURVEY §5 failure
             # detection — the reference's torch/NCCL stack fails loudly
             # on a bad device; jax would just sit there.)
             from pytorchvideo_accelerate_tpu.utils import device_doctor
@@ -181,21 +185,6 @@ class Trainer:
                 cfg.device_init_timeout, log=logger.info)
         if cfg.debug_nans:
             jax.config.update("jax_debug_nans", True)
-        if cfg.compilation_cache_dir:
-            try:
-                jax.config.update("jax_compilation_cache_dir",
-                                  cfg.compilation_cache_dir)
-            except Exception:  # flag availability varies by jax version
-                logger.warning("persistent compilation cache unavailable "
-                               "(jax_compilation_cache_dir rejected)")
-            else:
-                try:  # threshold flag is best-effort on top of the cache
-                    jax.config.update(
-                        "jax_persistent_cache_min_compile_time_secs", 1.0)
-                except Exception:
-                    logger.warning(
-                        "compilation cache active, but min-compile-time "
-                        "threshold flag unavailable (using jax defaults)")
 
         initialize_distributed(
             cfg.coordinator_address, cfg.num_processes, cfg.process_id
@@ -240,6 +229,7 @@ class Trainer:
                 if pipelined
                 else "context-parallel" if cp_spends_model_axis
                 else "tensor-parallel" if self._tp else "replicated")
+        main_print(f"device: {json.dumps(device_summary())}")
         main_print(
             f"mesh: {dict(self.mesh.shape)} over {self.mesh.size} "
             f"{jax.devices()[0].platform} devices, "

@@ -118,14 +118,13 @@ def time_variant(model_name: str, overrides: dict, wl: dict, smoke: bool,
 def child_main(args):
     import jax
 
+    from pytorchvideo_accelerate_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
     if args.smoke:
         jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(ROOT, ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    enable_compile_cache()
     spec = json.loads(args.child)
     res = time_variant(spec["model"], spec["overrides"], spec["workload"],
                        args.smoke, args.steps, args.warmup)

@@ -18,13 +18,17 @@ def test_env_snapshot_filters_device_vars(monkeypatch):
     assert "UNRELATED_VAR" not in snap
 
 
-def test_loopback_listeners_shape():
-    out = dd.loopback_listeners()
-    assert isinstance(out, list)
-    for rec in out:
-        assert "port" in rec or "error" in rec
-        if "port" in rec:
-            assert "connect" in rec and "connect_ms" in rec
+def test_probe_landing_on_cpu_unasked_is_refused(monkeypatch):
+    """No hidden CPU: a probe that came back from the CPU backend is a
+    failure unless JAX_PLATFORMS=cpu asked for the CPU."""
+    monkeypatch.setattr(dd, "quick_probe",
+                        lambda t: {"ok": True, "elapsed_s": 1.0,
+                                   "stdout": "cpu cpu"})
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="landed on the CPU"):
+        dd.assert_device_reachable(30, log=lambda m: None)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert dd.assert_device_reachable(30, log=lambda m: None)["ok"] is True
 
 
 def test_attempt_captures_output_on_success(tmp_path):
@@ -102,4 +106,4 @@ def test_cli_skip_init_exits_zero(capsys):
 
     rec = json.loads(capsys.readouterr().out)
     assert rec["probe"] == "diagnostics"
-    assert "env" in rec and "loopback_listeners" in rec
+    assert "env" in rec and "files" in rec

@@ -85,21 +85,22 @@ def test_psum_over_mesh(mesh8):
 
 
 def test_in_graph_collective_facade(mesh8):
-    """psum/all_gather wrappers under shard_map (via the version-compat
-    collectives.shard_map, which disables replication checking — the
-    documented pattern for returning a replicated gather)."""
+    """psum/all_gather wrappers under shard_map with replication checking
+    off — the documented pattern for returning a replicated gather."""
     from jax.sharding import PartitionSpec as P
 
     from pytorchvideo_accelerate_tpu.parallel.collectives import (
-        all_gather, psum, shard_map,
+        all_gather, psum,
     )
 
-    f = shard_map(lambda x: psum(x, ("data", "fsdp")), mesh=mesh8,
-                  in_specs=P(("data", "fsdp")), out_specs=P())
+    f = jax.shard_map(lambda x: psum(x, ("data", "fsdp")), mesh=mesh8,
+                      in_specs=P(("data", "fsdp")), out_specs=P(),
+                      check_vma=False)
     np.testing.assert_allclose(np.asarray(f(jnp.ones(8))), [8.0])
 
-    g = shard_map(lambda x: all_gather(x, "data"), mesh=mesh8,
-                  in_specs=P("data"), out_specs=P(None, "fsdp"))
+    g = jax.shard_map(lambda x: all_gather(x, "data"), mesh=mesh8,
+                      in_specs=P("data"), out_specs=P(None, "fsdp"),
+                      check_vma=False)
     out = g(jnp.arange(16.0).reshape(8, 2))
     assert out.shape == (8, 2)
     np.testing.assert_allclose(np.asarray(out),

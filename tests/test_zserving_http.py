@@ -71,6 +71,10 @@ def test_healthz_predict_stats_round_trip(server):
     # (serving/admission.py): healthy | degraded | draining
     assert health["status"] == "healthy" and health["model"] == "tiny3d"
     assert health["num_classes"] == CLASSES
+    # which device JAX gave the server (utils/hw.device_summary)
+    dev = jax.devices()
+    assert (health["platform"], health["device_kind"],
+            health["device_count"]) == ("cpu", dev[0].device_kind, len(dev))
 
     rng = np.random.default_rng(0)
     clip = rng.standard_normal((FRAMES, CROP, CROP, 3)).astype(np.float32)
