@@ -134,12 +134,14 @@ def _attn_flops(matmuls: int) -> Callable[[Any], float]:
     return hook
 
 
-# in-tree kernels (keyed by kernel function name)
+# in-tree kernels: the conv kernels by the stable `name=` their
+# pallas_call gives (also the kernel's name in a device trace), the
+# attention kernels by kernel function name
 PALLAS_FLOPS_HOOKS.update({
-    "_pw_bn_act_kernel": _pw_kernel_flops,
-    "_conv_bn_act_kernel": _conv_kernel_flops,
-    "_dw_bn_act_kernel": _dw_kernel_flops,
-    "_dw_kernel": _dw_kernel_flops,
+    "pva_fused_pointwise_bn_act": _pw_kernel_flops,
+    "pva_fused_conv3d_bn_act": _conv_kernel_flops,
+    "pva_fused_depthwise_bn_act": _dw_kernel_flops,
+    "pva_depthwise3d_s1": _dw_kernel_flops,
     "_fwd_kernel": _attn_flops(2),
     "_bwd_dq_kernel": _attn_flops(3),
     "_bwd_dkv_kernel": _attn_flops(4),

@@ -760,7 +760,7 @@ def run_stress(smoke: bool = True,
                 finally:
                     wd.stop()
             # drain the scenario collector the way the trainer would
-            spans._DEFAULT.pop_window()
+            spans._DEFAULT.drain()
     finally:
         spans._DEFAULT, flight_recorder._DEFAULT = old_collector, old_recorder
         obstrace.disable_tracing()

@@ -583,7 +583,9 @@ class TrainConfig:
     # can't complete — a wedged PJRT client-create otherwise hangs the job
     # forever with no error (utils/device_doctor.py; SURVEY §5). 0 = off.
     device_init_timeout: int = 0
-    profile: bool = False  # jax.profiler trace of a step window (SURVEY §5)
+    # shorthand for obs.profile_steps "2..6", published under profile_dir
+    # (one capture path: obs/profiler.py)
+    profile: bool = False
     profile_dir: str = "/tmp/pva_tpu_profile"
     debug_nans: bool = False  # jax.config debug_nans (SURVEY §5 sanitizers)
     # trace-time batch-contract chex asserts in the compiled steps

@@ -31,8 +31,10 @@ Contracts, in order of importance:
   `finally`: stop flag set, worker joined, source generator closed (which
   cancels the host loader's in-flight decode futures). Worker-side
   exceptions cross the queue and re-raise in the consumer.
-- **Observability.** Per-epoch time the consumer spent blocked waiting for
-  the next device batch accumulates into `wait_s`; `pop_wait()` drains it.
+- **Observability.** The consumer's blocking wait for the next device batch
+  is the `input_wait` span (obs/spans.py: a start, a parent, the step id,
+  on the profiler's clock); the same clock reads accumulate into `wait_s`,
+  which `pop_wait()` drains. `ready()` is the ring's fill when asked.
   The trainer divides by the epoch's train-section wall time to report
   `input_wait_frac` (≪ 1 proves the overlap is real; → 1 means the input
   pipeline, not the model, bounds throughput).
@@ -46,7 +48,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Iterator, Optional
 
 from pytorchvideo_accelerate_tpu import obs
@@ -93,10 +94,9 @@ class DevicePrefetcher:
         self.depth = depth
         self.micro_dim = micro_dim
         self.wait_s = 0.0  # consumer time blocked on the next device batch
-        # telemetry spine (obs/): the consumer wait doubles as the
-        # `wait_name` span ("input_wait" train / "eval_input_wait" val — the
-        # latter nests inside the "eval" span, so it is background-classed
-        # to keep window sums single-counted); worker-side placement is the
+        # telemetry spine (obs/): the consumer wait is the `wait_name`
+        # span ("input_wait" train / "eval_input_wait" val — the latter
+        # nests inside the "eval" span); worker-side placement is the
         # `h2d_name` span ("h2d" train / "eval_h2d" val, kept apart so the
         # per-train-step obs_h2d_s never counts eval placements); the
         # worker pings the watchdog per placed batch and deregisters when
@@ -115,6 +115,12 @@ class DevicePrefetcher:
         self._mem_component = f"prefetch_ring:{self.wait_name}"
 
     # --- observability ----------------------------------------------------
+
+    def ready(self) -> int:
+        """Device batches placed (or being placed) and not yet consumed:
+        `depth` is a full ring, 0 a consumer that is about to wait."""
+        with self._lock:
+            return self._resident
 
     def pop_wait(self) -> float:
         """Accumulated input-wait seconds since the last call (epoch-scoped
@@ -141,29 +147,31 @@ class DevicePrefetcher:
         ahead; `loader.state` tracks the consumed position exactly as the
         plain host iteration would."""
         if self.depth == 0:
-            yield from self._epoch_sync(epoch, from_start)
-            return
+            return self._epoch_sync(epoch, from_start)
+        # trace handoff (obs/trace.py): capture the caller's context HERE,
+        # when the epoch is asked for, so the worker's h2d spans join
+        # whatever trace was active then (disarmed: one global read, ctx
+        # stays None) — not inside the generator, whose body first runs
+        # under the first iteration's own trace root
+        return self._epoch_prefetched(epoch, from_start, trace.capture())
 
+    def _epoch_prefetched(self, epoch: Optional[int], from_start: bool,
+                          ctx) -> Iterator[Any]:
         q: "queue.Queue[tuple]" = make_queue()  # bounded by `slots`, not maxsize
         stop = threading.Event()
         slots = threading.Semaphore(self.depth)
         items = self.loader.epoch_items(epoch, from_start)
-        # trace handoff (obs/trace.py): capture the consumer's context so
-        # the worker's h2d spans join whatever trace was active when the
-        # epoch started (disarmed: one global read, ctx stays None)
         worker = make_thread(
             target=self._worker,
-            args=(items, q, stop, slots, trace.capture()),
+            args=(items, q, stop, slots, ctx),
             name="device-prefetch", daemon=True,
         )
         worker.start()
         try:
             while True:
-                t0 = time.perf_counter()
-                kind, payload, state = q.get()
-                dt = time.perf_counter() - t0
-                self.wait_s += dt
-                obs.observe(self.wait_name, dt)
+                with obs.timed_span(self.wait_name) as wait:
+                    kind, payload, state = q.get()
+                self.wait_s += wait.dur_s
                 if kind == "batch":
                     with self._lock:
                         self._resident -= 1
@@ -206,11 +214,9 @@ class DevicePrefetcher:
                 if batch is None:
                     self.loader.state = state
                     continue
-                t0 = time.perf_counter()
-                placed = self._place(batch)
-                dt = time.perf_counter() - t0
-                self.wait_s += dt
-                obs.observe(self.wait_name, dt)
+                with obs.timed_span(self.wait_name) as wait:
+                    placed = self._place(batch)
+                self.wait_s += wait.dur_s
                 if self.watchdog is not None:
                     self.watchdog.heartbeat(self.watchdog_name)
                 self.loader.state = state

@@ -513,9 +513,12 @@ class ClipLoader:
                 return self.source.get(int(i), epoch)
 
         def fetch_batch(b: int) -> dict:
-            chunk = indices[b * spy : (b + 1) * spy]
-            samples = list(self._pool.map(fetch_one, chunk))
-            return self._assemble(samples, spy)
+            # obs "batch" span: one per assembled batch, on the assembly
+            # lane's thread (its samples' decodes run on the pool's)
+            with obs.span("batch"):
+                chunk = indices[b * spy : (b + 1) * spy]
+                samples = list(self._pool.map(fetch_one, chunk))
+                return self._assemble(samples, spy)
 
         start = start_state.position
         pending: "Queue[tuple]" = Queue()
@@ -589,7 +592,8 @@ class ClipLoader:
 
         def flush():
             nonlocal samples, dones
-            batch = self._assemble(samples, spy)
+            with obs.span("batch"):
+                batch = self._assemble(samples, spy)
             for done in dones:
                 done()
             samples, dones = [], []

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
@@ -202,18 +203,23 @@ class ConvBNAct(nn.Module):
                       name="norm")
         x = x.astype(self.dtype)
         w = w.astype(self.dtype)
+        # the kernel runs under the scope the unfused nn.Conv would open
+        # (".../conv/"), forward and backward, so a reader that selects
+        # device time by the layer's path finds it whatever the lowering
         if train:
             # fused conv pass; stats/affine/act ride it as one elementwise
             # tail (autodiff through the batch statistics stays plain)
-            raw = fused_conv3d_bn_act(
-                x, w, jnp.ones((self.features,), jnp.float32),
-                jnp.zeros((self.features,), jnp.float32),
-                act="identity", mode=self.fused)
+            with jax.named_scope("conv"):
+                raw = fused_conv3d_bn_act(
+                    x, w, jnp.ones((self.features,), jnp.float32),
+                    jnp.zeros((self.features,), jnp.float32),
+                    act="identity", mode=self.fused)
             return fused_train_norm_act(raw, bn, self.features, act_name,
                                         self.dtype)
         mul, add = bn(self.features, train=False)
-        return fused_conv3d_bn_act(x, w, mul, add, act=act_name,
-                                   mode=self.fused)
+        with jax.named_scope("conv"):
+            return fused_conv3d_bn_act(x, w, mul, add, act=act_name,
+                                       mode=self.fused)
 
 
 class Bottleneck3D(nn.Module):

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Any, Tuple
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -127,16 +128,20 @@ class X3DBlock(nn.Module):
         epilogue = "identity" if self.use_se else "silu"
         y = y.astype(self.dtype)
         k = k.astype(self.dtype)
+        # under the scope DepthwiseConv3D(name="conv_b") would open, so the
+        # layer's device time is found by its path whatever the lowering
         if train:
-            raw = fused_depthwise_bn_act(
-                y, k, jnp.ones((c,), jnp.float32),
-                jnp.zeros((c,), jnp.float32), act="identity",
-                mode=self.fused)
+            with jax.named_scope("conv_b"):
+                raw = fused_depthwise_bn_act(
+                    y, k, jnp.ones((c,), jnp.float32),
+                    jnp.zeros((c,), jnp.float32), act="identity",
+                    mode=self.fused)
             y = fused_train_norm_act(raw, bn, c, epilogue, self.dtype)
         else:
             mul, add = bn(c, train=False)
-            y = fused_depthwise_bn_act(y, k, mul, add, act=epilogue,
-                                       mode=self.fused)
+            with jax.named_scope("conv_b"):
+                y = fused_depthwise_bn_act(y, k, mul, add, act=epilogue,
+                                           mode=self.fused)
         if self.use_se:
             y = SqueezeExcite(c, dtype=self.dtype, name="se")(y)
             y = nn.swish(y)
@@ -173,15 +178,17 @@ class X3D(nn.Module):
                                 name="stem_t")().astype(self.dtype)
             bn = BNAffine(momentum=0.9, eps=1e-5, name="stem_norm")
             if train:
-                raw = fused_depthwise_bn_act(
-                    x, k, jnp.ones((sf,), jnp.float32),
-                    jnp.zeros((sf,), jnp.float32), act="identity",
-                    mode=self.fused)
+                with jax.named_scope("stem_t"):
+                    raw = fused_depthwise_bn_act(
+                        x, k, jnp.ones((sf,), jnp.float32),
+                        jnp.zeros((sf,), jnp.float32), act="identity",
+                        mode=self.fused)
                 x = fused_train_norm_act(raw, bn, sf, "relu", self.dtype)
             else:
                 mul, add = bn(sf, train=False)
-                x = fused_depthwise_bn_act(x, k, mul, add, act="relu",
-                                           mode=self.fused)
+                with jax.named_scope("stem_t"):
+                    x = fused_depthwise_bn_act(x, k, mul, add, act="relu",
+                                               mode=self.fused)
         else:
             x = DepthwiseConv3D(self.stem_features, (5, 1, 1),
                                 impl=self.depthwise_impl, dtype=self.dtype,

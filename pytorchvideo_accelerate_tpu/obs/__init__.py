@@ -31,8 +31,8 @@ from pytorchvideo_accelerate_tpu.obs.spans import (  # noqa: F401
     SpanCollector,
     current_stacks,
     get_collector,
-    observe,
     span,
+    timed_span,
 )
 from pytorchvideo_accelerate_tpu.obs.watchdog import Watchdog  # noqa: F401
 # distributed tracing (obs/trace.py): `obs.trace.configure_tracing(...)`,

@@ -1,18 +1,17 @@
 """On-demand device profiler capture (pva-tpu-hbm layer c).
 
-`TrainConfig.profile` captures a fixed early-step window and nothing
-else: a live incident — serving p99 burning NOW, a step-time regression
-appearing mid-run — had no way to get a device profile out of the
-process. This module adds exactly that, two triggers over one capture
-primitive:
+The ONE profiler capture path of the package: a live incident — serving
+p99 burning NOW, a step-time regression appearing mid-run — needs a device
+profile out of the running process, in a trace small enough to read. Two
+triggers over one capture primitive:
 
 - ``POST /profile?seconds=N`` on the serving server: a background
   capture window on a live process (409 while one is running — the
   profiler is a singleton resource);
 - ``--obs.profile_steps A..B`` in the trainer: a run-relative step
-  window (same origin as the early-step `profile` flag: step 0 is this
-  run's first step, so a resumed run profiles its warm steps, not a
-  global step count it never sees).
+  window (step 0 is this run's first step, so a resumed run profiles its
+  warm steps, not a global step count it never sees). `--profile` is the
+  shorthand for steps 2..6, published under `profile_dir`.
 
 Captures are written ATOMICALLY under `output_dir`: the trace streams
 into a dot-prefixed temp dir and is `os.replace`d to its final name
@@ -93,7 +92,22 @@ class ProfilerCapture:
             import jax
 
             os.makedirs(tmp, exist_ok=True)
-            jax.profiler.start_trace(tmp)
+            # the least that still records host annotations (the `pva/*`
+            # spans, `train`): no Python tracer, no level-2 host events,
+            # no HLO proto (the step's text is read from the compiled
+            # program). On a v5e host the runtime's own level-1 events
+            # are still some 0.9 M a step (1.2 GB for 40 X3D-S steps,
+            # PERF.md PR 25), so keep a window to tens of steps
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            options.enable_hlo_proto = False
+            jax.profiler.start_trace(tmp, profiler_options=options)
+            # marks, on the trace's own clock, when start_trace returned:
+            # what the device does between here and its first traced
+            # execution is the profiler's arming, not the program's
+            with jax.profiler.TraceAnnotation("pva/capture"):
+                pass
         except Exception as e:
             with self._lock:
                 self._active_tag = self._tmp_dir = None

@@ -83,6 +83,9 @@ class _Noop:
     def finish(self, **tags):
         return None
 
+    def drop(self):
+        return None
+
 
 NOOP = _Noop()
 
@@ -200,6 +203,10 @@ class _SpanToken:
         self._t0_wall = time.time()
         self._t0_perf = time.perf_counter()
 
+    def drop(self) -> None:
+        """Leave the context without recording the span."""
+        self._tracer._pop()
+
     def end(self, error: bool = False, **tags) -> None:
         dur = time.perf_counter() - self._t0_perf
         self._tracer._pop()
@@ -236,7 +243,7 @@ class _TraceSpan:
 class TraceHandle:
     """A root (or continued) span. Two usage shapes:
 
-    - synchronous: `with tracer.start("train_step", gstep=g) or NOOP: ...`
+    - synchronous: `with tracer.start("train_iter", gstep=g) or NOOP: ...`
       — activates the context for the block, records the root event on
       exit;
     - asynchronous (the load generator, HTTP fronts): keep the handle,
@@ -268,6 +275,10 @@ class TraceHandle:
         self._entered = False
         self.finish(**({"error": True} if exc_type is not None else {}))
         return False
+
+    def drop(self) -> None:
+        """This root turned out to hold no work: record no event for it."""
+        self._done = True
 
     def finish(self, **tags) -> None:
         """Record the root event (idempotent; async completions race a
@@ -578,7 +589,7 @@ def attach(ctx: Optional[TraceContext]):
 
 
 def root(name: str, **tags):
-    """Start-or-noop: `with trace.root("train_step", gstep=g): ...`."""
+    """Start-or-noop: `with trace.root("train_iter", gstep=g): ...`."""
     rt = _tracer
     if rt is None:
         return NOOP

@@ -14,7 +14,8 @@ wall-clock axis. This tool does exactly that:
   already carry wall-clock microsecond timestamps and the recording pid;
 - flight records (`{"events": [...]}`) convert to Perfetto INSTANT events
   (`ph: "i"`), so watchdog stalls, warnings, and membership flaps line up
-  against the request spans that surrounded them;
+  against the request spans that surrounded them; their `span` events
+  carry a start (`t0`) and become intervals (`ph: "X"`) like the rings';
 - output is Chrome trace-event JSON, sorted by timestamp — load it in
   Perfetto / chrome://tracing, or grep it for a `trace_id` surfaced by a
   latency-histogram exemplar or `/stats` `slowest_traces`.
@@ -35,13 +36,14 @@ _FLIGHT_TID = 0  # flight-record events carry thread NAMES, not idents
 
 
 def flight_to_events(record: dict) -> List[dict]:
-    """Convert one flight-record dump into Perfetto instant events."""
+    """Convert one flight-record dump into Perfetto events: instants,
+    and intervals for the spans that carry their start (`t0`)."""
     pid = record.get("pid", 0)
     out = []
     for evt in record.get("events", ()):
         args = {k: v for k, v in evt.items()
                 if k not in ("ts", "kind", "name")}
-        out.append({
+        out_evt = {
             "name": f"{evt.get('kind', 'event')}:{evt.get('name', '?')}",
             "ph": "i",
             "s": "p",  # process-scoped instant
@@ -49,7 +51,12 @@ def flight_to_events(record: dict) -> List[dict]:
             "pid": pid,
             "tid": _FLIGHT_TID,
             "args": args,
-        })
+        }
+        if evt.get("kind") == "span" and "t0" in evt:
+            del out_evt["s"]
+            out_evt.update(ph="X", ts=round(float(evt["t0"]) * 1e6, 1),
+                           dur=round(float(evt.get("dur_s", 0.0)) * 1e6, 1))
+        out.append(out_evt)
     return out
 
 

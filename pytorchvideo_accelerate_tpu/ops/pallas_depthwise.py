@@ -112,6 +112,7 @@ def _dw_call(xp, kernel, dims, out_t: int, out_h: int, out_w: int,
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=interpret,
+        name="pva_depthwise3d_s1",
     )(xp, kernel)
 
 

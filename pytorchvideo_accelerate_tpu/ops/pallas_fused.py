@@ -147,6 +147,7 @@ def _pw_call(x2d, w, b2d, act: str, interpret: bool):
         ],
         out_specs=pl.BlockSpec((bm, cout), lambda i: (i, 0)),
         interpret=interpret,
+        name="pva_fused_pointwise_bn_act",
     )(x2d, w, b2d)
     return out[:m] if pad else out
 
@@ -246,6 +247,7 @@ def _conv_call(x, wf, b2d, act: str, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_CONV_VMEM_LIMIT),
         interpret=interpret,
+        name="pva_fused_conv3d_bn_act",
     )(xp, wflat, b2d)
 
 
@@ -344,6 +346,7 @@ def _dw_call(x, kf, b2d, act: str, interpret: bool):
             pltpu.SemaphoreType.DMA(()),
         ],
         interpret=interpret,
+        name="pva_fused_depthwise_bn_act",
     )(xp, kflat, b2d)
 
 

@@ -11,7 +11,9 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import time
+from collections import deque
 from contextlib import nullcontext
 from typing import Dict, Optional
 
@@ -92,6 +94,9 @@ from pytorchvideo_accelerate_tpu.utils.rng import RngManager, set_seed
 
 logger = get_logger("pva_tpu")
 
+STEP_RECORDS = 8192  # fit()'s per-iteration records kept (a bounded ring)
+STEP_RECORDS_FILE = "step_records.jsonl"  # beside flight_record.json
+
 
 def _parse_checkpointing_steps(value: str):
     """Reference parsing semantics (run.py:123-133): "" -> None, "epoch" ->
@@ -136,11 +141,22 @@ class Trainer:
         if self.obs_on and cfg.obs.history_ticks > 0:
             obs.history.configure(capacity=cfg.obs.history_ticks)
         # validate --obs.profile_steps at construction (a typo'd window must
-        # fail now, not 3 epochs in); run-relative step offsets A..B
-        self.profile_steps = obs.profiler.parse_steps(cfg.obs.profile_steps)
+        # fail now, not 3 epochs in); run-relative step offsets A..B.
+        # `--profile` is the shorthand for "2..6" published to profile_dir:
+        # one capture path (obs/profiler.py), whichever flag asked
+        self.profile_steps = obs.profiler.parse_steps(
+            cfg.obs.profile_steps or ("2..6" if cfg.profile else ""))
         if self.profile_steps is not None:
-            obs.profiler.configure(output_dir=cfg.checkpoint.output_dir,
-                                   recorder=obs.get_recorder())
+            obs.profiler.configure(
+                output_dir=(cfg.checkpoint.output_dir
+                            if cfg.obs.profile_steps else cfg.profile_dir),
+                recorder=obs.get_recorder())
+        # step observers: callables (gstep, trainer) -> bool, run at the
+        # bottom of every fit() iteration (inside its `iter` span); a true
+        # value ends the epoch exactly as data.limit_train_batches does
+        self.step_observers: list = []
+        # one record per fit() iteration, the last STEP_RECORDS of them
+        self.step_records: deque = deque(maxlen=STEP_RECORDS)
         self.watchdog: Optional[obs.Watchdog] = None
         if self.obs_on and cfg.obs.trace_sample_rate > 0:
             # distributed tracing (obs/trace.py): head-sample train steps;
@@ -886,6 +902,10 @@ class Trainer:
         (shared by fit()'s per-epoch eval and evaluate());
         returns (top1, top5, mean_loss)."""
         val = SumMetrics()
+        if self.cfg.data.limit_val_batches == 0:
+            # no eval asked for: no val batch is placed and the eval step
+            # is never called, so it is never compiled either
+            return val.accuracy(), val.accuracy_top5(), val.mean_loss()
         # from_start: eval is stateless — a prior early-broken pass (e.g.
         # limit_val_batches) must not make this one resume mid-epoch.
         # Batches arrive pre-placed on the mesh (device prefetch), so the
@@ -940,6 +960,42 @@ class Trainer:
                 self.train_feed.close()
             self.train_loader.close()
             self.val_loader.close()
+
+    def _profile_window(self, run_step: int) -> None:
+        """--profile / --obs.profile_steps A..B: the run-relative capture
+        window, published atomically as profile_steps_A_B/ by the one
+        capture object (obs/profiler.py). Opens before step A is asked
+        for and closes after step B-1's iteration has ended."""
+        prof = obs.profiler.get_profiler()
+        first, end = self.profile_steps
+        if prof is None:
+            return
+        if run_step == first and not prof.busy:
+            with obs.span("profile"):
+                prof.start(tag=f"steps_{first}_{end}")
+        elif run_step >= end and prof.busy:
+            with obs.span("profile"):
+                out = prof.stop()
+            if out:
+                main_print(f"profile window written to {out}")
+
+    def _dump_step_records(self) -> Optional[str]:
+        """Write fit()'s per-iteration records (one JSON object a line:
+        gstep, t0_ns, and the seconds of iter / input_wait / step / log,
+        ready) beside flight_record.json. Like the flight recorder's dump,
+        a failed write is not worth dying over."""
+        out_dir = self.cfg.checkpoint.output_dir
+        if not self.obs_on or not out_dir or not self.step_records:
+            return None
+        path = os.path.join(out_dir, STEP_RECORDS_FILE)
+        try:
+            os.makedirs(out_dir, exist_ok=True)
+            with open(path, "w") as f:
+                f.writelines(json.dumps(r) + "\n"
+                             for r in self.step_records)
+        except OSError:
+            return None
+        return path
 
     def _obs_on_flush(self):
         """DeferredStepLogger hook mirroring the logged step metrics into
@@ -999,10 +1055,10 @@ class Trainer:
         # includes compile) — lets benchmarks measure steady-state throughput
         epoch_train_times = []
 
-        profiling = False
         # profile window is relative to THIS run's first step, so resumed
         # runs (gstep >> 0) still capture a trace
         run_start_step = gstep
+        self.step_records.clear()
         metrics = None
         # metric logging is one step delayed: the fetch happens after the
         # NEXT step has been dispatched, so logging never syncs the step
@@ -1021,34 +1077,42 @@ class Trainer:
         recompile_guard = RecompileGuard(self.train_step)
         # obs window accounting: the collector aggregates named spans; every
         # log_every boundary drains them into a per-window step-time
-        # breakdown (obs/step_s, obs/input_wait_s, ...) logged through the
-        # trackers, and epoch_spans carries the epoch totals for the perf
-        # dict (obs_step_s / obs_input_wait_frac / obs_h2d_s — the numbers
+        # breakdown (obs/iter_s, obs/iter_self_s, obs/step_s,
+        # obs/input_wait_s, ...) logged through the trackers, and
+        # epoch_spans carries the epoch totals for the perf dict
+        # (obs_step_s / obs_input_wait_frac / obs_h2d_s — the numbers
         # bench.py reports on its headline line)
         collector = obs.get_collector() if self.obs_on else None
         epoch_spans: Dict[str, float] = {}
+        loop_thread = threading.get_ident()
 
         def drain_spans(log_step=None, window_wall=None):
             if collector is None:
                 return
-            window = collector.pop_window()
-            for name, (total, _count) in window.items():
+            window, self_by_thread = collector.drain()
+            for name, (total, _count, _self) in window.items():
                 epoch_spans[name] = epoch_spans.get(name, 0.0) + total
             if log_step is None or not self.trackers or not window:
                 return
-            vals = {f"obs/{n}_s": t for n, (t, _c) in window.items()}
+            vals = {f"obs/{n}_s": t for n, (t, _c, _s) in window.items()}
+            # a span that had children also reports its SELF time (its
+            # duration less what they cover): obs/iter_self_s is the loop's
+            # own Python and whatever took the GIL from it, measured
+            vals.update({f"obs/{n}_self_s": s
+                         for n, (t, _c, s) in window.items() if s < t})
             if window_wall is not None:
-                # consumer-side spans account the step loop's wall time;
-                # background spans (h2d/decode) overlap it on worker
-                # threads and are reported, not summed
-                consumer = sum(t for n, (t, _c) in window.items()
-                               if n not in obs.BACKGROUND_SPANS)
+                # the self times of everything THIS thread recorded sum to
+                # the wall time it spent inside any span; worker threads'
+                # spans (h2d/batch/decode) overlap it and are reported,
+                # not summed. What is left was outside every span: near
+                # zero while the whole iteration is inside `iter`
                 vals["obs/window_wall_s"] = window_wall
-                vals["obs/unattributed_s"] = window_wall - consumer
+                vals["obs/unattributed_s"] = (
+                    window_wall - self_by_thread.get(loop_thread, 0.0))
             self.trackers.log(vals, step=log_step)
 
         if collector is not None:
-            collector.pop_window()  # init/resume spans: not this window's
+            collector.drain()  # init/resume spans: not this window's
         if self.watchdog is not None:
             self.watchdog.start()  # re-arm after a prior fit/evaluate
             self.watchdog.heartbeat("train")
@@ -1110,147 +1174,201 @@ class Trainer:
                 # batches arrive pre-placed on the mesh: the device prefetch
                 # thread overlaps the H2D copy of batch N+1 with compute of
                 # batch N, so steady-state steps never block on the host link
-                for step_in_epoch, global_batch in enumerate(
-                        self.train_prefetch.epoch(epoch)):
-                    if self.watchdog is not None:
-                        self.watchdog.heartbeat("train")
-                    if (cfg.profile and not profiling
-                            and gstep - run_start_step == 2):
-                        jax.profiler.start_trace(cfg.profile_dir)
-                        profiling = True
-                    # --obs.profile_steps A..B: run-relative capture window,
-                    # published atomically as <output_dir>/profile_<tag>/
-                    # (obs/profiler.py). Independent of cfg.profile above.
-                    if (self.profile_steps is not None
-                            and gstep - run_start_step
-                            == self.profile_steps[0]):
-                        prof = obs.profiler.get_profiler()
-                        if prof is not None and not prof.busy:
-                            prof.start(tag=f"steps_{self.profile_steps[0]}_"
-                                           f"{self.profile_steps[1]}")
-                    # chaos hook: "delay" = a slow dispatch, "raise" = a
-                    # failing one, "nan" = poison the dispatched batch
-                    # (the numeric divergence the guard ladder recovers
-                    # from). Disarmed: one global read.
-                    if fault_point("step.dispatch") == "nan":
-                        global_batch = poison_batch(global_batch)
-                    # "step" span = dispatch time; under async dispatch it
-                    # absorbs compute only when the dispatch queue pushes
-                    # back (or at compile), which is exactly the reading
-                    # that matters for the per-window breakdown. With a
-                    # watchdog live, STEADY-STATE dispatches also run
-                    # inside an attributed "collective" section: queue
-                    # push-back from a wedged mesh collective then dumps
-                    # per-host evidence instead of anonymous silence. The
-                    # first dispatch (the legitimate minutes-long XLA
-                    # compile) is deliberately unwatched — attributing it
-                    # would be the exact wedged-collective misverdict this
-                    # detector exists to prevent; any LATER slow dispatch
-                    # is either a real wedge or a recompile the
-                    # recompile guard flags anyway.
-                    # sampled steps become trace roots tagged (epoch,
-                    # gstep) — the same coordinates the profiler's
-                    # StepTraceAnnotation window carries, so a merged
-                    # timeline and an XLA trace correlate by gstep
-                    with (obs.trace.root("train_step", epoch=epoch,
-                                         gstep=gstep, **trace_tags)
-                          if traced else nullcontext()):
-                        with (hang_watch.section(
-                                "collective",
-                                f"step_dispatch {host_tag} gstep={gstep}")
-                              if hang_watch is not None
-                              and recompile_guard.armed else nullcontext()):
-                            with obs.span("step"):
-                                with jax.profiler.StepTraceAnnotation(
-                                        "train", step_num=gstep):
-                                    self.state, metrics = self.train_step(
-                                        self.state, global_batch,
-                                        self.rng.step_key(gstep)
-                                    )
-                    gstep += 1
-                    train_steps_this_epoch += 1
-                    if not recompile_guard.armed:
-                        # the first dispatch has returned, so its trace +
-                        # compile are done: everything past this baseline
-                        # is a steady-state recompile
-                        recompile_guard.arm()
-                    if deferred is not None:
-                        # previous boundary's metrics: their step has retired
-                        # behind the one just dispatched, so this fetch
-                        # doesn't stall the pipeline
-                        with obs.span("log"):
-                            deferred.flush()
-                    if tguard is not None:
-                        # observe the PREVIOUS step's metrics (retired
-                        # behind the dispatch above — never a pipeline
-                        # stall) and stash this one; a rollback verdict
-                        # breaks out, GuardHalt raises through
-                        action = tguard.step(
-                            gstep, metrics, global_batch,
-                            self.train_loader.state, self.state)
-                        if action is not None:
-                            self._guard_rollback(action)
-                            rolled_back = True
-                            break
-                    if self._flops_per_step is None:
-                        # unconditional (not tracking-gated): fit()'s return
-                        # dict and the bench harness both need FLOPs/step
-                        with obs.span("compile_probe"):
-                            self._capture_step_flops(global_batch, gstep)
-                    if profiling and gstep - run_start_step >= 6:
-                        jax.profiler.stop_trace()
-                        profiling = False
-                        main_print(f"profile trace written to {cfg.profile_dir}")
-                    if (self.profile_steps is not None
-                            and gstep - run_start_step
-                            >= self.profile_steps[1]):
-                        prof = obs.profiler.get_profiler()
-                        if prof is not None and prof.busy:
-                            out = prof.stop()
-                            if out:
-                                main_print(
-                                    f"profile window written to {out}")
-
-                    if use_tqdm:
-                        progress.update(1)
-                    # device scalar; the host->device sync happens at epoch end
-                    # (MeanLoss.mean) or at the deferred log_every fetch
-                    epoch_loss.update_async(metrics["loss"])
-                    if deferred is not None and gstep % cfg.tracking.log_every == 0:
-                        vals = {"train_loss_step": metrics["loss"],
-                                "lr": metrics["lr"],
-                                "grad_norm": metrics["grad_norm"]}
-                        if self.obs_on:
-                            # on-device health gauges ride the same
-                            # deferred fetch (steps.py health_metrics)
-                            vals["obs/param_norm"] = metrics["param_norm"]
-                            vals["obs/update_ratio"] = metrics["update_ratio"]
-                            vals["obs/nonfinite"] = metrics["nonfinite"]
-                        deferred.defer(vals, step=gstep)
-                    if self.obs_on and gstep % cfg.tracking.log_every == 0:
-                        now = time.perf_counter()
-                        drain_spans(log_step=gstep,
-                                    window_wall=now - window_t0)
-                        window_t0 = now
-                        recompile_guard.sample()  # refresh the gauge
-                        # append a scrape tick to the bounded history ring
-                        # (obs/history.py) — the ledger's live gauges land
-                        # in the same tick, so hbm series accrue for free
-                        hist = obs.history.get_history()
-                        if hist is not None:
-                            hist.tick()
-                    if (isinstance(self.checkpointing_steps, int)
-                            and gstep % self.checkpointing_steps == 0):
-                        self._save("step", epoch)
-                        main_print(f"saved checkpoint at step {gstep}")
-                    if guard is not None and guard.requested:
-                        # finish-the-step-then-leave: the dispatch above
-                        # has returned, so breaking here never abandons an
-                        # in-flight optimizer update
-                        preempted = True
-                        break
-                    if 0 <= cfg.data.limit_train_batches <= step_in_epoch + 1:
-                        break
+                batches = iter(self.train_prefetch.epoch(epoch))
+                step_in_epoch = -1
+                try:
+                    while True:
+                        step_in_epoch += 1
+                        if self.profile_steps is not None:
+                            # between two iterations, so that every
+                            # iteration of the window is whole in the trace
+                            self._profile_window(gstep - run_start_step)
+                        # ONE span per iteration, from asking for the next
+                        # batch to the bottom of the body, carrying gstep:
+                        # input_wait (inside the prefetcher), step, log,
+                        # ckpt are its children and inherit the id; its
+                        # self time is the loop's own. A sampled iteration
+                        # is also a distributed-trace root tagged (epoch,
+                        # gstep) — the coordinates the profiler's
+                        # StepTraceAnnotation carries, so a merged timeline
+                        # and an XLA trace correlate by gstep
+                        iter_span = obs.span("iter", step=gstep)
+                        iter_root = (obs.trace.root("train_iter", epoch=epoch,
+                                                    gstep=gstep, **trace_tags)
+                                     if traced else obs.trace.NOOP)
+                        step_span = log_span = None
+                        try:
+                            with iter_root, iter_span:
+                                ready = self.train_prefetch.ready()
+                                wait_before = self.train_prefetch.wait_s
+                                global_batch = next(batches, None)
+                                if global_batch is None:
+                                    # the epoch ran out: step `gstep` is not
+                                    # of this epoch, so neither is an `iter`
+                                    # (the wait stays in `input_wait`)
+                                    iter_span.discard()
+                                    iter_root.drop()
+                                    break
+                                waited = (self.train_prefetch.wait_s
+                                          - wait_before)
+                                if self.watchdog is not None:
+                                    self.watchdog.heartbeat("train")
+                                # chaos hook: "delay" = a slow dispatch,
+                                # "raise" = a failing one, "nan" = poison
+                                # the dispatched batch (the numeric
+                                # divergence the guard ladder recovers
+                                # from). Disarmed: one global read.
+                                if fault_point("step.dispatch") == "nan":
+                                    global_batch = poison_batch(global_batch)
+                                # "step" span = dispatch time; under async
+                                # dispatch it absorbs compute only when the
+                                # dispatch queue pushes back (or at
+                                # compile), which is exactly the reading
+                                # that matters for the per-window
+                                # breakdown. With a watchdog live,
+                                # STEADY-STATE dispatches also run inside
+                                # an attributed "collective" section: queue
+                                # push-back from a wedged mesh collective
+                                # then dumps per-host evidence instead of
+                                # anonymous silence. The first dispatch
+                                # (the legitimate minutes-long XLA compile)
+                                # is deliberately unwatched — attributing
+                                # it would be the exact wedged-collective
+                                # misverdict this detector exists to
+                                # prevent; any LATER slow dispatch is
+                                # either a real wedge or a recompile the
+                                # recompile guard flags anyway.
+                                with (hang_watch.section(
+                                        "collective",
+                                        f"step_dispatch {host_tag} "
+                                        f"gstep={gstep}")
+                                      if hang_watch is not None
+                                      and recompile_guard.armed
+                                      else nullcontext()):
+                                    with obs.span("step") as step_span:
+                                        with jax.profiler.StepTraceAnnotation(
+                                                "train", step_num=gstep):
+                                            self.state, metrics = \
+                                                self.train_step(
+                                                    self.state, global_batch,
+                                                    self.rng.step_key(gstep))
+                                gstep += 1
+                                train_steps_this_epoch += 1
+                                if not recompile_guard.armed:
+                                    # the first dispatch has returned, so
+                                    # its trace + compile are done:
+                                    # everything past this baseline is a
+                                    # steady-state recompile
+                                    recompile_guard.arm()
+                                if deferred is not None:
+                                    # previous boundary's metrics: their
+                                    # step has retired behind the one just
+                                    # dispatched, so this fetch doesn't
+                                    # stall the pipeline
+                                    with obs.span("log") as log_span:
+                                        deferred.flush()
+                                if tguard is not None:
+                                    # observe the PREVIOUS step's metrics
+                                    # (retired behind the dispatch above —
+                                    # never a pipeline stall) and stash
+                                    # this one; a rollback verdict breaks
+                                    # out, GuardHalt raises through
+                                    action = tguard.step(
+                                        gstep, metrics, global_batch,
+                                        self.train_loader.state, self.state)
+                                    if action is not None:
+                                        self._guard_rollback(action)
+                                        rolled_back = True
+                                        break
+                                if self._flops_per_step is None:
+                                    # unconditional (not tracking-gated):
+                                    # fit()'s return dict and the bench
+                                    # harness both need FLOPs/step
+                                    with obs.span("compile_probe"):
+                                        self._capture_step_flops(
+                                            global_batch, gstep)
+                                if use_tqdm:
+                                    progress.update(1)
+                                # device scalar; the host->device sync
+                                # happens at epoch end (MeanLoss.mean) or at
+                                # the deferred log_every fetch
+                                epoch_loss.update_async(metrics["loss"])
+                                if (deferred is not None
+                                        and gstep % cfg.tracking.log_every == 0):
+                                    vals = {"train_loss_step": metrics["loss"],
+                                            "lr": metrics["lr"],
+                                            "grad_norm": metrics["grad_norm"]}
+                                    if self.obs_on:
+                                        # on-device health gauges ride the
+                                        # same deferred fetch (steps.py
+                                        # health_metrics)
+                                        vals["obs/param_norm"] = \
+                                            metrics["param_norm"]
+                                        vals["obs/update_ratio"] = \
+                                            metrics["update_ratio"]
+                                        vals["obs/nonfinite"] = \
+                                            metrics["nonfinite"]
+                                    deferred.defer(vals, step=gstep)
+                                if (isinstance(self.checkpointing_steps, int)
+                                        and gstep % self.checkpointing_steps
+                                        == 0):
+                                    self._save("step", epoch)
+                                    main_print(
+                                        f"saved checkpoint at step {gstep}")
+                                if guard is not None and guard.requested:
+                                    # finish-the-step-then-leave: the
+                                    # dispatch above has returned, so
+                                    # breaking here never abandons an
+                                    # in-flight optimizer update
+                                    preempted = True
+                                    break
+                                if (0 <= cfg.data.limit_train_batches
+                                        <= step_in_epoch + 1):
+                                    break
+                                # every observer sees every step, also the
+                                # one at which another asks for the end
+                                if [o for o in self.step_observers
+                                        if o(gstep, self)]:
+                                    break
+                        finally:
+                            if step_span is not None and self.obs_on:
+                                # the iteration reached its dispatch: its
+                                # record (written by this thread only)
+                                self.step_records.append({
+                                    "gstep": iter_span.step,
+                                    "t0_ns": iter_span.t0_ns,
+                                    "iter": iter_span.dur_s,
+                                    "input_wait": waited,
+                                    "step": step_span.dur_s,
+                                    "log": (log_span.dur_s if log_span
+                                            is not None else 0.0),
+                                    "ready": ready,
+                                })
+                            if self.profile_steps is not None:
+                                # step B-1's iteration has ended: close the
+                                # capture here, also where a `break` leaves
+                                # the loop and no iteration follows (the
+                                # trace would stay open through eval)
+                                self._profile_window(gstep - run_start_step)
+                        # a log window closes BETWEEN two iterations, so
+                        # that every `iter` span lies wholly in one window
+                        if self.obs_on and gstep % cfg.tracking.log_every == 0:
+                            now = time.perf_counter()
+                            drain_spans(log_step=gstep,
+                                        window_wall=now - window_t0)
+                            window_t0 = now
+                            recompile_guard.sample()  # refresh the gauge
+                            # append a scrape tick to the bounded history
+                            # ring (obs/history.py) — the ledger's live
+                            # gauges land in the same tick, so hbm series
+                            # accrue for free
+                            hist = obs.history.get_history()
+                            if hist is not None:
+                                hist.tick()
+                finally:
+                    # stops the prefetch worker and frees its device
+                    # batches now, not when the generator is collected
+                    batches.close()
                 if preempted:
                     # grace path: sync the last step's result, flush the
                     # pending log, persist, and leave — no eval, no
@@ -1464,12 +1582,8 @@ class Trainer:
             raise  # pva: disable=spmd-divergence -- crash path: this host is already dying; surviving hosts wedge ATTRIBUTABLY in their next hangcheck section
         finally:
             # flush a partial trace even when the run dies mid-window —
-            # that trace is most valuable exactly when diagnosing a crash
-            if profiling:
-                jax.profiler.stop_trace()
-                main_print(f"profile trace written to {cfg.profile_dir}")
-            # likewise an unfinished --obs.profile_steps window: stop() still
-            # publishes atomically (partial trace beats no trace on a crash)
+            # that trace is most valuable exactly when diagnosing a crash;
+            # stop() still publishes atomically
             if self.profile_steps is not None:
                 prof = obs.profiler.get_profiler()
                 if prof is not None and prof.busy:
@@ -1478,6 +1592,7 @@ class Trainer:
             # (<output_dir>/trace_ring.json) on clean exit AND on a crash;
             # no-op when tracing is disarmed
             obs.trace.dump()
+            self._dump_step_records()
             if self.watchdog is not None:
                 self.watchdog.clear("train")
                 self.watchdog.stop()
@@ -1504,7 +1619,9 @@ class Trainer:
                   "epoch_train_times": epoch_train_times,
                   "flops_per_step": self._flops_per_step,
                   "analytic_flops_per_step": self._analytic_flops_per_step,
-                  "preempted": preempted, **last_perf, **last_mfu_labels}
+                  "preempted": preempted,
+                  "step_records": list(self.step_records),
+                  **last_perf, **last_mfu_labels}
         if self.is_pretraining:
             result["val_recon_loss"] = last_val_loss
         else:

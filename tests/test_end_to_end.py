@@ -241,13 +241,20 @@ def test_register_for_checkpointing_round_trip(tmp_path):
 
 def test_profile_writes_trace(tmp_path):
     """--profile captures a jax.profiler trace of the step window
-    (SURVEY §5 tracing; trainer/loop.py steps 2-6)."""
+    (SURVEY §5 tracing): the shorthand for --obs.profile_steps 2..6
+    published under profile_dir, through the one capture path."""
     cfg = _cfg(tmp_path, **{"optim.num_epochs": 2})
     cfg.profile = True
     cfg.profile_dir = str(tmp_path / "trace")
-    Trainer(cfg).fit()
+    tr = Trainer(cfg)
+    assert tr.profile_steps == (2, 6)
+    tr.fit()
     found = list((tmp_path / "trace").rglob("*"))
     assert any(f.is_file() for f in found), "no trace artifacts written"
+    # published atomically, whole: no temp dir left, the trace under its tag
+    assert list((tmp_path / "trace" / "profile_steps_2_6").rglob(
+        "*.xplane.pb"))
+    assert not list((tmp_path / "trace").glob(".profile_tmp_*"))
 
 
 def test_parse_checkpointing_steps_zero_disables():

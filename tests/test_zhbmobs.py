@@ -412,7 +412,12 @@ def fake_jax_profiler(monkeypatch, tmp_path):
 
     state = {"dir": None, "stops": 0}
 
-    def start_trace(d):
+    def start_trace(d, profiler_options=None):
+        # the one capture path arms a trace that can be read: host
+        # annotations only, no Python tracer, no HLO proto
+        assert profiler_options.python_tracer_level == 0
+        assert profiler_options.host_tracer_level == 1
+        assert profiler_options.enable_hlo_proto is False
         state["dir"] = d
         with open(os.path.join(d, "trace.marker"), "w") as f:
             f.write("x")
@@ -456,7 +461,7 @@ def test_profiler_backend_refusal_is_recorded_not_raised(monkeypatch,
                                                          tmp_path):
     import jax
 
-    def boom(d):
+    def boom(d, profiler_options=None):
         raise RuntimeError("no profiler on this backend")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)

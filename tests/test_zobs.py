@@ -50,10 +50,10 @@ def test_span_nesting_single_thread():
             stacks = c.current_stacks()
             assert _stack_of(stacks) == ["outer", "inner"]
     assert c.current_stacks() == {}  # everything closed
-    win = c.pop_window()
+    win, _ = c.drain()
     assert win["outer"][1] == 1 and win["inner"][1] == 1
     assert win["outer"][0] >= win["inner"][0] >= 0.0
-    assert c.pop_window() == {}  # drained
+    assert c.drain() == ({}, {})  # drained
 
 
 def test_span_threading_isolated_stacks():
@@ -77,18 +77,151 @@ def test_span_threading_isolated_stacks():
     # each thread saw only its own stack; both were visible concurrently
     assert _stack_of(inner_seen, t) == ["bg"]
     assert _stack_of(inner_seen) == ["fg"]
-    win = c.pop_window()
+    win, _ = c.drain()
     assert win["bg"][1] == 1 and win["fg"][1] == 1
 
 
-def test_span_disabled_is_noop():
+class _CountingAnnotation:
+    """Stands where `jax.profiler.TraceAnnotation` would (obs/spans.py
+    takes the class lazily): counts what is built, keeps the arguments."""
+
+    built = []
+
+    def __init__(self, name, **kwargs):
+        type(self).built.append((name, kwargs))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture()
+def counting_annotation(monkeypatch):
+    from pytorchvideo_accelerate_tpu.obs import spans
+
+    _CountingAnnotation.built = []
+    monkeypatch.setattr(spans, "_annotation_cls", _CountingAnnotation)
+    return _CountingAnnotation.built
+
+
+def test_span_disabled_is_noop(counting_annotation):
     c = SpanCollector(enabled=False)
     with c.span("x"):
         pass
-    c.observe("y", 1.0)
-    assert c.pop_window() == {}
-    # the disabled path returns a shared no-op: no per-call allocation
+    assert c.drain() == ({}, {})
+    # the disabled path returns a shared no-op: no per-call allocation,
+    # and nothing is built for the profiler either
     assert c.span("a") is c.span("b")
+    assert c.span("a", step=3) is c.span("b")
+    # the caller that needs the duration still gets it, and nothing else
+    with c.timed_span("input_wait") as w:
+        time.sleep(0.002)
+    assert w.dur_s >= 0.002
+    assert c.drain() == ({}, {}) and c.current_stacks() == {}
+    assert counting_annotation == []
+
+
+def test_span_is_an_annotation_on_the_profilers_clock(counting_annotation):
+    """Every span enters a `pva/<name>` TraceAnnotation; the step rides as
+    `step_num` and children inherit it; per-sample `decode` stays
+    aggregate-only."""
+    c = SpanCollector()
+    with c.span("iter", step=41):
+        with c.span("input_wait"):
+            pass
+        with c.span("decode"):
+            pass
+    with c.span("sync"):
+        pass
+    assert counting_annotation == [
+        ("pva/iter", {"step_num": 41}),
+        ("pva/input_wait", {"step_num": 41}),
+        ("pva/sync", {}),
+    ]
+    assert c.drain()[0]["decode"][1] == 1
+
+
+def test_obs_imports_without_jax():
+    """obs/ is stdlib-importable and a process without jax builds no
+    annotation (and never imports jax to get one)."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from pytorchvideo_accelerate_tpu import obs\n"
+        "from pytorchvideo_accelerate_tpu.obs import spans\n"
+        "with obs.span('iter', step=1) as s:\n"
+        "    pass\n"
+        "assert s._ann is None and spans._annotation() is None\n"
+        "assert 'jax' not in sys.modules, 'obs imported jax'\n"
+        "print('ok', s.dur_s >= 0.0)\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok True"
+
+
+def test_span_parents_and_self_time():
+    """The collector learns parents from the per-thread stack: a span's
+    self time is its duration less what its children cover, so the self
+    times of one thread's spans sum to the wall time it spent in any."""
+    c = SpanCollector()
+    t0 = time.perf_counter()
+    with c.span("iter", step=7) as it:
+        with c.span("input_wait") as w:
+            time.sleep(0.02)
+        with c.span("step") as st:
+            time.sleep(0.01)
+            with c.span("inner") as inner:
+                time.sleep(0.01)
+        time.sleep(0.01)  # the loop's own
+    wall = time.perf_counter() - t0
+    assert (w.step, st.step, inner.step) == (7, 7, 7)
+    assert it.dur_s >= w.dur_s + st.dur_s and st.dur_s >= inner.dur_s
+    assert it.self_s == pytest.approx(it.dur_s - w.dur_s - st.dur_s)
+    assert st.self_s == pytest.approx(st.dur_s - inner.dur_s)
+    assert 0.01 <= it.self_s < 0.02 + 0.01
+    spans, by_thread = c.drain()
+    assert spans["iter"] == (it.dur_s, 1, it.self_s)
+    assert spans["input_wait"][2] == spans["input_wait"][0]  # a leaf
+    mine = by_thread[threading.get_ident()]
+    assert mine == pytest.approx(it.dur_s)
+    assert mine == pytest.approx(sum(v[2] for v in spans.values()))
+    assert abs(wall - mine) < 0.005
+    assert c.drain() == ({}, {})
+
+
+def test_worker_spans_never_become_children_of_the_loops():
+    c = SpanCollector()
+    inside = threading.Event()
+    done = threading.Event()
+    seen = {}
+
+    def worker():
+        inside.wait(timeout=5)
+        with c.span("h2d") as h:
+            time.sleep(0.02)
+        seen["h2d"] = h
+        done.set()
+
+    t = threading.Thread(target=worker, name="zobs-worker")
+    t.start()
+    with c.span("iter", step=3) as it:
+        inside.set()
+        done.wait(timeout=5)
+    t.join(timeout=5)
+    assert not t.is_alive()
+    # the worker's span ran wholly inside the loop's iter, on another
+    # thread: no step inherited, nothing taken from the loop's self time
+    assert seen["h2d"].step is None
+    assert it.self_s == it.dur_s >= seen["h2d"].dur_s
+    spans, by_thread = c.drain()
+    assert by_thread[threading.get_ident()] == pytest.approx(it.dur_s)
+    assert by_thread[t.ident] == pytest.approx(seen["h2d"].dur_s)
 
 
 def test_spans_feed_flight_recorder():
@@ -103,8 +236,101 @@ def test_spans_feed_flight_recorder():
     events = rec.snapshot()
     assert [e["name"] for e in events if e["kind"] == "span"] == ["h2d"]
     assert events[-1]["dur_s"] >= 0.0
-    win = c.pop_window()
+    # an interval, not a duration stamped at its end: t0 is the start
+    assert events[-1]["t0"] <= events[-1]["ts"]
+    assert events[-1]["ts"] - events[-1]["t0"] < 1.0
+    assert "step" not in events[-1]
+    with c.span("iter", step=12):
+        with c.span("log"):
+            pass
+    assert [(e["name"], e["step"]) for e in rec.snapshot()[-2:]] == [
+        ("log", 12), ("iter", 12)]
+    win, _ = c.drain()
     assert win["decode"][1] == 1  # aggregated even though not recorded
+
+
+def test_discarded_span_leaves_no_record():
+    """A section that turned out not to be one (the loop's `iter` when the
+    epoch had run out): nothing is aggregated or put in the flight ring
+    for it, its children are recorded as ever, and its time stays its
+    parent's own."""
+    rec = FlightRecorder(capacity=32)
+    c = SpanCollector(recorder=rec)
+    with c.span("epoch") as outer:
+        with c.span("iter", step=7) as it:
+            with c.span("input_wait"):
+                time.sleep(0.002)
+            it.discard()
+        assert c.current_stacks()[
+            f"{threading.current_thread().name}-{threading.get_ident()}"
+        ] == ["epoch"]
+    spans, by_thread = c.drain()
+    assert sorted(spans) == ["epoch", "input_wait"]
+    assert [(e["name"], e.get("step")) for e in rec.snapshot()
+            if e["kind"] == "span"] == [("input_wait", 7), ("epoch", None)]
+    assert outer.self_s == outer.dur_s  # no recorded child covered it
+    assert by_thread[threading.get_ident()] == pytest.approx(
+        outer.dur_s + spans["input_wait"][0])
+    # the disabled path's shared no-op takes the call too
+    SpanCollector(enabled=False).span("iter").discard()
+
+
+@pytest.mark.parametrize("depth", [2, 0])
+def test_input_wait_is_a_span_and_feeds_wait_s(mesh8, depth):
+    """The prefetcher's blocking wait is a real span (a start, a step from
+    its parent) and `wait_s` is fed from the same two clock reads: the two
+    totals are equal, not close. With depth 0 the placement runs inline,
+    as a child of the wait."""
+    from pytorchvideo_accelerate_tpu.data.device_prefetch import (
+        DevicePrefetcher,
+    )
+    from pytorchvideo_accelerate_tpu.data.pipeline import (
+        ClipLoader,
+        SyntheticClipSource,
+    )
+    from pytorchvideo_accelerate_tpu.data.transforms import make_transform
+
+    tf = make_transform(num_frames=4, training=False, crop_size=32,
+                        min_short_side_scale=32)
+    loader = ClipLoader(SyntheticClipSource(tf, num_videos=32,
+                                            num_classes=4),
+                        global_batch_size=8, num_workers=2)
+    pf = DevicePrefetcher(loader, mesh8, depth=depth)
+    collector = obs.get_collector()
+    collector.drain()
+    batches = pf.epoch(0)
+    taken = 0
+    while True:
+        with obs.span("iter", step=100 + taken):
+            assert 0 <= pf.ready() <= max(depth, 0)
+            if next(batches, None) is None:
+                break
+        taken += 1
+    assert taken == 4
+    spans, by_thread = collector.drain()
+    total, count, self_s = spans["input_wait"]
+    # depth 2: four batches, the epoch's rollover marker and its end (both
+    # met by the asking that found no batch); depth 0: the four placements
+    assert count == (6 if depth else 4)
+    assert pf.wait_s == pytest.approx(total, rel=1e-12, abs=0.0)
+    assert pf.pop_wait() == pytest.approx(total, rel=1e-12, abs=0.0)
+    waits = [e for e in obs.get_recorder().snapshot()
+             if e["kind"] == "span" and e["name"] == "input_wait"][-count:]
+    assert [e["step"] for e in waits] == (
+        [100, 101, 102, 103] + [104] * (count - 4))
+    if depth == 0:
+        assert spans["h2d"][1] == 4 and self_s < total  # h2d nests inside
+    else:
+        assert self_s == total and spans["batch"][1] >= 4
+    # nothing the loop's thread did was counted twice
+    assert by_thread[threading.get_ident()] == pytest.approx(
+        spans["iter"][0])
+    # telemetry off: no span, and wait_s is still fed
+    obs.configure(enabled=False)
+    loader.state = type(loader.state)()
+    assert len(list(pf.epoch(0))) == 4
+    assert pf.pop_wait() > 0.0 and collector.drain() == ({}, {})
+    loader.close()
 
 
 # --- flight recorder --------------------------------------------------------
@@ -523,8 +749,10 @@ def _read_jsonl(cfg):
 
 def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     """obs.enabled=true (the default): the per-window step-time breakdown
-    is logged, its consumer-side components sum to within 10% of measured
-    window wall time, and fit() returns the span-sourced obs keys."""
+    is logged with the iteration as the parent span, the SELF times of the
+    loop's spans sum to the measured window wall time (so what is left
+    unattributed is near zero), and fit() returns the span-sourced obs
+    keys and one record a step."""
     from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
 
     cfg = _cfg(tmp_path)
@@ -542,26 +770,195 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
                if "obs/window_wall_s" in ln and "obs/step_s" in ln
                and "obs/eval_s" not in ln]
     assert windows, f"no train obs windows logged: {lines}"
-    # components sum to wall within 10%, asserted over the AGGREGATE of
+    # self times sum to wall within 10%, asserted over the AGGREGATE of
     # the train windows: a single scheduler/GC pause can blow any one
     # sub-100ms window without any product bug (plus a small absolute
-    # floor for sub-ms aggregates)
-    total_wall = total_consumer = 0.0
+    # floor for sub-ms aggregates). A parent span logs `_self_s` beside
+    # `_s`; a leaf's self time is its total.
+    total_wall = total_self = total_unattributed = 0.0
     for w in windows:
+        assert "obs/iter_s" in w and "obs/iter_self_s" in w, w
+        assert w["obs/iter_s"] >= (w["obs/step_s"] + w["obs/input_wait_s"]
+                                   + w.get("obs/log_s", 0.0))
         total_wall += w["obs/window_wall_s"]
-        total_consumer += sum(
-            v for k, v in w.items()
-            if k.startswith("obs/") and k.endswith("_s")
-            and k not in ("obs/window_wall_s", "obs/unattributed_s")
-            and k[4:-2] not in BACKGROUND)
-    assert abs(total_wall - total_consumer) <= max(0.10 * total_wall, 0.02), \
-        (total_wall, total_consumer, windows)
+        total_unattributed += w["obs/unattributed_s"]
+        names = {k[4:-2] for k in w
+                 if k.startswith("obs/") and k.endswith("_s")
+                 and not k.endswith("_self_s")
+                 and k not in ("obs/window_wall_s", "obs/unattributed_s")}
+        total_self += sum(w.get(f"obs/{n}_self_s", w[f"obs/{n}_s"])
+                          for n in names - BACKGROUND)
+    assert abs(total_wall - total_self) <= max(0.10 * total_wall, 0.02), \
+        (total_wall, total_self, windows)
+    # the same statement as the log makes it: the remainder, once most of
+    # the loop's time, is now what lies between two iterations
+    assert total_unattributed == pytest.approx(total_wall - total_self,
+                                               abs=1e-6)
+    # one `iter` a step: the asking that found the epoch at its end is no
+    # iteration (its wait is `input_wait`'s alone)
+    assert [e["step"] for e in obs.get_recorder().snapshot()
+            if e["kind"] == "span" and e["name"] == "iter"
+            ][-result["steps"]:] == list(range(result["steps"]))
+    # one record a step, newest last, children inside the parent
+    records = result["step_records"]
+    assert [r["gstep"] for r in records] == list(range(result["steps"]))
+    for r in records:
+        assert set(r) == {"gstep", "t0_ns", "iter", "input_wait", "step",
+                          "log", "ready"}
+        assert r["iter"] >= r["input_wait"] + r["step"] + r["log"] > 0.0
+        assert 0 <= r["ready"] <= cfg.data.device_prefetch_depth
+    assert [r["t0_ns"] for r in records] == sorted(r["t0_ns"]
+                                                   for r in records)
+    on_disk = [json.loads(ln) for ln in
+               open(tmp_path / "step_records.jsonl").read().splitlines()]
+    assert on_disk == records
     # health gauges rode the step logs and landed in the registry
     step_logs = [ln for ln in lines if "obs/param_norm" in ln]
     assert step_logs and step_logs[-1]["obs/param_norm"] > 0.0
     assert obs.get_registry().gauge("pva_train_grad_norm").value() > 0.0
     # eval got its own span in the timeline
     assert any("obs/eval_s" in ln for ln in lines)
+
+
+def test_zz_fit_spans_on_the_profilers_clock(tmp_path, _tiny_slow_r50):
+    """A tiny fit() under --obs.profile_steps: the one capture path writes
+    a trace whose host plane holds the loop's spans as `pva/*`
+    annotations, nested as the collector nests them, carrying the record's
+    gstep, and started when the record says (one clock)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
+
+    cfg = _cfg(tmp_path, **{"obs.profile_steps": "2..7",
+                            "data.synthetic_num_videos": 72,
+                            "data.limit_val_batches": 0})
+    result = Trainer(cfg).fit()
+    assert result["steps"] == 9  # 72 clips, one a device on the 8-mesh
+    pbs = glob.glob(str(tmp_path / "profile_steps_2_7" / "**" / "*.xplane.pb"),
+                    recursive=True)
+    assert len(pbs) == 1, list(tmp_path.rglob("*"))
+    data = ProfileData.from_file(pbs[0])
+    start_ns = None
+    found = {}  # name -> [(thread line, start, end, step_num)]
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            start_ns = dict(plane.stats)["profile_start_time"]
+        if plane.name != "/host:CPU":
+            continue
+        # a line is a thread; the lines' names collide ("python"), so a
+        # thread is known by its line's place in the plane
+        for thread, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("pva/") or ev.name == "train":
+                    found.setdefault(ev.name, []).append(
+                        (thread, ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats).get("step_num")))
+    assert start_ns is not None
+    for name in ("pva/capture", "pva/iter", "pva/input_wait", "pva/step",
+                 "pva/log", "pva/h2d", "pva/batch", "train"):
+        assert found.get(name), (name, sorted(found))
+    assert "pva/decode" not in found
+    loop_line = found["pva/iter"][0][0]
+    assert {ln for ln, *_ in found["pva/step"] + found["pva/input_wait"]
+            + found["pva/log"]} == {loop_line}
+    assert loop_line not in {ln for ln, *_ in found["pva/h2d"]
+                             + found["pva/batch"]}
+    # the window opens and closes between two iterations: 2..6 are whole,
+    # with their children inside
+    iters = {step: (a, b) for _ln, a, b, step in found["pva/iter"]}
+    assert sorted(iters) == [2, 3, 4, 5, 6]
+    for name in ("pva/step", "pva/input_wait", "pva/log", "train"):
+        for _ln, a, b, step in found[name]:
+            if step in iters:
+                assert iters[step][0] <= a and b <= iters[step][1], (name,
+                                                                     step)
+    for name in ("pva/step", "pva/input_wait", "pva/log", "train"):
+        assert sorted(s for *_x, s in found[name]) == [2, 3, 4, 5, 6], name
+    # one clock: a record's time_ns() start is its annotation's start
+    records = {r["gstep"]: r for r in result["step_records"]}
+    for step, (a, _b) in iters.items():
+        assert abs(records[step]["t0_ns"] - (start_ns + a)) < 5e6, step
+    # the capture's own mark lies before the first traced dispatch
+    assert found["pva/capture"][0][1] <= min(a for _l, a, _b, _s
+                                             in found["train"])
+    assert len(result["step_records"]) == 9
+    # limit_val_batches 0: no eval pass, so no eval span and no val batch
+    assert not any("obs/eval_s" in ln for ln in _read_jsonl(cfg))
+
+
+def test_zz_capture_closes_when_a_break_ends_the_epoch(tmp_path,
+                                                       _tiny_slow_r50):
+    """Step B-1 is the epoch's last and `limit_train_batches` breaks out of
+    the loop, so no iteration follows: the capture still closes once that
+    iteration has ended, before the epoch's value fetch and the eval pass
+    (a trace left open through them is what ran a host out of memory)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
+
+    cfg = _cfg(tmp_path, **{"obs.profile_steps": "1..3",
+                            "data.synthetic_num_videos": 72,  # 9 steps
+                            "data.limit_train_batches": 3})
+    result = Trainer(cfg).fit()
+    assert result["steps"] == 3
+    pbs = glob.glob(str(tmp_path / "profile_steps_1_3" / "**" / "*.xplane.pb"),
+                    recursive=True)
+    assert len(pbs) == 1, list(tmp_path.rglob("*"))
+    names = {}
+    for plane in ProfileData.from_file(pbs[0]).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("pva/"):
+                        names.setdefault(ev.name, []).append(
+                            dict(ev.stats).get("step_num"))
+    assert sorted(names["pva/iter"]) == [1, 2]
+    assert not {"pva/sync", "pva/eval", "pva/ckpt"} & set(names), names
+    assert any("obs/eval_s" in ln for ln in _read_jsonl(cfg))  # eval ran
+
+
+def test_zz_step_observers_and_no_needless_eval(tmp_path, _tiny_slow_r50):
+    """`Trainer.step_observers`: every observer sees every step, inside
+    the iteration, and a true value ends the epoch as limit_train_batches
+    does (value fetch, residual window, final save). With
+    data.limit_val_batches 0 the eval step is never called."""
+    from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
+
+    cfg = _cfg(tmp_path, **{"data.limit_val_batches": 0,
+                            "data.synthetic_num_videos": 64})  # 8 steps
+    tr = Trainer(cfg)
+    seen_a, seen_b = [], []
+
+    def stop_at_five(gstep, trainer):
+        assert trainer is tr
+        # inside the iteration's span, after the step was dispatched
+        assert obs.current_stacks()[
+            f"{threading.current_thread().name}-{threading.get_ident()}"
+        ] == ["iter"]
+        seen_a.append(gstep)
+        return gstep >= 5
+
+    def watch(gstep, trainer):
+        seen_b.append(len(trainer.step_records))
+        return None
+
+    tr.step_observers += [stop_at_five, watch]
+
+    def no_eval(state, batch):
+        raise AssertionError("eval step called with limit_val_batches 0")
+
+    tr.eval_step = no_eval
+    result = tr.fit()
+    assert result["steps"] == 5 and seen_a == [1, 2, 3, 4, 5]
+    # the second observer ran at the stopping step too; a step's record is
+    # appended when its iteration closes, after the observers
+    assert seen_b == [0, 1, 2, 3, 4]
+    assert len(result["step_records"]) == 5
+    assert result["val_accuracy"] == 0.0 and np.isfinite(result["train_loss"])
 
 
 def test_zz_obs_disabled_restores_prior_logging_keys(tmp_path,
@@ -591,9 +988,13 @@ def test_zz_fit_exception_dumps_flight_record(tmp_path, _tiny_slow_r50):
 
     cfg = _cfg(tmp_path)
     tr = Trainer(cfg)
+    real_step, calls = tr.train_step, []
 
     def boom(state, batch, key):
-        raise RuntimeError("injected step failure")
+        calls.append(1)
+        if len(calls) > 1:
+            raise RuntimeError("injected step failure")
+        return real_step(state, batch, key)
 
     tr.train_step = boom
     with pytest.raises(RuntimeError, match="injected step failure"):
@@ -602,6 +1003,18 @@ def test_zz_fit_exception_dumps_flight_record(tmp_path, _tiny_slow_r50):
     exc = [e for e in data["events"] if e["kind"] == "exception"]
     assert exc and exc[-1]["name"] == "RuntimeError"
     assert "injected step failure" in exc[-1]["message"]
+    # the spans of the ring are intervals that carry the step they belong to
+    spans = [e for e in data["events"] if e["kind"] == "span"]
+    assert {"iter", "step", "input_wait"} <= {e["name"] for e in spans}
+    failed = [e for e in spans if e.get("error")]
+    assert {(e["name"], e["step"]) for e in failed} == {("step", 1),
+                                                        ("iter", 1)}
+    assert all(e["t0"] <= e["ts"] for e in spans)
+    # and the records of the iterations that did dispatch are beside it
+    # (the failing iteration's step span closed too: its record is there)
+    records = [json.loads(ln) for ln in
+               open(tmp_path / "step_records.jsonl").read().splitlines()]
+    assert [r["gstep"] for r in records] == [0, 1]
 
 
 def test_zz_stalled_train_loop_trips_watchdog(tmp_path, _tiny_slow_r50,

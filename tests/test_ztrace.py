@@ -85,7 +85,7 @@ def test_disarmed_structural_zero_overhead():
 
     with obs.span("ztrace_unit") as s:
         assert s._trace is None
-    obs.get_collector().pop_window()  # leave no residue for other tests
+    obs.get_collector().drain()  # leave no residue for other tests
 
 
 def test_configure_zero_rate_disarms():
@@ -139,7 +139,7 @@ def test_obs_span_joins_active_trace(tracer):
     with tracer.start("step_root", force=True, gstep=7) as h:
         with obs.span("ztrace_step"):
             pass
-    obs.get_collector().pop_window()
+    obs.get_collector().drain()
     events = tracer.export()["traceEvents"]
     spans = [e for e in events if e["name"] == "ztrace_step"]
     assert spans and spans[0]["args"]["trace_id"] == h.ctx.trace_id
@@ -322,6 +322,8 @@ def test_merge_includes_flight_record(tracer, tmp_path):
         pass
     rec = FlightRecorder(capacity=32)
     rec.record("watchdog", "stall", stalled=["train"])
+    # a span of the flight ring carries its start: an interval in the merge
+    rec.record("span", "iter", t0=1700000000.25, dur_s=0.5, step=7)
     flight = tmp_path / "flight_record.json"
     rec.install(str(tmp_path))
     assert rec.dump() == str(flight)
@@ -332,6 +334,9 @@ def test_merge_includes_flight_record(tracer, tmp_path):
     assert phases == {"X", "i"}  # spans + instants on one timeline
     ts = [e["ts"] for e in merged["traceEvents"]]
     assert ts == sorted(ts)
+    span = [e for e in merged["traceEvents"] if e["name"] == "span:iter"]
+    assert [(e["ph"], e["ts"], e["dur"], e["args"]["step"]) for e in span] \
+        == [("X", 1700000000.25e6, 0.5e6, 7)]
 
 
 def test_multiprocess_merge_two_forced_children(tmp_path):
