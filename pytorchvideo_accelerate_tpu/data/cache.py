@@ -209,17 +209,22 @@ class CachedClipSource:
     def __len__(self) -> int:
         return len(self.cache)
 
-    def get(self, index: int, epoch: int) -> Dict[str, np.ndarray]:
-        from pytorchvideo_accelerate_tpu.data.pipeline import sample_views
+    def get(self, index: int, epoch: int,
+            out: Optional[Dict[str, np.ndarray]] = None
+            ) -> Dict[str, np.ndarray]:
+        from pytorchvideo_accelerate_tpu.data.pipeline import (
+            label_row,
+            sample_views,
+        )
 
         rng = np.random.default_rng((self.seed, epoch, index))
-        out = sample_views(
+        sample = sample_views(
             lambda a, b: self.cache.read(index, a, b), self.transform,
             self.cache.duration(index), self.clip_duration, self.training,
-            rng, self.num_clips,
+            rng, self.num_clips, out,
         )
-        out["label"] = np.int32(self.cache.label(index))
-        return out
+        sample["label"] = label_row(self.cache.label(index), out)
+        return sample
 
 
 def measure_clip_throughput(fetch: Callable[[int], np.ndarray], n_items: int,

@@ -12,8 +12,10 @@ become:
   `DistributedSampler`/`BatchSamplerShard` equivalent, without padding
   duplicates: val tail batches carry an explicit mask instead),
 - a thread-pool decode pool (cv2 releases the GIL; threads give native
-  decode parallelism without fork overhead) with one-batch-ahead prefetch
-  (`DataLoaderShard.__iter__` prefetch semantics, data_loader.py:576-610),
+  decode parallelism without fork overhead) whose workers write each clip
+  straight into its row of the batch, fed `prefetch_batches` batches ahead
+  with no barrier between batches (`DataLoaderShard.__iter__` prefetch
+  semantics, data_loader.py:576-610),
 - checkpointable iterator state {epoch, position} (extends checkpoint
   capability A8 to data, replacing the reference's skip-batches resume at
   run.py:246-249 with an O(1) index fast-forward).
@@ -26,10 +28,11 @@ independent deterministic sample.
 
 from __future__ import annotations
 
+import inspect
 import logging
+from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from queue import Empty, Queue
 from typing import Callable, Dict, Iterator, List, Optional
 
 import numpy as np
@@ -44,6 +47,7 @@ from pytorchvideo_accelerate_tpu.data.samplers import (
     substitute_indices,
     uniform_clips,
 )
+from pytorchvideo_accelerate_tpu.data.transforms import resize_on_calling_thread
 
 logger = logging.getLogger(__name__)
 
@@ -61,13 +65,30 @@ class ClipSource:
     def __len__(self) -> int:  # pragma: no cover - interface
         raise NotImplementedError
 
-    def get(self, index: int, epoch: int) -> Dict[str, np.ndarray]:  # pragma: no cover
+    def get(self, index: int, epoch: int,
+            out: Optional[Dict[str, np.ndarray]] = None
+            ) -> Dict[str, np.ndarray]:  # pragma: no cover
+        """`out`, where a source takes it: the sample's rows of the batch
+        being built, one preallocated array a key (`ClipLoader`'s thread
+        path). The source writes what it can into them and returns those
+        very arrays; whatever it returns as an array of its own the loader
+        copies in. A subclass may keep the two-argument signature."""
         raise NotImplementedError
+
+
+def label_row(label: int, out: Optional[Dict[str, np.ndarray]]):
+    """The sample's label: written into its row where the loader gave one."""
+    if out is None:
+        return np.int32(label)
+    out["label"][...] = label
+    return out["label"]
 
 
 def sample_views(read_span: Callable, transform: Callable, duration: float,
                  clip_duration: float, training: bool,
-                 rng: np.random.Generator, num_clips: int) -> Dict[str, np.ndarray]:
+                 rng: np.random.Generator, num_clips: int,
+                 out: Optional[Dict[str, np.ndarray]] = None
+                 ) -> Dict[str, np.ndarray]:
     """Shared span-selection + multi-view stacking for every clip source.
 
     Train: ONE random span. Eval: `num_clips` evenly-spaced spans — times
@@ -76,6 +97,12 @@ def sample_views(read_span: Callable, transform: Callable, duration: float,
     stacked on ONE leading view axis, temporal-major (the eval step
     view-averages the logits; reference uniform tiling, run.py:163).
     `read_span(start_sec, end_sec) -> (T, H, W, 3) uint8`.
+
+    `out` (the loader's rows for this sample, `ClipSource.get`) goes on to a
+    transform that `writes_rows`: each view is written where the stack
+    would have put it (`out[key][view]`; `out[key]` for the single view)
+    and the rows themselves are returned. Any other transform returns its
+    own arrays, as without `out`.
     """
     # training transforms can't carry spatial crops (make_transform forbids
     # it), so the attribute alone decides — this also serves sources that
@@ -86,16 +113,33 @@ def sample_views(read_span: Callable, transform: Callable, duration: float,
         spans = [random_clip(duration, clip_duration, rng)]
     else:
         spans = uniform_clips(duration, clip_duration, num_clips)
+    n_views = len(spans) * n_spatial
+    writes = out is not None and getattr(transform, "writes_rows", False)
+    if not writes:
+        rows = [None] * n_views
+    elif n_views == 1:  # no view axis for the single-view case
+        rows = [out]
+    else:
+        rows = [{k: v[j] for k, v in out.items() if k != "label"}
+                for j in range(n_views)]
     if n_spatial > 1:
         # decode AND pre-crop once per span; spatial_views applies the
         # n_spatial crops to the shared scaled frames
         views = []
-        for s in spans:
-            views.extend(transform.spatial_views(read_span(s.start, s.end)))
+        for i, s in enumerate(spans):
+            frames = read_span(s.start, s.end)
+            views.extend(
+                transform.spatial_views(
+                    frames, rows[i * n_spatial:(i + 1) * n_spatial])
+                if writes else transform.spatial_views(frames))
     else:
-        views = [transform(read_span(s.start, s.end), rng) for s in spans]
-    if len(views) == 1:  # no view axis for the single-view case
+        views = [transform(read_span(s.start, s.end), rng, out=r) if writes
+                 else transform(read_span(s.start, s.end), rng)
+                 for s, r in zip(spans, rows)]
+    if n_views == 1:
         return views[0]
+    if writes:  # every view is already where the stack would put it
+        return {k: out[k] for k in views[0]}
     return {k: np.stack([v[k] for v in views]) for k in views[0]}
 
 
@@ -185,7 +229,9 @@ class VideoClipSource(ClipSource):
                 self._meta_cache[path] = meta
         return meta
 
-    def get(self, index: int, epoch: int) -> Dict[str, np.ndarray]:
+    def get(self, index: int, epoch: int,
+            out: Optional[Dict[str, np.ndarray]] = None
+            ) -> Dict[str, np.ndarray]:
         idx = index
         for attempt in range(self._MAX_CONSECUTIVE_FAILURES):
             # each attempt gets its OWN rng stream: reproducibility across
@@ -242,16 +288,18 @@ class VideoClipSource(ClipSource):
                     mark_failed(e)
                 else:
                     try:
-                        out = sample_views(
+                        # a substitute writes the same rows again: the
+                        # transform writes a view only once it is whole
+                        sample = sample_views(
                             read_span, self.transform, meta.duration,
                             self.clip_duration, self.training, rng,
-                            self.num_clips,
+                            self.num_clips, out,
                         )
                     except _DecodeFailure as e:
                         mark_failed(e)
                     else:
-                        out["label"] = np.int32(entry.label)
-                        return out
+                        sample["label"] = label_row(entry.label, out)
+                        return sample
             # deterministic replacement, also attempt-keyed
             idx = int(np.random.default_rng(
                 (self.seed, 0xBAD, epoch, index, attempt)
@@ -287,7 +335,9 @@ class SyntheticClipSource(ClipSource):
     def __len__(self) -> int:
         return self.num_videos
 
-    def get(self, index: int, epoch: int) -> Dict[str, np.ndarray]:
+    def get(self, index: int, epoch: int,
+            out: Optional[Dict[str, np.ndarray]] = None
+            ) -> Dict[str, np.ndarray]:
         label = index % self.num_classes
         rng = np.random.default_rng((self.seed, epoch, index))
         h, w = self.raw_size
@@ -297,11 +347,11 @@ class SyntheticClipSource(ClipSource):
             frames += np.uint8(label * (160 // max(self.num_classes - 1, 1)))
             return frames
 
-        out = sample_views(synth_span, self.transform, 1.0, 1.0,
-                           training=self.num_clips == 1, rng=rng,
-                           num_clips=self.num_clips)
-        out["label"] = np.int32(label)
-        return out
+        sample = sample_views(synth_span, self.transform, 1.0, 1.0,
+                              training=self.num_clips == 1, rng=rng,
+                              num_clips=self.num_clips, out=out)
+        sample["label"] = label_row(label, out)
+        return sample
 
 
 def stack_samples(arrs: List[np.ndarray]) -> np.ndarray:
@@ -345,6 +395,71 @@ def assemble_batch(samples: List[Dict[str, np.ndarray]], pad_to: int,
             for k, v in batch.items()
         }
     return batch
+
+
+def _takes_out(get: Callable) -> bool:
+    """Does a source's `get` accept the rows to write (`out=`)?"""
+    try:
+        params = inspect.signature(get).parameters
+    except (TypeError, ValueError):  # pragma: no cover - a C callable
+        return False
+    return "out" in params or any(
+        p.kind is p.VAR_KEYWORD for p in params.values())
+
+
+def _row_views(rows: Dict[str, np.ndarray], r: int) -> Dict[str, np.ndarray]:
+    """Sample `r`'s rows of a batch's buffers (views; `label`'s is 0-d)."""
+    return {k: v[r, ...] for k, v in rows.items() if k != "mask"}
+
+
+def _fill_rows(rows: Dict[str, np.ndarray],
+               sample: Dict[str, np.ndarray]) -> bool:
+    """Copy in whatever `sample` holds as arrays of its own; True when it
+    held none (the source wrote every row itself). A sample that does not
+    fit the loader's rows is refused, as a ragged stack was."""
+    if sample.keys() != rows.keys():
+        raise ValueError(f"sample keys {sorted(sample)} differ from the "
+                         f"loader's rows {sorted(rows)}")
+    in_place = True
+    for k, row in rows.items():
+        v = sample[k]
+        if v is row:
+            continue
+        in_place = False
+        if np.shape(v) != row.shape:
+            raise ValueError(f"sample[{k!r}] has shape {np.shape(v)}, the "
+                             f"loader's rows hold {row.shape}")
+        np.copyto(row, v, casting="unsafe")
+    return in_place
+
+
+def _row_counters() -> tuple:
+    """`pva_loader_rows_in_place`, `pva_loader_rows_copied`: rows of yielded
+    batches that the source wrote itself, and rows a worker copied in from
+    arrays the source returned (docs/OBSERVABILITY.md)."""
+    reg = obs.get_registry()
+    return (reg.counter("pva_loader_rows_in_place",
+                        "batch rows written in place by the clip source"),
+            reg.counter("pva_loader_rows_copied",
+                        "batch rows copied in from arrays the source returned"))
+
+
+class LoaderRowCounts:
+    """The two row counters read window by window (the trainer's per-window
+    log, beside `obs/batch_s`)."""
+
+    def __init__(self):
+        self._counters = _row_counters()
+        self._seen = [c.total() for c in self._counters]
+
+    def window_share(self) -> Optional[float]:
+        """Rows written in place over all rows yielded since the last call;
+        None when no batch was yielded."""
+        now = [c.total() for c in self._counters]
+        in_place, copied = (a - b for a, b in zip(now, self._seen))
+        self._seen = now
+        rows = in_place + copied
+        return in_place / rows if rows else None
 
 
 @dataclass
@@ -406,6 +521,13 @@ class ClipLoader:
         self.prefetch_batches = prefetch_batches
         self.state = LoaderState()
         self._pool = ThreadPoolExecutor(max_workers=self.num_workers)
+        # thread path: does the source write its sample into the rows it is
+        # handed (`get(index, epoch, out=rows)`), or return arrays of its
+        # own for the worker to copy in? Decided by what its `get` accepts
+        self._source_writes_rows = _takes_out(source.get)
+        # {key: (sample shape, dtype)}, learnt from the first sample this
+        # loader produces and held: what a batch's buffers are made from
+        self._row_spec: Optional[Dict[str, tuple]] = None
         # "process": forked decode workers + native shm ring (SURVEY N8);
         # falls back to threads when the native lib can't build.
         # "auto" = threads. Every measurement to date says so: cv2 decode
@@ -419,6 +541,10 @@ class ClipLoader:
         # (heavy pure-Python per-clip work), where the fork + shm-ring
         # overhead can pay for itself.
         self.transport = "thread" if transport == "auto" else transport
+        if self.transport == "thread" and self.num_workers > 1:
+            # the decode pool is the parallelism; cv2's own, inside every
+            # worker's resize, would oversubscribe a pool that is kept full
+            resize_on_calling_thread()
         self._shm_pool = None
         if self.transport == "process":
             import pytorchvideo_accelerate_tpu.native as native
@@ -462,13 +588,31 @@ class ClipLoader:
 
     # --- iteration --------------------------------------------------------
 
-    @staticmethod
-    def _stack(arrs: List[np.ndarray]) -> np.ndarray:
-        return stack_samples(arrs)
-
     def _assemble(self, samples: List[Dict[str, np.ndarray]], pad_to: int) -> dict:
         return assemble_batch(samples, pad_to, accum_steps=self.accum_steps,
                               local_batch_size=self.local_batch_size)
+
+    def _new_rows(self, n: int) -> Dict[str, np.ndarray]:
+        """One fresh buffer a key for a batch of `n` real samples, `mask`
+        included where `n` falls short (the padded val tail: unused rows
+        zero, as `assemble_batch` pads). Never reused: a yielded batch may
+        still be read by an asynchronous transfer, or aliased by a CPU
+        `device_put`."""
+        spy = self.samples_per_yield
+        make = np.empty if n == spy else np.zeros
+        rows = {k: make((spy, *shape), dtype)
+                for k, (shape, dtype) in self._row_spec.items()}
+        if n < spy:
+            rows["mask"] = np.zeros(spy, np.float32)
+            rows["mask"][:n] = 1.0
+        return rows
+
+    def _shaped(self, rows: Dict[str, np.ndarray]) -> dict:
+        """The filled buffers as the batch the step takes."""
+        if self.accum_steps == 1:
+            return rows
+        return {k: v.reshape(self.accum_steps, self.local_batch_size,
+                             *v.shape[1:]) for k, v in rows.items()}
 
     def epoch(self, epoch: Optional[int] = None,
               from_start: bool = False) -> Iterator[dict]:
@@ -505,57 +649,73 @@ class ClipLoader:
                 epoch, start_state.position, indices, n_batches)
             return
 
-        def fetch_one(i) -> Dict[str, np.ndarray]:
+        source, writes_rows = self.source, self._source_writes_rows
+        rows_in_place, rows_copied = _row_counters()
+
+        def decode(i: int, rows: Dict[str, np.ndarray]) -> bool:
             # obs "decode" span: per-sample decode+transform wall time on
             # the worker threads (background-classed — it overlaps the
-            # consumer loop, so it informs, never sums into, window wall)
+            # consumer loop, so it informs, never sums into, window wall).
+            # True: the source wrote every row itself
             with obs.span("decode"):
-                return self.source.get(int(i), epoch)
+                sample = (source.get(i, epoch, out=rows) if writes_rows
+                          else source.get(i, epoch))
+                return _fill_rows(rows, sample)
 
-        def fetch_batch(b: int) -> dict:
-            # obs "batch" span: one per assembled batch, on the assembly
-            # lane's thread (its samples' decodes run on the pool's)
-            with obs.span("batch"):
-                chunk = indices[b * spy : (b + 1) * spy]
-                samples = list(self._pool.map(fetch_one, chunk))
-                return self._assemble(samples, spy)
+        def submit(b: int) -> tuple:
+            chunk = indices[b * spy : (b + 1) * spy]
+            first = None
+            if self._row_spec is None:
+                # the first sample this loader ever produces tells the
+                # rows' shapes and types; made here, then copied into its
+                # row like any sample a source returns as its own arrays
+                with obs.span("decode"):
+                    first = source.get(int(chunk[0]), epoch)
+                self._row_spec = {k: (np.shape(v), np.asarray(v).dtype)
+                                  for k, v in first.items()}
+            rows = self._new_rows(len(chunk))
+            skip = 0
+            if first is not None:
+                _fill_rows(_row_views(rows, 0), first)
+                skip = 1
+            futures = [self._pool.submit(decode, int(i), _row_views(rows, r))
+                       for r, i in enumerate(chunk[skip:], skip)]
+            return b, rows, len(chunk), futures
 
-        start = start_state.position
-        pending: "Queue[tuple]" = Queue()
+        # per-sample futures of up to `depth` batches ahead, straight on the
+        # decode pool (FIFO: a batch's samples start before the next one's,
+        # and the pool never idles between batches while the window is open)
+        pending: deque = deque()
         depth = max(self.prefetch_batches, 1)
-        next_submit = start
-        submitted = 0
-        executor = ThreadPoolExecutor(max_workers=1)  # batch-assembly lane
+        next_submit = start_state.position
         try:
-            while next_submit < n_batches and submitted < depth:
-                pending.put((next_submit, executor.submit(fetch_batch, next_submit)))
+            while next_submit < n_batches and len(pending) < depth:
+                pending.append(submit(next_submit))
                 next_submit += 1
-                submitted += 1
-            while not pending.empty():
-                b, fut = pending.get()
-                batch = fut.result()
+            while pending:
+                b, rows, n, futures = pending[0]
+                # obs "batch" span: one per yielded batch, on the thread
+                # that advances this generator: the wait for the batch's
+                # rows (its samples' decodes run on the pool's threads)
+                with obs.span("batch"):
+                    in_place = sum(f.result() for f in futures)
+                pending.popleft()
+                rows_in_place.inc(in_place)
+                rows_copied.inc(n - in_place)
                 if next_submit < n_batches:
-                    pending.put(
-                        (next_submit, executor.submit(fetch_batch, next_submit))
-                    )
+                    pending.append(submit(next_submit))
                     next_submit += 1
-                yield batch, LoaderState(epoch=epoch, position=b + 1)
+                yield self._shaped(rows), LoaderState(epoch=epoch,
+                                                      position=b + 1)
             yield None, LoaderState(epoch=epoch + 1, position=0)
         finally:
-            # early exit (limit_train_batches break -> GeneratorExit, or an
-            # exception upstream): in-flight fetch_batch futures would keep
-            # decoding whole batches after the consumer is gone. Cancel
-            # everything still queued; shutdown(cancel_futures) catches any
-            # race between the drain and a worker picking one up.
-            while not pending.empty():
-                try:
-                    pending.get_nowait()[1].cancel()
-                except Empty:  # pragma: no cover - single-consumer queue
-                    break
-            try:
-                executor.shutdown(wait=False, cancel_futures=True)
-            except TypeError:  # pragma: no cover - py<3.9 fallback
-                executor.shutdown(wait=False)
+            # early exit (limit_train_batches break -> GeneratorExit, an
+            # exception upstream, or a sample that failed): the queued
+            # samples of the batches ahead would keep decoding after the
+            # consumer is gone. Cancel everything that has not started
+            for _b, _rows, _n, futures in pending:
+                for f in futures:
+                    f.cancel()
 
     def _start_state(self, epoch: Optional[int],
                      from_start: bool) -> LoaderState:

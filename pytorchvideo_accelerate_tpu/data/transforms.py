@@ -71,6 +71,17 @@ def normalize_u8(frames: np.ndarray, mean: Sequence[float],
     return out
 
 
+def resize_on_calling_thread() -> None:
+    """For a process whose resizes are called from a pool of decode threads:
+    run each on the thread that calls it. cv2 otherwise splits every frame
+    over a pool of its own, one thread a core, so a decode pool that is
+    kept busy oversubscribes the host (8 workers x 13 cv2 threads on the
+    chip's host: 15% of the thread loader's rate, PERF.md). The setting is
+    cv2's and process-wide; the pixels do not depend on it."""
+    if cv2 is not None:
+        cv2.setNumThreads(0)
+
+
 def short_side_scale(frames: np.ndarray, size: int) -> np.ndarray:
     """Resize so the short spatial side == `size`, bilinear, AR preserved."""
     t, h, w = frames.shape[:3]
@@ -140,12 +151,16 @@ def horizontal_flip(frames: np.ndarray, p: float, rng: np.random.Generator) -> n
     return frames
 
 
+def slow_indices(t: int, alpha: int) -> np.ndarray:
+    """The slow pathway's frames among the fast pathway's `t`."""
+    return np.linspace(0, t - 1, t // alpha).astype(np.int64)
+
+
 def pack_pathway(frames: np.ndarray, alpha: int) -> Dict[str, np.ndarray]:
     """SlowFast dual-rate packing (reference PackPathway, run.py:56-65):
     fast keeps all T frames; slow takes T//alpha truncated-linspace picks."""
-    t = frames.shape[0]
-    slow_idx = np.linspace(0, t - 1, t // alpha).astype(np.int64)
-    return {"slow": frames[slow_idx], "fast": frames}
+    return {"slow": frames[slow_indices(frames.shape[0], alpha)],
+            "fast": frames}
 
 
 def make_transform(
@@ -166,6 +181,12 @@ def make_transform(
 
     Returns `fn(frames_uint8_THWC, rng) -> {"video": ...}` or
     `{"slow": ..., "fast": ...}` (contiguous).
+
+    `fn(..., out=rows)` (`fn.writes_rows` says a transform takes it) writes
+    each array into `rows[key]` instead, a preallocated array of the
+    output's shape and type (the loader's row of the batch, or one view of
+    it), and returns those: one casting copy from the strided crop/flip
+    view, the bytes the plain call returns, no array of the sample's own.
 
     `num_spatial_crops > 1` (eval only): the transform takes an extra
     `spatial_idx` argument selecting one of the evenly-spaced crops along
@@ -211,18 +232,32 @@ def make_transform(
             x = normalize_u8(x, mean, std)
         return short_side_scale(x, min_short_side_scale)
 
-    def _finalize(x: np.ndarray) -> Dict[str, np.ndarray]:
+    def _finalize(x: np.ndarray,
+                  out: Optional[Dict[str, np.ndarray]] = None
+                  ) -> Dict[str, np.ndarray]:
+        if out is not None:
+            # the one write of the sample: nothing is in a row before this
+            # (a decode that fails upstream leaves the row as it was)
+            if not is_slowfast:
+                np.copyto(out["video"], x, casting="unsafe")
+                return {"video": out["video"]}
+            np.copyto(out["fast"], x, casting="unsafe")
+            # slow's frames are among fast's: taken from the row just cast
+            np.take(out["fast"], slow_indices(x.shape[0], slowfast_alpha),
+                    axis=0, out=out["slow"], mode="clip")
+            return {"slow": out["slow"], "fast": out["fast"]}
         # astype on a sliced view already allocates contiguous output, so
         # cast first: one copy total in both modes
         if is_slowfast:
-            out = pack_pathway(x, slowfast_alpha)
+            packed = pack_pathway(x, slowfast_alpha)
             return {k: np.ascontiguousarray(v.astype(out_dtype, copy=False))
-                    for k, v in out.items()}
+                    for k, v in packed.items()}
         return {"video": np.ascontiguousarray(x.astype(out_dtype, copy=False))}
 
     def transform(frames: np.ndarray,
                   rng: Optional[np.random.Generator] = None,
-                  spatial_idx: Optional[int] = None):
+                  spatial_idx: Optional[int] = None,
+                  out: Optional[Dict[str, np.ndarray]] = None):
         if training and rng is None:
             raise ValueError("training transform requires an rng")
         if training:
@@ -247,19 +282,22 @@ def make_transform(
                     num_spatial_crops)
             else:
                 x = center_crop(x, crop_size)
-        return _finalize(x)
+        return _finalize(x, out)
 
     if num_spatial_crops > 1:
-        def spatial_views(frames: np.ndarray):
+        def spatial_views(frames: np.ndarray, out=None):
             """All spatial crops of one span, sharing ONE pre-crop pass
             (subsample/normalize/scale dominate eval host cost — running
-            them per crop would triple the hot path)."""
+            them per crop would triple the hot path). `out`: one dict of
+            rows a crop, as `transform`'s."""
             x = _precrop_eval(frames)
-            return [_finalize(uniform_crop(x, crop_size, j, num_spatial_crops))
+            return [_finalize(uniform_crop(x, crop_size, j, num_spatial_crops),
+                              None if out is None else out[j])
                     for j in range(num_spatial_crops)]
 
         transform.spatial_views = spatial_views
     transform.num_spatial_crops = num_spatial_crops
+    transform.writes_rows = True  # `out=` is taken (data/pipeline.sample_views)
     # u8-through clips still need `x*scale + bias` — on device, in-graph
     # (trainer/steps.py); None means the host already normalized
     transform.device_normalize = (tuple(mean), tuple(std)) if u8_through else None

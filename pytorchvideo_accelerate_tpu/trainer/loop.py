@@ -27,6 +27,7 @@ from pytorchvideo_accelerate_tpu.config import TrainConfig
 from pytorchvideo_accelerate_tpu.data.manifest import from_list, scan_directory
 from pytorchvideo_accelerate_tpu.data.pipeline import (
     ClipLoader,
+    LoaderRowCounts,
     LoaderState,
     SyntheticClipSource,
     VideoClipSource,
@@ -1083,6 +1084,7 @@ class Trainer:
         # (obs_step_s / obs_input_wait_frac / obs_h2d_s — the numbers
         # bench.py reports on its headline line)
         collector = obs.get_collector() if self.obs_on else None
+        loader_rows = LoaderRowCounts()
         epoch_spans: Dict[str, float] = {}
         loop_thread = threading.get_ident()
 
@@ -1109,6 +1111,12 @@ class Trainer:
                 vals["obs/window_wall_s"] = window_wall
                 vals["obs/unattributed_s"] = (
                     window_wall - self_by_thread.get(loop_thread, 0.0))
+            # beside obs/batch_s: the share of the window's batch rows that
+            # the clip source wrote in place (1.0 unless a source returns
+            # arrays of its own for the worker to copy in)
+            share = loader_rows.window_share()
+            if share is not None:
+                vals["obs/loader_rows_in_place_share"] = share
             self.trackers.log(vals, step=log_step)
 
         if collector is not None:

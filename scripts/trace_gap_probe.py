@@ -15,7 +15,12 @@ capture window (`--profile-steps A..B`, the trainer's one capture path), then
 * the host events the profiler recorded a step, and how many are `pva/*`;
 * the longest iterations by the records alone, which need no profiler: the
   same run without `--profile-steps` says whether such an iteration exists
-  untraced.
+  untraced;
+* the steady iterations' breakdown per step (`per_step_ms`: `input_wait`,
+  `step`, `log`, `iter` self, the ring's fill at the pop) and the workers'
+  side of the same steps from the per-window `obs` logs (`per_window`:
+  `batch`, `decode`, `h2d` per step, and the share of batch rows that the
+  clip source wrote in place).
 
     python scripts/trace_gap_probe.py --workload x3d_s.train --steps 130 \\
         --profile-steps 50..90 --out chiprun_out/gap_probe_traced.json
@@ -157,6 +162,54 @@ def long_iterations(records, skip):
     }
 
 
+def per_step(records, skip):
+    """Mean and median per steady iteration, ms: where the loop's time goes
+    (`self` is `iter` less its three children), and the prefetch ring's fill
+    when the loop asked."""
+    steady = [r for r in records if r["gstep"] >= skip]
+    if not steady:
+        return {}
+    cols = {k: [1000 * r[k] for r in steady]
+            for k in ("iter", "input_wait", "step", "log")}
+    cols["self"] = [1000 * (r["iter"] - r["input_wait"] - r["step"] - r["log"])
+                    for r in steady]
+    ready = [r["ready"] for r in steady]
+    out = {k: {"mean": sum(v) / len(v), "median": sorted(v)[len(v) // 2]}
+           for k, v in cols.items()}
+    out["ready"] = {"mean": sum(ready) / len(ready),
+                    "pops_with_ring_empty": sum(r == 0 for r in ready),
+                    "pops": len(ready)}
+    return out
+
+
+def per_window(work_dir, skip):
+    """The worker threads' spans of the steady log windows, ms per step, from
+    the jsonl tracker's `obs/*` lines; `batch` is the loader's wait for a
+    batch's rows, `decode` the pool's threads summed (over `num_workers`: the
+    least a batch can take), `h2d` the placement."""
+    lines = []
+    for path in glob.glob(os.path.join(work_dir, "runs", "*.jsonl")):
+        with open(path) as f:
+            lines += [json.loads(line) for line in f]
+    windows = sorted((v for v in lines if "obs/window_wall_s" in v),
+                     key=lambda v: v["step"])
+    steady = [(b["step"] - a["step"], b) for a, b in zip(windows, windows[1:])
+              if a["step"] >= skip]
+    steps = sum(n for n, _v in steady)
+    if not steps:
+        return {}
+    out = {"windows": len(steady), "steps": steps}
+    for name in ("batch", "decode", "h2d"):
+        out[f"{name}_ms_per_step"] = 1000 * sum(
+            v.get(f"obs/{name}_s", 0.0) for _n, v in steady) / steps
+    shares = [v["obs/loader_rows_in_place_share"] for _n, v in steady
+              if "obs/loader_rows_in_place_share" in v]
+    if shares:
+        out["loader_rows_in_place_share"] = {"min": min(shares),
+                                             "max": max(shares)}
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="x3d_s.train")
@@ -200,7 +253,9 @@ def main():
            "device": jax.devices()[0].device_kind, "steps": fit["steps"],
            "fit_and_setup_s": time.perf_counter() - t0,
            "rehearsal": bool(args.rehearse),
-           "records": long_iterations(records, args.skip)}
+           "records": long_iterations(records, args.skip),
+           "per_step_ms": per_step(records, args.skip),
+           "per_window": per_window(work_dir, args.skip)}
     if args.profile_steps:
         pbs = sorted(glob.glob(os.path.join(work_dir, "profile_steps_*", "**",
                                             "*.xplane.pb"), recursive=True))

@@ -9,11 +9,13 @@ import pytest
 
 from pytorchvideo_accelerate_tpu.data.decode import decode_span, probe
 from pytorchvideo_accelerate_tpu.data.manifest import scan_directory
+from pytorchvideo_accelerate_tpu import obs
 from pytorchvideo_accelerate_tpu.data.pipeline import (
     ClipLoader,
     LoaderState,
     SyntheticClipSource,
     VideoClipSource,
+    assemble_batch,
 )
 from pytorchvideo_accelerate_tpu.data.samplers import random_clip, uniform_clips
 from pytorchvideo_accelerate_tpu.data.transforms import make_transform
@@ -265,3 +267,235 @@ def test_loader_eval_from_start_after_early_break():
     loader.state = LoaderState(epoch=0, position=1)
     assert len(list(loader.epoch(0))) == 3
     loader.close()
+
+
+# --- rows written in place (thread path) -------------------------------------
+
+
+class _OldSignatureSource(SyntheticClipSource):
+    """A subclass written before `get` took `out`: returns its own arrays."""
+
+    def get(self, index, epoch):
+        return super().get(index, epoch)
+
+
+def _row_counts():
+    reg = obs.get_registry()
+    return (reg.counter("pva_loader_rows_in_place").total(),
+            reg.counter("pva_loader_rows_copied").total())
+
+
+# (id, transform options, source class, source options, loader options, videos)
+_IN_PLACE_CASES = [
+    ("video-bf16-train", dict(training=True, output_dtype="bfloat16"),
+     SyntheticClipSource, {}, {}, 16),
+    ("video-f32-train", dict(training=True, output_dtype="float32"),
+     SyntheticClipSource, {}, {}, 16),
+    ("video-u8-train", dict(training=True, output_dtype="uint8"),
+     SyntheticClipSource, {}, {}, 16),
+    ("slowfast-bf16-train",
+     dict(training=True, is_slowfast=True, output_dtype="bfloat16"),
+     SyntheticClipSource, {}, {}, 16),
+    ("slowfast-f32-train",
+     dict(training=True, is_slowfast=True, output_dtype="float32"),
+     SyntheticClipSource, {}, {}, 16),
+    ("slowfast-u8-train",
+     dict(training=True, is_slowfast=True, output_dtype="uint8"),
+     SyntheticClipSource, {}, {}, 16),
+    ("video-bf16-val-padded-tail", dict(output_dtype="bfloat16"),
+     SyntheticClipSource, {}, dict(drop_last=False), 11),
+    ("slowfast-f32-val-padded-tail",
+     dict(is_slowfast=True, output_dtype="float32"),
+     SyntheticClipSource, {}, dict(drop_last=False), 11),
+    ("video-f32-accum2", dict(training=True, output_dtype="float32"),
+     SyntheticClipSource, {}, dict(accum_steps=2), 16),
+    ("slowfast-bf16-accum2-padded-tail",
+     dict(is_slowfast=True, output_dtype="bfloat16"),
+     SyntheticClipSource, {}, dict(accum_steps=2, drop_last=False), 13),
+    ("video-bf16-val-3clips-padded-tail", dict(output_dtype="bfloat16"),
+     SyntheticClipSource, dict(num_clips=3), dict(drop_last=False), 10),
+    ("slowfast-u8-val-3clips", dict(is_slowfast=True, output_dtype="uint8"),
+     SyntheticClipSource, dict(num_clips=3), {}, 8),
+    ("video-f32-val-2clips-3crops",
+     dict(output_dtype="float32", num_spatial_crops=3),
+     SyntheticClipSource, dict(num_clips=2), dict(drop_last=False), 6),
+    ("old-signature-video-bf16", dict(training=True, output_dtype="bfloat16"),
+     _OldSignatureSource, {}, {}, 16),
+    ("old-signature-slowfast-f32-padded-tail",
+     dict(is_slowfast=True, output_dtype="float32"),
+     _OldSignatureSource, dict(num_clips=3), dict(drop_last=False), 7),
+]
+
+
+@pytest.mark.parametrize(
+    "tf_kw,source_cls,source_kw,loader_kw,n_videos",
+    [c[1:] for c in _IN_PLACE_CASES], ids=[c[0] for c in _IN_PLACE_CASES])
+def test_thread_path_rows_are_the_stack_byte_for_byte(
+        tf_kw, source_cls, source_kw, loader_kw, n_videos):
+    """The thread path writes each sample into its row of the batch; what it
+    yields is, key by key and byte by byte, `assemble_batch` of the samples
+    the source returns on its own — and the two counters say who wrote the
+    rows (the source, or the worker copying in a returned sample)."""
+    tf = make_transform(num_frames=8, crop_size=32, min_short_side_scale=36,
+                        max_short_side_scale=44, slowfast_alpha=4, **tf_kw)
+    src = source_cls(tf, num_videos=n_videos, num_classes=4, seed=5,
+                     **source_kw)
+    loader = ClipLoader(src, global_batch_size=4, num_workers=3, shuffle=True,
+                        seed=9, **loader_kw)
+    spy = loader.samples_per_yield
+    before = _row_counts()
+    rows = 0
+    for epoch in (0, 1):  # the second epoch reuses the rows' learnt shapes
+        indices = loader._epoch_indices(epoch)
+        got = list(loader.epoch(epoch))
+        assert len(got) == loader.batches_per_epoch()
+        for b, batch in enumerate(got):
+            chunk = indices[b * spy:(b + 1) * spy]
+            want = assemble_batch(
+                [src.get(int(i), epoch) for i in chunk], spy,
+                accum_steps=loader.accum_steps,
+                local_batch_size=loader.local_batch_size)
+            rows += len(chunk)
+            assert list(batch) == list(want)
+            for k in want:
+                assert batch[k].dtype == want[k].dtype, k
+                assert batch[k].shape == want[k].shape, k
+                assert batch[k].tobytes() == want[k].tobytes(), k
+    in_place, copied = (a - b for a, b in zip(_row_counts(), before))
+    if source_cls is _OldSignatureSource:
+        assert (in_place, copied) == (0, rows)
+    else:  # all but the loader's first sample ever, which told the shapes
+        assert (in_place, copied) == (rows - 1, 1)
+    loader.close()
+
+
+def test_thread_path_real_videos_with_a_substituted_decode(video_dir, tmp_path):
+    """`VideoClipSource` through the rows: a corrupt file's substitute is
+    written into the same row, and the batch is still the stack."""
+    import shutil
+
+    root = tmp_path / "train"
+    shutil.copytree(os.path.join(video_dir, "train"), root)
+    (root / "archery" / "archery_9.avi").write_bytes(b"not a video")
+    manifest = scan_directory(str(root))
+    tf = make_transform(num_frames=4, training=True, crop_size=32,
+                        min_short_side_scale=36, max_short_side_scale=40,
+                        output_dtype="bfloat16")
+
+    def source():
+        return VideoClipSource(manifest, tf, clip_duration=1.0, training=True,
+                               seed=3, retry_base_delay_s=0.0)
+
+    loader = ClipLoader(source(), global_batch_size=5, num_workers=2,
+                        drop_last=False)
+    (batch,) = list(loader.epoch(0))
+    assert loader.source._failed  # the corrupt file was met and replaced
+    plain = source()
+    want = assemble_batch([plain.get(i, 0) for i in range(5)], 5)
+    for k in want:
+        assert batch[k].tobytes() == want[k].tobytes(), k
+    loader.close()
+
+
+def test_a_sample_that_does_not_fit_the_rows_is_refused():
+    class Ragged(SyntheticClipSource):
+        def get(self, index, epoch):
+            sample = super().get(index, epoch)
+            if index == 5:
+                sample["video"] = sample["video"][:, :16]
+            return sample
+
+    tf = make_transform(num_frames=4, training=False, crop_size=32,
+                        min_short_side_scale=32)
+    loader = ClipLoader(Ragged(tf, num_videos=8, num_classes=4),
+                        global_batch_size=4, num_workers=2)
+    it = loader.epoch(0)
+    next(it)
+    with pytest.raises(ValueError, match="shape"):
+        next(it)
+    loader.close()
+
+
+def test_no_barrier_between_batches_and_bounded_run_ahead():
+    """While a batch's last sample is unfinished the pool already decodes
+    the next batch's samples (no per-batch barrier), never more than
+    `prefetch_batches` batches ahead of the one being yielded, and an early
+    close still cancels what is queued."""
+    import threading
+    import time as _t
+
+    started, gate = [], threading.Event()
+
+    class Gated(SyntheticClipSource):
+        def get(self, index, epoch, out=None):
+            started.append(index)
+            _t.sleep(0.05)
+            if index == 3:  # batch 0's last sample
+                gate.wait(10.0)
+            return super().get(index, epoch, out=out)
+
+    def wait_for(cond, timeout=10.0):
+        deadline = _t.time() + timeout
+        while not cond() and _t.time() < deadline:
+            _t.sleep(0.005)
+        return cond()
+
+    tf = make_transform(num_frames=4, training=False, crop_size=32,
+                        min_short_side_scale=32)
+    loader = ClipLoader(Gated(tf, num_videos=64, num_classes=4),
+                        global_batch_size=4, num_workers=2,
+                        prefetch_batches=2)
+    it = loader.epoch(0)
+    got = []
+    consumer = threading.Thread(target=lambda: got.append(next(it)))
+    consumer.start()
+    try:
+        # batch 1 (indices 4..7) decodes while index 3 is still unfinished
+        assert wait_for(lambda: {4, 5, 6, 7} <= set(started))
+        assert not got
+        _t.sleep(0.2)
+        # ...and nothing further: batches 0 and 1 are the window
+        assert max(started) == 7
+    finally:
+        gate.set()
+        consumer.join(10.0)
+    assert len(got) == 1
+    # batch 0 yielded: batch 2 (8..11) was submitted, batch 3 never is
+    it.close()
+    _t.sleep(0.4)
+    seen = list(started)
+    _t.sleep(0.2)
+    assert started == seen, "decode work kept flowing after close"
+    assert max(started) <= 11
+    assert len(started) < 12, "close cancelled none of the queued samples"
+    loader.close()
+
+
+def test_rows_in_place_under_thread_pressure():
+    """More decode threads than cores and a short switch interval: every
+    worker still writes its own row and no other (a lost or crossed write
+    would break the byte identity)."""
+    import sys
+
+    tf = make_transform(num_frames=2, training=True, crop_size=16,
+                        min_short_side_scale=18, max_short_side_scale=22,
+                        output_dtype="bfloat16")
+    src = SyntheticClipSource(tf, num_videos=96, num_classes=4,
+                              raw_frames=4, raw_size=(24, 32), seed=11)
+    loader = ClipLoader(src, global_batch_size=8, shuffle=True, seed=2,
+                        num_workers=4 * (os.cpu_count() or 4),
+                        prefetch_batches=3)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for epoch in range(3):
+            indices = loader._epoch_indices(epoch)
+            for b, batch in enumerate(loader.epoch(epoch)):
+                want = assemble_batch(
+                    [src.get(int(i), epoch)
+                     for i in indices[b * 8:(b + 1) * 8]], 8)
+                for k in want:
+                    assert batch[k].tobytes() == want[k].tobytes(), (epoch, b, k)
+    finally:
+        sys.setswitchinterval(interval)
+        loader.close()

@@ -108,6 +108,28 @@ def test_bounded_residency(mesh8):
     loader.close()
 
 
+def test_placed_batch_survives_the_loader_running_on(mesh8):
+    """No yielded buffer is ever written again: on the CPU backend a
+    `device_put` may alias the host array, so a placed batch must read the
+    same after the loader has produced the next `prefetch_batches + depth +
+    1` batches into fresh buffers — and the ring still never holds more than
+    `depth`."""
+    depth, ahead = 2, 2
+    want = list(_loader(n_videos=96).epoch(0))  # 12 batches
+    loader = _loader(n_videos=96, prefetch_batches=ahead)
+    pf = DevicePrefetcher(loader, mesh8, depth=depth)
+    held = []
+    for placed in pf.epoch(0):
+        held.append(placed)
+        time.sleep(0.02)  # let the loader and the ring run ahead
+    assert len(held) == len(want) == 12
+    assert ahead + depth + 1 < len(held)
+    for placed, host in zip(held, want):  # read only now, all produced
+        _assert_batches_equal(placed, host)
+    assert 1 <= pf.max_resident <= depth
+    loader.close()
+
+
 def test_loader_state_tracks_consumption_not_prefetch(mesh8):
     """THE checkpoint-correctness property: while the prefetch thread runs
     ahead, `loader.state` must report the consumed position — a checkpoint

@@ -220,3 +220,32 @@ def test_device_normalize_batch_matches_host_values():
     np.testing.assert_array_equal(
         np.asarray(device_normalize_batch(fbatch, (mean, std))["video"]),
         np.ones((1, 2, 2, 2, 3), np.float32))
+
+
+def test_resize_on_calling_thread_keeps_the_pixels():
+    """cv2's own thread pool is a matter of speed alone: with it off (what a
+    multi-worker `ClipLoader` sets, its decode pool being the parallelism)
+    the scaled frames are the same bytes."""
+    import cv2
+
+    from pytorchvideo_accelerate_tpu.data.pipeline import (
+        ClipLoader,
+        SyntheticClipSource,
+    )
+    from pytorchvideo_accelerate_tpu.data.transforms import make_transform
+
+    frames = np.random.default_rng(3).random((5, 72, 96, 3)).astype(np.float32)
+    before = cv2.getNumThreads()
+    try:
+        cv2.setNumThreads(4)
+        threaded = short_side_scale(frames, 205)
+        tf = make_transform(num_frames=2, crop_size=16, min_short_side_scale=16)
+        ClipLoader(SyntheticClipSource(tf, num_videos=4), 2,
+                   num_workers=1).close()
+        assert cv2.getNumThreads() == 4  # one worker: cv2's pool stays
+        ClipLoader(SyntheticClipSource(tf, num_videos=4), 2,
+                   num_workers=2).close()
+        assert cv2.getNumThreads() == 1
+        assert short_side_scale(frames, 205).tobytes() == threaded.tobytes()
+    finally:
+        cv2.setNumThreads(before)

@@ -818,6 +818,11 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     assert obs.get_registry().gauge("pva_train_grad_norm").value() > 0.0
     # eval got its own span in the timeline
     assert any("obs/eval_s" in ln for ln in lines)
+    # beside obs/batch_s: who wrote the windows' batch rows (the source
+    # itself, but for the first sample a loader ever makes)
+    shares = [ln["obs/loader_rows_in_place_share"] for ln in lines
+              if "obs/loader_rows_in_place_share" in ln]
+    assert shares and all(0.5 < s <= 1.0 for s in shares), shares
 
 
 def test_zz_fit_spans_on_the_profilers_clock(tmp_path, _tiny_slow_r50):
