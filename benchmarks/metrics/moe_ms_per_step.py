@@ -1,0 +1,11 @@
+"""`moe_ms_per_step` (layer: mixture of experts: router, dispatch, grouped products, combine).
+Device milliseconds a step of every op of the compiled step under the layer's
+scope, forward, rematerialised forward and backward (lib/scoped.py)."""
+
+from benchmarks.lib import scoped
+
+SCOPE = r"/moe/"
+
+
+def read(results):
+    return scoped.scope_ms_per_step(results, SCOPE)

@@ -90,6 +90,9 @@ class DataConfig:
     synthetic: bool = False  # synthetic clips (test/bench fixture; SURVEY §4.4)
     synthetic_num_videos: int = 64
     num_frames: int = 8  # run.py:374 default; 32 in run_slowfast_r50.sh
+    # tokens a sequence, for a model whose task is next-token (one document
+    # a sequence, no padding); a sequence is what `batch_size` counts
+    seq_len: int = 1024
     sampling_rate: int = 8  # pva: disable=knob-read -- read via the clip_duration property below (the one derived config value)
     frames_per_second: int = 30  # pva: disable=knob-read -- read via the clip_duration property below (the one derived config value)
     batch_size: int = 8  # per data-parallel shard, matching per-rank semantics
@@ -198,6 +201,14 @@ class ModelConfig:
     # accuracy recovers (the recipe in docs/SERVING.md).
     attn_mask: str = "none"  # none | causal | windowed
     attn_window: int = 0     # temporal slots (= frames / tubelet_t)
+    # Token models (models/qwen3_next.py; docs/TOKENS.md): the share of the
+    # published model this process holds. 0 = as published. The router keeps
+    # its published width whatever is held; the traffic draws its ids from
+    # the held vocabulary slice [0, vocab_size).
+    num_layers: int = 0      # decoder layers, whole periods of the pattern
+    vocab_size: int = 0      # rows of the embedding and the head held here
+    experts_held: int = 0    # routed experts held, of the model's num_experts
+    expert_offset: int = 0   # the first held expert's index
 
 
 @dataclass

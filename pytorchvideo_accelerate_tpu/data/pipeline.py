@@ -354,6 +354,33 @@ class SyntheticClipSource(ClipSource):
         return sample
 
 
+class SyntheticTokenSource(ClipSource):
+    """Token sequences for a next-token model: `seq_len` int32 ids a sample,
+    uniform over the held vocabulary slice [0, vocab_size), one document a
+    sequence (no padding, no packing). Each (seed, epoch, index) is a stream
+    of its own, written straight into the loader's row where it gives one."""
+
+    def __init__(self, seq_len: int, vocab_size: int, num_sequences: int = 64,
+                 seed: int = 42):
+        self.seq_len = seq_len
+        self.vocab_size = vocab_size
+        self.num_sequences = num_sequences
+        self.seed = seed
+
+    def __len__(self) -> int:
+        return self.num_sequences
+
+    def get(self, index: int, epoch: int,
+            out: Optional[Dict[str, np.ndarray]] = None
+            ) -> Dict[str, np.ndarray]:
+        rng = np.random.default_rng((self.seed, epoch, index))
+        ids = rng.integers(0, self.vocab_size, self.seq_len, dtype=np.int32)
+        if out is None:
+            return {"tokens": ids}
+        out["tokens"][...] = ids
+        return {"tokens": out["tokens"]}
+
+
 def stack_samples(arrs: List[np.ndarray]) -> np.ndarray:
     """np.stack via the native multithreaded gather-copy when available
     (GIL-free batch assembly); numpy fallback otherwise. Module-level so

@@ -8,6 +8,7 @@ weights come from the torch->Flax converter (models/convert.py) via
 
 from __future__ import annotations
 
+import dataclasses
 import inspect
 
 from typing import Callable, Dict
@@ -28,14 +29,30 @@ from pytorchvideo_accelerate_tpu.models.videomae import (  # noqa: F401
 )
 
 _REGISTRY: Dict[str, Callable] = {}
+# what a model is trained to do, declared where it is registered and read by
+# the trainer (never off the model's name): "classify" (clips and a label),
+# "reconstruct" (clips, the model returns its own loss), "next_token"
+# (token sequences, the model returns its summed cross-entropy)
+TASKS = ("classify", "reconstruct", "next_token")
+_TASKS: Dict[str, str] = {}
 
 
-def register_model(name: str):
+def register_model(name: str, task: str = "classify"):
+    if task not in TASKS:
+        raise ValueError(f"task must be one of {TASKS}, got {task!r}")
+
     def deco(fn):
         _REGISTRY[name] = fn
+        _TASKS[name] = task
         return fn
 
     return deco
+
+
+def model_task(name: str) -> str:
+    """The registered model's task; an unknown name is `create_model`'s to
+    refuse, so it reads as the default here."""
+    return _TASKS.get(name, "classify")
 
 
 @register_model("slow_r50")
@@ -206,7 +223,7 @@ def _videomae_b(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
     )
 
 
-@register_model("videomae_b_pretrain")
+@register_model("videomae_b_pretrain", task="reconstruct")
 def _videomae_b_pretrain(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
     """MAE pretraining path of BASELINE config 5 (self-supervised; the
     reference stack has no SSL path — run.py is supervised-only)."""
@@ -254,7 +271,7 @@ def _mvit_t(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
     )
 
 
-@register_model("videomae_t_pretrain")
+@register_model("videomae_t_pretrain", task="reconstruct")
 def _videomae_t_pretrain(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
     """Tiny VideoMAE pretraining twin of `videomae_t` (depth 4 encoder /
     depth 2 decoder — both divide by 2 stages, the encoder by 4)."""
@@ -265,6 +282,54 @@ def _videomae_t_pretrain(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
         context_mesh=mesh if cfg.attention in ("ring", "ulysses") else None,
         shard_mesh=mesh, pipeline=pipeline, remat=cfg.remat, dtype=dtype,
     )
+
+
+def _qwen3_next(cfg: ModelConfig, dtype, published: dict):
+    """The held share of a Qwen3-Next decoder: published widths, with the
+    depth, the vocabulary slice and the experts held as the config says."""
+    from pytorchvideo_accelerate_tpu.models.qwen3_next import (
+        Qwen3Next,
+        Qwen3NextArch,
+    )
+
+    arch = Qwen3NextArch(**published)
+    arch = dataclasses.replace(
+        arch,
+        num_hidden_layers=cfg.num_layers or arch.num_hidden_layers,
+        vocab_size=cfg.vocab_size or arch.vocab_size,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset)
+    if arch.num_hidden_layers % arch.full_attention_interval:
+        raise ValueError(
+            f"model.num_layers={arch.num_hidden_layers} is not whole periods "
+            f"of {arch.full_attention_interval} layers")
+    if not 0 <= arch.expert_offset <= arch.num_experts - arch.held:
+        raise ValueError(
+            f"experts [{arch.expert_offset}, {arch.expert_offset + arch.held}) "
+            f"are not among the model's {arch.num_experts}")
+    return Qwen3Next(arch, dtype=dtype, remat=True)
+
+
+@register_model("qwen3_next_80b_a3b", task="next_token")
+def _qwen3_next_80b_a3b(cfg: ModelConfig, dtype, mesh=None):
+    """Qwen3-Next-80B-A3B-Instruct at its published widths (the defaults of
+    `Qwen3NextArch`: huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct
+    config.json). One chip holds a share: `--model.num_layers`,
+    `--model.vocab_size`, `--model.experts_held` (docs/TOKENS.md)."""
+    return _qwen3_next(cfg, dtype, {})
+
+
+@register_model("qwen3_next_t", task="next_token")
+def _qwen3_next_t(cfg: ModelConfig, dtype, mesh=None):
+    """Deliberately tiny Qwen3-Next (the `tiny3d` of the token family): every
+    mechanism, toy widths — tests and the CPU rehearsal. Not a reference
+    architecture."""
+    return _qwen3_next(cfg, dtype, dict(
+        hidden_size=64, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=32, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=32, shared_expert_intermediate_size=32,
+        vocab_size=256))
 
 
 def available_models():
@@ -338,7 +403,10 @@ def create_model(cfg: ModelConfig, mixed_precision: str = "bf16", mesh=None,
 
 
 def model_input_spec(cfg: ModelConfig, data_cfg) -> dict:
-    """Shapes the model expects for one clip batch (B=1), NDHWC."""
+    """Shapes the model expects for one clip batch (B=1), NDHWC; for a
+    next-token model one sequence of int32 ids."""
+    if model_task(cfg.name) == "next_token":
+        return {"tokens": (1, data_cfg.seq_len)}
     t, s = data_cfg.num_frames, data_cfg.crop_size
     if cfg.name.startswith("slowfast"):
         return {

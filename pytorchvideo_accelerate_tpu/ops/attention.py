@@ -63,6 +63,63 @@ def fused_attention(q, k, v, scale: Optional[float] = None, kmask=None,
     return jax.nn.dot_product_attention(q, k, v, mask=mask, scale=scale)
 
 
+def rotate_half(x, positions, theta: float, rotary_dim: int):
+    """Rotary embedding (rotate-half pairing) on the first `rotary_dim` of
+    the head dimension of x (B, T, H, D), float32 angles; the other
+    dimensions pass through (partial rotary)."""
+    half = rotary_dim // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    angle = f32_island(positions)[:, None] * freq[None, :]      # (T, half)
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    rot, rest = f32_island(x[..., :rotary_dim]), x[..., rotary_dim:]
+    a, b = rot[..., :half], rot[..., half:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
+
+
+def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
+                         block_q: int = 512):
+    """Causal softmax attention with grouped queries: q (B, T, Hq, D), k and v
+    (B, T, Hkv, D), Hq a multiple of Hkv, each key-value head serving
+    Hq / Hkv query heads. Token t reads keys 0..t.
+
+    Computed a block of `block_q` queries at a time against the keys up to
+    that block's end, so the keys behind the diagonal cost nothing and the
+    float32 scores of one block (B, Hq, block_q, keys so far) are the
+    largest array alive; each block is rematerialised in the backward pass.
+    A sequence no longer than `block_q` is one dense masked product."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} key-value heads")
+    if scale is None:
+        scale = d ** -0.5
+    group = hq // hkv
+
+    @jax.checkpoint
+    def block(q_blk, k_seen, v_seen, start):
+        # a key-value head's `group` query heads become rows of one product:
+        # scores (B, Hkv, group * block, keys), no axis of size `group` for
+        # the compiler to lay out minor
+        n = q_blk.shape[1]
+        rows = q_blk.reshape(b, n, hkv, group, d).transpose(0, 2, 3, 1, 4)
+        rows = rows.reshape(b, hkv, group * n, d)
+        # f32 softmax logits: the island every attention impl shares
+        logits = f32_island(jnp.einsum("bhrd,bkhd->bhrk", rows, k_seen)) * scale
+        pos = start + jnp.arange(group * n) % n
+        seen = jnp.arange(k_seen.shape[1])[None, :] <= pos[:, None]
+        probs = jax.nn.softmax(jnp.where(seen, logits, -1e30), axis=-1)
+        out = jnp.einsum("bhrk,bkhd->bhrd", probs.astype(q.dtype), v_seen)
+        out = out.reshape(b, hkv, group, n, d).transpose(0, 3, 1, 2, 4)
+        return out.reshape(b, n, hq, d)
+
+    outs = []
+    for start in range(0, t, block_q):
+        end = min(start + block_q, t)
+        outs.append(block(q[:, start:end], k[:, :end], v[:, :end], start))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 def banded_time_mask(q_idx, k_idx, window: int):
     """Boolean band mask over ABSOLUTE temporal-slot indices: query slot
     qi may attend key slot kj iff ``0 <= qi - kj < window``.

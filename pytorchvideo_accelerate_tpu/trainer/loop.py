@@ -30,11 +30,16 @@ from pytorchvideo_accelerate_tpu.data.pipeline import (
     LoaderRowCounts,
     LoaderState,
     SyntheticClipSource,
+    SyntheticTokenSource,
     VideoClipSource,
 )
 from pytorchvideo_accelerate_tpu.data.device_prefetch import DevicePrefetcher
 from pytorchvideo_accelerate_tpu.data.transforms import make_transform
-from pytorchvideo_accelerate_tpu.models import create_model, model_input_spec
+from pytorchvideo_accelerate_tpu.models import (
+    create_model,
+    model_input_spec,
+    model_task,
+)
 from pytorchvideo_accelerate_tpu.parallel.distributed import (
     initialize_distributed,
     is_main_process,
@@ -78,7 +83,10 @@ from pytorchvideo_accelerate_tpu.trainer.checkpoint import (
 from pytorchvideo_accelerate_tpu.trainer.metrics import MeanLoss, SumMetrics
 from pytorchvideo_accelerate_tpu.trainer.optim import build_lr_schedule, build_optimizer
 from pytorchvideo_accelerate_tpu.trainer.steps import (
+    LM_LOG_KEYS,
     make_eval_step,
+    make_lm_eval_step,
+    make_lm_step,
     make_pretrain_eval_step,
     make_pretrain_step,
     make_train_step,
@@ -119,9 +127,11 @@ class Trainer:
 
     def __init__(self, cfg: TrainConfig):
         self.cfg = cfg
-        # self-supervised objective (VideoMAE): no labels, model computes its
-        # own loss; the supervised path is the reference's only mode
-        self.is_pretraining = cfg.model.name.endswith("_pretrain")
+        # what the model is trained to do, as its registry entry declares it
+        # (models/__init__.py TASKS): "classify" is the reference's only
+        # mode; "reconstruct" (VideoMAE) has no labels and the model computes
+        # its own loss; "next_token" trains on token sequences
+        self.task = model_task(cfg.model.name)
         self.checkpointing_steps = _parse_checkpointing_steps(
             cfg.checkpoint.checkpointing_steps
         )
@@ -337,14 +347,15 @@ class Trainer:
                 f"data.host_cast must be 'auto', 'fp32' or 'u8', "
                 f"got {d.host_cast!r}"
             )
-        if d.host_cast == "u8" and self.is_pretraining:
+        reconstructs = self.task == "reconstruct"
+        if d.host_cast == "u8" and reconstructs:
             raise ValueError(
                 "data.host_cast='u8' is supervised-only: the MAE target is "
                 "computed from the raw clip in fp32 (videomae.py patchify)"
             )
         u8 = d.host_cast == "u8"
         bf16 = (cfg.mixed_precision in ("bf16", "fp16")
-                and d.host_cast == "auto" and not self.is_pretraining)
+                and d.host_cast == "auto" and not reconstructs)
         common = dict(
             num_frames=d.num_frames,
             is_slowfast=is_slowfast,
@@ -363,17 +374,33 @@ class Trainer:
 
         # multi-view eval is supervised-only: the pretrain eval step scores
         # reconstructions clip-by-clip, so a view axis would just crash it
-        eval_clips = 1 if self.is_pretraining else d.eval_num_clips
-        eval_spatial = 1 if self.is_pretraining else d.eval_num_spatial_crops
-        if self.is_pretraining and (d.eval_num_clips > 1
-                                    or d.eval_num_spatial_crops > 1):
+        eval_clips = 1 if reconstructs else d.eval_num_clips
+        eval_spatial = 1 if reconstructs else d.eval_num_spatial_crops
+        if reconstructs and (d.eval_num_clips > 1
+                             or d.eval_num_spatial_crops > 1):
             main_print("multi-view eval options ignored for self-supervised "
                        "pretraining")
         val_tf = make_transform(training=False,
                                 num_spatial_crops=eval_spatial, **common)
 
         train_manifest = None  # set by the real-video branch (dataplane spec)
-        if d.synthetic:
+        if self.task == "next_token":
+            # token sequences: one source so far, ids uniform over the held
+            # vocabulary slice (docs/TOKENS.md); the loader, the prefetcher
+            # and everything behind them are the clips' own
+            if not d.synthetic:
+                raise ValueError(
+                    f"model {cfg.model.name!r} trains on token sequences, "
+                    "and the synthetic source is the only token source so "
+                    "far: pass --synthetic (docs/TOKENS.md)")
+            num_classes = create_model(cfg.model, cfg.mixed_precision,
+                                       mesh=self.mesh).arch.vocab_size
+            self.train_source = SyntheticTokenSource(
+                d.seq_len, num_classes, d.synthetic_num_videos, seed=cfg.seed)
+            self.val_source = SyntheticTokenSource(
+                d.seq_len, num_classes, max(d.synthetic_num_videos // 4, 4),
+                seed=cfg.seed + 1)
+        elif d.synthetic:
             num_classes = cfg.model.num_classes or 4
             self.train_source = SyntheticClipSource(
                 train_tf, num_videos=d.synthetic_num_videos,
@@ -546,7 +573,10 @@ class Trainer:
         spec = model_input_spec(cfg.model, cfg.data)
         import jax.numpy as jnp
 
-        if "slow" in spec:
+        if "tokens" in spec:
+            # parameters do not depend on the length: a short init sample
+            sample = jnp.zeros((1, min(spec["tokens"][1], 128)), jnp.int32)
+        elif "slow" in spec:
             sample = (jnp.zeros(spec["slow"]), jnp.zeros(spec["fast"]))
         else:
             sample = jnp.zeros(spec["video"])
@@ -654,7 +684,20 @@ class Trainer:
                     len(nonhead), ", ".join(nonhead[:8]),
                 )
 
-        if self.is_pretraining:
+        if self.task == "next_token":
+            if self.pipeline_plan is not None:
+                raise ValueError("the next-token step has no pipelined form")
+            self.train_step = make_lm_step(
+                self.model, self.tx, self.mesh,
+                accum_steps=cfg.optim.gradient_accumulation_steps,
+                lr_schedule=self.lr_schedule,
+                debug_asserts=cfg.debug_asserts,
+                ema_decay=cfg.optim.ema_decay,
+                health_metrics=self.obs_on,
+                guard_skip=cfg.guard.enabled,
+            )
+            self.eval_step = make_lm_eval_step(self.eval_model, self.mesh)
+        elif self.task == "reconstruct":
             self.train_step = make_pretrain_step(
                 self.model, self.tx, self.mesh,
                 accum_steps=cfg.optim.gradient_accumulation_steps,
@@ -940,7 +983,7 @@ class Trainer:
                 self.watchdog.heartbeat("train")
             self._maybe_resume()
             acc, acc5, loss = self._run_eval(epoch=0)
-            if self.is_pretraining:
+            if self.task == "reconstruct":
                 result = {"val_recon_loss": loss}
                 main_print(f"evaluate: val_recon_loss={loss:.4f}")
             else:
@@ -1087,6 +1130,9 @@ class Trainer:
         loader_rows = LoaderRowCounts()
         epoch_spans: Dict[str, float] = {}
         loop_thread = threading.get_ident()
+        tokens_per_step = (
+            self.train_loader.global_batch_size * self.train_loader.accum_steps
+            * cfg.data.seq_len if self.task == "next_token" else 0)
 
         def drain_spans(log_step=None, window_wall=None):
             if collector is None:
@@ -1117,6 +1163,11 @@ class Trainer:
             share = loader_rows.window_share()
             if share is not None:
                 vals["obs/loader_rows_in_place_share"] = share
+            if tokens_per_step and window_wall and "iter" in window:
+                # a next-token model's rate over the window: the window's
+                # iterations (the `iter` span's count), host arithmetic
+                vals["obs/tokens_per_s"] = (
+                    tokens_per_step * window["iter"][1] / window_wall)
             self.trackers.log(vals, step=log_step)
 
         if collector is not None:
@@ -1316,6 +1367,13 @@ class Trainer:
                                             metrics["update_ratio"]
                                         vals["obs/nonfinite"] = \
                                             metrics["nonfinite"]
+                                    if self.task == "next_token":
+                                        # the step's own counters, same
+                                        # fetch (none under accumulation)
+                                        vals.update({
+                                            name: metrics[k] for k, name
+                                            in LM_LOG_KEYS.items()
+                                            if k in metrics})
                                     deferred.defer(vals, step=gstep)
                                 if (isinstance(self.checkpointing_steps, int)
                                         and gstep % self.checkpointing_steps
@@ -1445,7 +1503,8 @@ class Trainer:
                     window_t0 = now
                 last_train_loss = epoch_loss.mean()
                 val_str = (
-                    f"val_recon_loss={last_val_loss:.4f}" if self.is_pretraining
+                    f"val_recon_loss={last_val_loss:.4f}"
+                    if self.task == "reconstruct"
                     else f"val_acc={last_val_acc:.4f} "
                          f"val_acc5={last_val_acc5:.4f}"
                 )
@@ -1558,7 +1617,7 @@ class Trainer:
                 if self.trackers:
                     epoch_metrics = {"train_loss_epoch": last_train_loss,
                                      "epoch": epoch}
-                    if self.is_pretraining:
+                    if self.task == "reconstruct":
                         epoch_metrics["val_recon_loss"] = last_val_loss
                     else:
                         epoch_metrics["accuracy"] = last_val_acc
@@ -1630,7 +1689,7 @@ class Trainer:
                   "preempted": preempted,
                   "step_records": list(self.step_records),
                   **last_perf, **last_mfu_labels}
-        if self.is_pretraining:
+        if self.task == "reconstruct":
             result["val_recon_loss"] = last_val_loss
         else:
             result["val_accuracy"] = last_val_acc

@@ -94,6 +94,10 @@ def assert_batch_contract(batch: dict, leading_micro: bool = False) -> None:
     import chex
 
     lead = 2 if leading_micro else 1
+    if "tokens" in batch:  # a next-token batch: (B, T) ids and nothing else
+        chex.assert_rank(batch["tokens"], lead + 1)
+        chex.assert_type(batch["tokens"], jnp.int32)
+        return
     clips = [batch[k] for k in ("slow", "fast", "video") if k in batch]
     assert clips, "batch has neither 'video' nor 'slow'/'fast' clips"
     for c in clips:
@@ -163,7 +167,10 @@ def _make_update_step(
 
     `grad_fn(params, batch_stats, batch, key) -> ((loss, (new_stats, correct,
     count)), grads)` — a value_and_grad with has_aux; the self-supervised
-    wrapper passes batch_stats/correct/count through untouched. Gradient
+    wrapper passes batch_stats/correct/count through untouched. The aux may
+    end in a fourth entry, a dict of the task's own step outputs (scalars):
+    they join the step's metrics under their own names (`make_lm_step`; not
+    under accumulation, where a step is several forwards). Gradient
     accumulation is an in-graph `lax.scan` over the leading micro-batch axis
     syncing ONCE per effective step; the returned step is jitted with state
     donation (params update in place in HBM).
@@ -197,15 +204,17 @@ def _make_update_step(
             batch = _fold_micro_axis(batch)
         if accum_steps == 1 or pipelined:
             batch = _constrain_batch(batch, mesh, leading_micro=False)
-            (loss, (new_stats, correct, count)), grads = grad_fn(
+            (loss, (new_stats, correct, count, *extra)), grads = grad_fn(
                 state.params, state.batch_stats, batch, key
             )
         else:
             batch = _constrain_batch(batch, mesh, leading_micro=True)
 
+            extra = ()  # a task's own outputs are of one forward, not a scan
+
             def micro(carry, mb):
                 grads_acc, stats, i = carry
-                (loss_i, (stats, corr_i, cnt_i)), g = grad_fn(
+                (loss_i, (stats, corr_i, cnt_i, *_)), g = grad_fn(
                     state.params, stats, mb, jax.random.fold_in(key, i)
                 )
                 grads_acc = jax.tree.map(jnp.add, grads_acc, g)
@@ -255,6 +264,8 @@ def _make_update_step(
             ema_params=new_ema,
         )
         metrics = {"loss": loss, "grad_norm": grad_norm}
+        for outputs in extra:
+            metrics.update(outputs)
         if skipped is not None:
             metrics["skipped"] = skipped
         if health_metrics:
@@ -446,6 +457,100 @@ def make_pretrain_step(
                              ema_decay=ema_decay,
                              health_metrics=health_metrics,
                              guard_skip=guard_skip, pipeline=pipeline)
+
+
+# the next-token step's own outputs -> the names the deferred logger gives
+# them (docs/TOKENS.md, docs/OBSERVABILITY.md): the counts as they are, the
+# two ratios among the window's `obs/` values
+LM_LOG_KEYS = {
+    "tokens": "tokens",
+    "moe_local_pairs": "moe_local_pairs",
+    "moe_expert_rows_max": "moe_expert_rows_max",
+    "moe_expert_rows_mean": "moe_expert_rows_mean",
+    "moe_local_pair_share": "obs/moe_local_pair_share",
+    "moe_expert_load_max_over_mean": "obs/moe_expert_load_max_over_mean",
+}
+
+
+def _next_token_batch(batch: dict):
+    """tokens (B, T) -> (inputs, targets, weights): position t is scored on
+    token t + 1, the last position of a sequence on nothing."""
+    tokens = batch["tokens"]
+    targets = jnp.roll(tokens, -1, axis=1)
+    weights = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
+    if batch.get("mask") is not None:
+        weights = weights * batch["mask"][:, None]
+    return tokens, targets, weights
+
+
+def make_lm_step(
+    model,
+    tx: optax.GradientTransformation,
+    mesh,
+    accum_steps: int = 1,
+    lr_schedule: Optional[Callable] = None,
+    debug_asserts: bool = False,
+    ema_decay: float = 0.0,
+    health_metrics: bool = False,
+    guard_skip: bool = False,
+) -> Callable:
+    """Build the next-token step: `step(state, batch, key) -> (state,
+    metrics)` over batches {"tokens": (B, T) int32}. The loss is the mean
+    cross-entropy of positions 0..T-2 against the tokens that follow them,
+    over the vocabulary slice the model holds, in float32 (the model sums it
+    in sequence blocks). Besides loss and next-token accuracy the step puts
+    out, with no fetch of their own (they ride the deferred logger):
+    `tokens` (positions the model ran), `moe_local_pairs` ((token, held
+    expert) pairs computed, all layers), `moe_expert_rows_max` /
+    `moe_expert_rows_mean` (the fullest held expert's rows and the mean, the
+    layer where the ratio is worst), and the two ratios a log window reports,
+    `moe_local_pair_share` (pairs a token a layer) and
+    `moe_expert_load_max_over_mean`."""
+
+    def forward_loss(params, batch_stats, batch, key):
+        del key  # no dropout, no mask to draw
+        tokens, targets, weights = _next_token_batch(batch)
+        out = model.apply({"params": params}, tokens, targets=targets,
+                          weights=weights, train=True)
+        count = out["count"]
+        loss = out["loss_sum"] / jnp.maximum(count, 1.0)
+        rows = out["expert_rows"].astype(jnp.float32)     # (layers, held)
+        positions = jnp.float32(tokens.size)
+        ratio = rows.max(axis=1) / jnp.maximum(rows.mean(axis=1), 1e-9)
+        worst = jnp.argmax(ratio)
+        outputs = {
+            "tokens": positions,
+            "moe_local_pairs": rows.sum(),
+            "moe_expert_rows_max": rows.max(axis=1)[worst],
+            "moe_expert_rows_mean": rows.mean(axis=1)[worst],
+            "moe_local_pair_share": rows.sum() / (positions * rows.shape[0]),
+            "moe_expert_load_max_over_mean": ratio[worst],
+        }
+        return loss, (batch_stats, out["correct"], count, outputs)
+
+    grad_fn = jax.value_and_grad(forward_loss, has_aux=True)
+    return _make_update_step(grad_fn, tx, mesh, accum_steps, lr_schedule,
+                             with_accuracy=True, debug_asserts=debug_asserts,
+                             ema_decay=ema_decay,
+                             health_metrics=health_metrics,
+                             guard_skip=guard_skip)
+
+
+def make_lm_eval_step(model, mesh) -> Callable:
+    """Eval for a next-token model: summed cross-entropy and hits over the
+    scored positions (the `SumMetrics` contract; `count` counts positions)."""
+
+    def eval_step(state: TrainState, batch: dict) -> dict:
+        batch = _constrain_batch(batch, mesh, leading_micro=False)
+        eval_params = (state.ema_params if state.ema_params is not None
+                       else state.params)
+        tokens, targets, weights = _next_token_batch(batch)
+        out = model.apply({"params": eval_params}, tokens, targets=targets,
+                          weights=weights, train=False)
+        return {"loss_sum": out["loss_sum"], "correct": out["correct"],
+                "count": out["count"]}
+
+    return jax.jit(eval_step)
 
 
 def make_pretrain_eval_step(model, mesh) -> Callable:
