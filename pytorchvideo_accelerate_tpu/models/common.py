@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
+from pytorchvideo_accelerate_tpu.ops import lane_fold
 from pytorchvideo_accelerate_tpu.precision import end_island, f32_island
 
 Dtype = Any
@@ -112,16 +113,21 @@ class BNAffine(nn.Module):
         return mul, bias - mean * mul
 
 
-def batch_norm_stats(raw32):
+def batch_norm_stats(raw32, group: int = 1):
     """Per-channel batch (mean, var) of a raw conv output, f32, the
     fast-variance form nn.BatchNorm uses (E[x^2] - E[x]^2, clamped).
     Under pjit the batch axis is one global sharded tensor, so these are
     sync-BN global stats by construction — same semantics as the unfused
-    nn.BatchNorm path (module docstring above)."""
+    nn.BatchNorm path (module docstring above). `group` > 1: the last
+    axis holds `group` output columns of each channel (ops/lane_fold.py),
+    whose moments are averaged into the channel's."""
     axes = tuple(range(raw32.ndim - 1))
     mean = jnp.mean(raw32, axis=axes)
-    var = jnp.maximum(jnp.mean(raw32 * raw32, axis=axes) - mean * mean, 0.0)
-    return mean, var
+    mean2 = jnp.mean(raw32 * raw32, axis=axes)
+    if group > 1:
+        mean = mean.reshape(group, -1).mean(axis=0)
+        mean2 = mean2.reshape(group, -1).mean(axis=0)
+    return mean, jnp.maximum(mean2 - mean * mean, 0.0)
 
 
 def fused_train_norm_act(raw, bn: BNAffine, features: int, act: str,
@@ -143,7 +149,13 @@ def fused_train_norm_act(raw, bn: BNAffine, features: int, act: str,
 class ConvBNAct(nn.Module):
     """conv3d -> BN -> activation, the unit both ResNet and X3D stems/stages
     are made of (pytorchvideo's create_conv_patch_embed / Net blocks, cited
-    from the reference call sites at run.py:107,115 [external model zoo])."""
+    from the reference call sites at run.py:107,115 [external model zoo]).
+
+    Three lowerings of one function and one param tree: nn.Conv +
+    nn.BatchNorm; the `fused` kernels where that knob arms them; and, on
+    the TPU, the lane fold for a site with few input channels and fewer
+    than 128 output channels (an RGB stem), chosen from the site's static
+    shapes alone (`_lane_fold_group`; docs/KERNELS.md)."""
 
     features: int
     kernel: Tuple[int, int, int]
@@ -165,6 +177,9 @@ class ConvBNAct(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
+        group = self._lane_fold_group(x)
+        if group:
+            return self._lane_folded(x, train, group)
         act_name = fusable_act_name(self.act)
         if (self.fused != "off" and self.use_bn and not self.use_bias
                 and self.groups == 1 and tuple(self.stride) == (1, 1, 1)
@@ -191,6 +206,48 @@ class ConvBNAct(nn.Module):
         if self.act is not None:
             x = self.act(x)
         return x
+
+    @nn.nowrap
+    def _lane_fold_group(self, x) -> int:
+        """Output columns this site folds into its channel axis
+        (ops/lane_fold.py): nonzero for an RGB stem on the TPU. `init` runs
+        un-jitted and needs the parameters' shapes only: it keeps nn.Conv's
+        eager programs."""
+        if (self.groups != 1 or self.use_bias or not self.use_bn
+                or fusable_act_name(self.act) is None
+                or self.is_initializing() or not lane_fold.takes_fold()):
+            return 0
+        return lane_fold.fold_group(x.shape[-1], self.features, self.kernel,
+                                    self.stride, x.shape[-2])
+
+    @nn.nowrap
+    def _lane_folded(self, x, train: bool, group: int):
+        """The site as a lane-filling contraction: conv, batch statistics,
+        affine and activation on the folded (..., W/G, G*C) tensor, whose
+        last axis fills the 128 lanes, then one reshape back. Same param
+        tree as the nn.Conv / nn.BatchNorm it replaces."""
+        lane_fold.note_site(self.path)
+        w = ConvKernelParam(self.features, tuple(self.kernel),
+                            x.shape[-1], name="conv")()
+        bn = BNAffine(momentum=self.bn_momentum, eps=self.bn_eps,
+                      name="norm")
+        # fold, expansion, conv and un-fold (and their backward) under the
+        # scope the nn.Conv would open, as `_fused` does
+        with jax.named_scope("conv"):
+            raw = lane_fold.lane_fold_conv3d(
+                x.astype(self.dtype), w.astype(self.dtype), self.stride,
+                group)
+        raw32 = f32_island(raw)
+        if train:
+            mul, add = bn(self.features, *batch_norm_stats(raw32, group),
+                          train=True)
+        else:
+            mul, add = bn(self.features, train=False)
+        # a known activation is elementwise: it commutes with the fold
+        y = raw32 * jnp.tile(mul, group) + jnp.tile(add, group)
+        y = end_island(y if self.act is None else self.act(y), self.dtype)
+        with jax.named_scope("conv"):
+            return lane_fold.unfold(y, group)
 
     def _fused(self, x, train: bool, act_name: str):
         from pytorchvideo_accelerate_tpu.ops.pallas_fused import (

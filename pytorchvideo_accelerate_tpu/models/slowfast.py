@@ -77,6 +77,8 @@ class SlowFast(nn.Module):
         fast = fast.astype(self.dtype)
 
         fast_stem = self.stem_features // self.beta_inv  # 8 for r50
+        # 3 -> 64 and 3 -> 8 channels: on the TPU ConvBNAct lowers both as
+        # a lane-filling contraction (ops/lane_fold.py), by their shapes
         slow = ConvBNAct(
             self.stem_features, kernel=(1, 7, 7), stride=(1, 2, 2),
             dtype=self.dtype, name="slow_stem",

@@ -1128,6 +1128,8 @@ class Trainer:
         # bench.py reports on its headline line)
         collector = obs.get_collector() if self.obs_on else None
         loader_rows = LoaderRowCounts()
+        # a trace-time fact of the step, logged once, with the first window
+        fold_sites_gauge = ["pva_conv_lane_fold_sites"]
         epoch_spans: Dict[str, float] = {}
         loop_thread = threading.get_ident()
         tokens_per_step = (
@@ -1163,6 +1165,10 @@ class Trainer:
             share = loader_rows.window_share()
             if share is not None:
                 vals["obs/loader_rows_in_place_share"] = share
+            if fold_sites_gauge:
+                gauge = obs.get_registry().get(fold_sites_gauge.pop())
+                if gauge is not None:
+                    vals["obs/conv_lane_fold_sites"] = gauge.value()
             if tokens_per_step and window_wall and "iter" in window:
                 # a next-token model's rate over the window: the window's
                 # iterations (the `iter` span's count), host arithmetic

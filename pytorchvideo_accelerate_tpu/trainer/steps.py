@@ -33,6 +33,8 @@ import optax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from pytorchvideo_accelerate_tpu.obs.registry import get_registry
+from pytorchvideo_accelerate_tpu.ops import lane_fold
 from pytorchvideo_accelerate_tpu.parallel.mesh import batch_axes
 from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
 
@@ -394,13 +396,19 @@ def make_train_step(
                              + (1.0 - w) * x[::-1].astype(jnp.float32))
                     batch[k] = mixed.astype(x.dtype)
             labels2 = batch["label"][::-1]
-        logits, updates = model.apply(
-            {"params": params, "batch_stats": batch_stats},
-            model_inputs(batch),
-            train=True,
-            rngs={"dropout": key},
-            mutable=["batch_stats"],
-        )
+        with lane_fold.count_sites() as folded:
+            logits, updates = model.apply(
+                {"params": params, "batch_stats": batch_stats},
+                model_inputs(batch),
+                train=True,
+                rngs={"dropout": key},
+                mutable=["batch_stats"],
+            )
+        # a fact of the trace, set while the step is traced (no op added)
+        get_registry().gauge(
+            "pva_conv_lane_fold_sites",
+            "ConvBNAct sites of the traced train step lowered as a "
+            "lane-filling contraction (ops/lane_fold.py)").set(len(folded))
         if labels2 is not None:
             loss_a, correct_a, count = _loss_and_metrics(
                 logits, batch["label"], mask, label_smoothing)

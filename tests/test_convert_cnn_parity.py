@@ -264,11 +264,18 @@ class TorchSlowFastTiny(nn.Module):
         return self.blocks["6"].proj(pooled)
 
 
-def test_slowfast_forward_parity():
+# the stems' TPU lowering (ops/lane_fold.py) forced on the CPU, at a width
+# its shape rule takes (128 = 64 columns x stride 2 of the 2-channel fast
+# stem): converted weights load into the same tree and give the same numbers
+@pytest.mark.parametrize("stems, width", [("nn_conv", 16), ("lane_fold", 128)])
+def test_slowfast_forward_parity(monkeypatch, stems, width):
+    from pytorchvideo_accelerate_tpu.ops import lane_fold
+
+    monkeypatch.setattr(lane_fold, "takes_fold", lambda: stems == "lane_fold")
     tm = TorchSlowFastTiny().eval()
     _randomize(tm, 1)
     rng = np.random.default_rng(1)
-    fast_np = rng.standard_normal((2, 8, 16, 16, 3)).astype(np.float32)
+    fast_np = rng.standard_normal((2, 8, 16, width, 3)).astype(np.float32)
     slow_np = fast_np[:, ::2]  # alpha=2
     with torch.no_grad():
         theirs = tm(_nchw(slow_np), _nchw(fast_np)).numpy()
@@ -279,8 +286,10 @@ def test_slowfast_forward_parity():
     pathways = (jnp.asarray(slow_np), jnp.asarray(fast_np))
     variables = fm.init(jax.random.key(0), pathways)
     tree = _convert_and_check_coverage(tm, "slowfast_r50", variables)
-    ours = fm.apply({"params": tree["params"],
-                     "batch_stats": tree["batch_stats"]}, pathways)
+    with lane_fold.count_sites() as folded:
+        ours = fm.apply({"params": tree["params"],
+                         "batch_stats": tree["batch_stats"]}, pathways)
+    assert ({("slow_stem",), ("fast_stem",)} <= folded) == (stems == "lane_fold")
     np.testing.assert_allclose(np.asarray(ours), theirs, rtol=1e-4, atol=1e-4)
 
 

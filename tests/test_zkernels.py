@@ -393,3 +393,237 @@ def test_even_kernel_and_mode_validation():
     with pytest.raises(ValueError, match="fused_kernels"):
         create_model(ModelConfig(name="tiny3d", num_classes=2,
                                  fused_kernels="bogus"))
+
+
+# --- lane fold: the RGB stems as a lane-filling contraction -----------------
+# (ops/lane_fold.py; docs/KERNELS.md). The rule's backend half is off on the
+# CPU, so every test here forces it and leaves the shape half to decide.
+
+@pytest.fixture
+def fold_forced(monkeypatch):
+    from pytorchvideo_accelerate_tpu.ops import lane_fold
+
+    monkeypatch.setattr(lane_fold, "takes_fold", lambda: True)
+    return lane_fold
+
+
+# name: (kernel, stride, Cin, Cout, input (B,T,H,W), the G the rule gives)
+LANE_FOLD_SITES = {
+    "fast_stem": ((5, 7, 7), (1, 2, 2), 3, 8, (2, 4, 12, 64), 16),
+    "slow_stem": ((1, 7, 7), (1, 2, 2), 3, 64, (2, 2, 12, 16), 2),
+    "stride1_24ch": ((3, 3, 3), (1, 1, 1), 3, 24, (1, 4, 8, 20), 5),
+    # W = 48 is no multiple of G x stride_w = 32: keeps nn.Conv
+    "width_not_divisible": ((5, 7, 7), (1, 2, 2), 3, 8, (2, 4, 12, 48), 0),
+    # a 64-channel input is no stem: the rule does not fire
+    "cin64": ((1, 3, 3), (1, 1, 1), 64, 64, (1, 2, 8, 16), 0),
+    "cout128": ((1, 3, 3), (1, 2, 2), 3, 128, (1, 2, 8, 16), 0),
+}
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16"])
+@pytest.mark.parametrize("site", sorted(LANE_FOLD_SITES))
+def test_lane_fold_matches_conv_general_dilated(fold_forced, site, policy):
+    """The folded lowering against `lax.conv_general_dilated`: the op's
+    output and weight gradient, then the ConvBNAct site around it (train
+    mode: batch statistics, running averages, gradients of all three
+    parameters) against the same site with the fold off."""
+    from pytorchvideo_accelerate_tpu.models.common import ConvBNAct
+
+    kernel, stride, cin, cout, shape, want_group = LANE_FOLD_SITES[site]
+    dtype = jnp.dtype(policy)
+    # f32: the two contractions differ in summation order only; bf16: both
+    # round an f32 accumulation once, the gradient sums G rounded parts
+    tol = 2e-5 if policy == "float32" else 2e-2
+    rng = np.random.default_rng(11)
+    x = _x(rng, shape + (cin,))
+    group = fold_forced.fold_group(cin, cout, kernel, stride, shape[-1])
+    assert group == want_group
+
+    if group:
+        w = _x(rng, kernel + (cin, cout)) * 0.1
+        pads = [(k // 2, k // 2) for k in kernel]
+
+        def plain(w):
+            return lax.conv_general_dilated(
+                x.astype(dtype), w.astype(dtype), stride, pads,
+                dimension_numbers=("NDHWC", "DHWIO", "NDHWC"))
+
+        def folded(w):
+            return fold_forced.unfold(fold_forced.lane_fold_conv3d(
+                x.astype(dtype), w.astype(dtype), stride, group), group)
+
+        ref = np.asarray(plain(w), np.float32)
+        got = np.asarray(folded(w), np.float32)
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(got, ref, rtol=tol,
+                                   atol=tol * np.abs(ref).max())
+        ct = _x(rng, ref.shape)
+        g_ref, g_got = (np.asarray(jax.grad(
+            lambda w: jnp.sum(f(w).astype(jnp.float32) * ct))(w))
+            for f in (plain, folded))
+        assert (np.linalg.norm(g_got - g_ref)
+                <= tol * np.linalg.norm(g_ref))
+
+    site_mod = ConvBNAct(cout, kernel=kernel, stride=stride, dtype=dtype)
+    v = site_mod.init(jax.random.key(0), x)
+
+    def run(params, fold):
+        fold_forced.takes_fold = lambda: fold
+        with fold_forced.count_sites() as sites:
+            y, mut = site_mod.apply(
+                {"params": params, "batch_stats": v["batch_stats"]}, x,
+                train=True, mutable=["batch_stats"])
+        return jnp.sum(y.astype(jnp.float32) ** 2), (y, mut, sites)
+
+    outs = {}
+    for fold in (False, True):
+        (_, (y, mut, sites)), grads = jax.value_and_grad(
+            run, has_aux=True)(v["params"], fold)
+        outs[fold] = (y, mut, grads)
+        assert len(sites) == (1 if fold and group else 0)
+    for a, b in zip(jax.tree.leaves(outs[False]), jax.tree.leaves(outs[True])):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if not group:
+            np.testing.assert_array_equal(a, b)  # the same nn.Conv graph
+        else:
+            assert np.linalg.norm(a - b) <= 10 * tol * np.linalg.norm(a)
+    # eval mode (serving): running statistics, the same lowering
+    fold_forced.takes_fold = lambda: True
+    e1 = np.asarray(site_mod.apply(v, x), np.float32)
+    fold_forced.takes_fold = lambda: False
+    e0 = np.asarray(site_mod.apply(v, x), np.float32)
+    np.testing.assert_allclose(e1, e0, rtol=10 * tol,
+                               atol=10 * tol * np.abs(e0).max())
+
+
+def _toy_slowfast():
+    """Stems at the published widths (3 -> 64 and 3 -> 8), so that no other
+    site has few enough input channels for the rule."""
+    from pytorchvideo_accelerate_tpu.models.slowfast import SlowFast
+
+    rng = np.random.default_rng(12)
+    fast = _x(rng, (2, 8, 16, 64, 3))
+    model = SlowFast(num_classes=5, depths=(1, 1), stem_features=64,
+                     slow_temporal_kernels=(1, 3), dropout_rate=0.0)
+    return model, (fast[:, ::4], fast)
+
+
+def test_slowfast_lane_fold_is_pure_lowering(fold_forced):
+    """Fold forced or not: the same parameter tree (shapes and values at
+    the same key), eval/train parity on the same variables, matching
+    running-stat updates and gradients; both stems take it, nothing else."""
+    model, pathways = _toy_slowfast()
+    v = model.init(jax.random.key(0), pathways)
+    fold_forced.takes_fold = lambda: False
+    v_off = model.init(jax.random.key(0), pathways)
+    assert jax.tree.structure(v) == jax.tree.structure(v_off)
+    for a, b in zip(jax.tree.leaves(v), jax.tree.leaves(v_off)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def loss(params, fold):
+        fold_forced.takes_fold = lambda: fold
+        with fold_forced.count_sites() as sites:
+            out, mut = model.apply(
+                {"params": params, "batch_stats": v["batch_stats"]},
+                pathways, train=True, mutable=["batch_stats"])
+        return jnp.sum(out ** 2), (out, mut, sites)
+
+    (_, (out0, mut0, sites0)), g0 = jax.value_and_grad(
+        loss, has_aux=True)(v["params"], False)
+    (_, (out1, mut1, sites1)), g1 = jax.value_and_grad(
+        loss, has_aux=True)(v["params"], True)
+    assert sites0 == set()
+    assert sites1 == {("slow_stem",), ("fast_stem",)}
+    np.testing.assert_allclose(np.asarray(out0), np.asarray(out1),
+                               rtol=1e-3, atol=1e-3)
+    for a, b in zip(jax.tree.leaves((mut0, g0)), jax.tree.leaves((mut1, g1))):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.linalg.norm(a - b) <= 1e-3 * max(np.linalg.norm(a), 1e-6)
+    fold_forced.takes_fold = lambda: True
+    e1 = np.asarray(model.apply(v, pathways))
+    fold_forced.takes_fold = lambda: False
+    np.testing.assert_allclose(e1, np.asarray(model.apply(v, pathways)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def _lowered_toy_step(model, batch):
+    """(the lowered train step of a toy model on one device, the value the
+    `pva_conv_lane_fold_sites` gauge holds after its trace)."""
+    import optax
+
+    from pytorchvideo_accelerate_tpu.config import MeshConfig
+    from pytorchvideo_accelerate_tpu.obs import get_registry
+    from pytorchvideo_accelerate_tpu.parallel.mesh import make_train_mesh
+    from pytorchvideo_accelerate_tpu.trainer.steps import (
+        make_train_step,
+        model_inputs,
+    )
+    from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
+
+    mesh = make_train_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    tx = optax.sgd(0.01)
+    v = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), model_inputs(batch)))
+    state = jax.eval_shape(
+        lambda p, s: TrainState.create(p, s, tx), v["params"],
+        v["batch_stats"])
+    lowered = make_train_step(model, tx, mesh).lower(
+        state, batch, jax.random.key(0))
+    return lowered, get_registry().get("pva_conv_lane_fold_sites").value()
+
+
+@pytest.mark.parametrize("family", ["slowfast", "slowfast_unforced", "x3d"])
+def test_lane_fold_sites_gauge(fold_forced, family):
+    """The gauge counts the ConvBNAct sites of the traced train step that
+    took the fold: both SlowFast stems when forced, none on the CPU's own
+    rule, none for X3D (its RGB stem `stem_xy` is an nn.Conv)."""
+    if family == "x3d":
+        from pytorchvideo_accelerate_tpu.models.x3d import X3D
+
+        model = X3D(num_classes=5, depths=(1, 1), stem_features=8,
+                    stage_features=(8, 16), head_features=32,
+                    dropout_rate=0.0)
+        batch = {"video": jnp.zeros((2, 4, 16, 64, 3))}
+    else:
+        model, (slow, fast) = _toy_slowfast()
+        batch = {"slow": slow, "fast": fast}
+    batch["label"] = jnp.zeros((2,), jnp.int32)
+    if family == "slowfast_unforced":
+        fold_forced.takes_fold = lambda: jax.default_backend() == "tpu"
+    _, sites = _lowered_toy_step(model, batch)
+    assert sites == (2 if family == "slowfast" else 0)
+
+
+def test_lane_fold_ops_sit_under_the_conv_scope(fold_forced):
+    """Every op the fold adds to a SlowFast train step (the input's fold,
+    the weight's expansion, the conv, forward and backward) carries the
+    scope the unfused nn.Conv would open, `<site>/conv`: the benchmark's
+    `conv_roofline` and the ledger's breakdown select device time by it."""
+    import re
+
+    model, (slow, fast) = _toy_slowfast()
+    batch = {"slow": slow, "fast": fast, "label": jnp.zeros((2,), jnp.int32)}
+    lowered, sites = _lowered_toy_step(model, batch)
+    assert sites == 2
+    # the text names its locations by alias: an op's is
+    # loc("<name stack>"(#call stack)), a call stack callsite(#inner at
+    # #outer), a frame loc("<function>"(#file)), a file loc("<path>":line)
+    defs = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$",
+                           lowered.as_text(debug_info=True), re.M))
+
+    def innermost_file(ref):
+        d = defs[ref]
+        m = (re.match(r"callsite\((#loc\d+) at", d)
+             or re.match(r'"[^"]*"\((#loc\d+)\)$', d))
+        if m:
+            return innermost_file(m.group(1))
+        m = re.match(r'"([^"]+)":\d+', d)
+        return m.group(1) if m else ""
+
+    stacks = [m.group(1) for m in (
+        re.match(r'"(jit\([^"]*)"\((#loc\d+)\)$', d) for d in defs.values())
+        if m and innermost_file(m.group(2)).endswith("ops/lane_fold.py")]
+    assert len(stacks) >= 8  # 2 sites x (reshape, einsum, conv) x fwd/bwd
+    stray = [s for s in stacks
+             if not re.search(r"(fast|slow)_stem\)?/conv/", s)]
+    assert not stray, stray[:5]

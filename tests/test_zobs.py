@@ -823,6 +823,10 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     shares = [ln["obs/loader_rows_in_place_share"] for ln in lines
               if "obs/loader_rows_in_place_share" in ln]
     assert shares and all(0.5 < s <= 1.0 for s in shares), shares
+    # once, with the first window: the conv sites of the traced step that
+    # took the lane fold (ops/lane_fold.py; none on the CPU)
+    assert [ln["obs/conv_lane_fold_sites"] for ln in lines
+            if "obs/conv_lane_fold_sites" in ln] == [0.0]
 
 
 def test_zz_fit_spans_on_the_profilers_clock(tmp_path, _tiny_slow_r50):
@@ -992,6 +996,7 @@ def test_zz_fit_exception_dumps_flight_record(tmp_path, _tiny_slow_r50):
     from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
 
     cfg = _cfg(tmp_path)
+    t_start = time.time()  # the ring is the process's: older tests' too
     tr = Trainer(cfg)
     real_step, calls = tr.train_step, []
 
@@ -1009,7 +1014,8 @@ def test_zz_fit_exception_dumps_flight_record(tmp_path, _tiny_slow_r50):
     assert exc and exc[-1]["name"] == "RuntimeError"
     assert "injected step failure" in exc[-1]["message"]
     # the spans of the ring are intervals that carry the step they belong to
-    spans = [e for e in data["events"] if e["kind"] == "span"]
+    spans = [e for e in data["events"]
+             if e["kind"] == "span" and e["ts"] >= t_start]
     assert {"iter", "step", "input_wait"} <= {e["name"] for e in spans}
     failed = [e for e in spans if e.get("error")]
     assert {(e["name"], e["step"]) for e in failed} == {("step", 1),
