@@ -21,14 +21,23 @@ already calls every step:
 * a tracker object is appended to `trainer.trackers`, so every flush of the
   deferred logger (losses, and the `obs` spans of each log window) reaches it.
 
-No step loop of the harness's own, no pre-placed batches, no second trainer.
+No step loop of the harness's own, no second trainer. Every batch comes from
+the program's own pipeline. A cell whose file sets `resident_batches` to K
+keeps the K batches placed last before the window starts on the device,
+closes the prefetcher's epoch there (its worker ends and the loader's pending
+decodes are cancelled) and hands `fit()` those K in turn for every step of
+the window: the step donates its state only, so a placed batch can be fed
+again. The window then shows the step, the dispatch and the logger with no
+input to wait for; the checked steps and the warm-up are fed as in any cell.
 """
 
 from __future__ import annotations
 
+import collections
 import gc
 import glob
 import os
+import threading
 import time
 
 from benchmarks import reference
@@ -99,6 +108,9 @@ class FitTap:
         self.tracing = False
         self.trace_t0 = self.trace_t1 = None
         self.pop_wait_calls = 0
+        self.pulled = 0  # batches taken from the prefetcher
+        self.worker_ended = None  # a thread ended where the epoch was closed
+        self.live_bytes = 0  # `bytes_in_use` at the window's ends, the larger
 
     def __getattr__(self, name):
         return getattr(self.inner, name)
@@ -109,16 +121,29 @@ class FitTap:
         import jax
 
         it = self.inner.epoch(epoch, from_start)
+        start = self.plan["window_start"]
+        keep = self.plan.get("resident_batches", 0)
+        kept = collections.deque(maxlen=keep)  # the batches placed last
         index = 0
         try:
             while True:
-                with jax.profiler.TraceAnnotation("bench/prefetch_next"):
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        raise RuntimeError(
-                            "the epoch ran out of clips before the window "
-                            "closed: raise synthetic_num_videos") from None
+                if keep and index >= start:
+                    if index == start:
+                        before = set(threading.enumerate())
+                        it.close()  # the worker ends, pending decodes go
+                        self.worker_ended = any(
+                            not t.is_alive() for t in before)
+                    batch = kept[(index - start) % len(kept)]
+                else:
+                    with jax.profiler.TraceAnnotation("bench/prefetch_next"):
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            raise RuntimeError(
+                                "the epoch ran out of clips before the window "
+                                "closed: raise synthetic_num_videos") from None
+                    self.pulled += 1
+                    kept.append(batch)
                 self.on_boundary(index, batch)
                 yield batch
                 index += 1
@@ -135,6 +160,7 @@ class FitTap:
             self.window_wait_s = self.inner.wait_s - self.wait_at_t0
             if self.tracing:
                 self.stop_trace()
+            self.live_bytes = max(self.live_bytes, live_bytes_in_use())
         return self.inner.pop_wait()
 
     # --- step boundaries --------------------------------------------------
@@ -171,6 +197,7 @@ class FitTap:
                                          profiler_options=options)
                 self.tracing = True
                 self.trace_t0 = time.perf_counter()
+            self.live_bytes = live_bytes_in_use()
             self.t0 = time.perf_counter()
             self.wait_at_t0 = self.inner.wait_s
         if self.t0 is not None and not self.ending:
@@ -267,17 +294,30 @@ def compiled_step_scopes(trainer, batch_struct):
     return hlo.scopes(compiled.as_text())
 
 
-def memory_peak_bytes(devices):
-    """Peak HBM held on the fullest chip. On this runtime
+def live_bytes_in_use():
+    """`bytes_in_use` of the fullest chip, now: the arrays that are alive."""
+    import jax
+
+    return max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+               for d in jax.local_devices())
+
+
+def memory_peak_bytes(devices, live_bytes=0):
+    """Peak HBM held at once on the fullest chip. On this runtime
     `peak_bytes_in_use` counts live arrays only; the running program's
-    scratch (the step's temp buffers, 13.25 GiB for slowfast_r50 at batch 8)
+    scratch (the step's temp buffers, 9.4 GiB for slowfast_r50 at batch 8)
     is `peak_bytes_reserved`, which matches the compiler's `memory_analysis()`
-    to 0.4% (PERF.md, Findings). The peak is their sum."""
+    to 0.4% (PERF.md, Findings). The two peaks need not fall together (the
+    token cell's live arrays peak while its weights are made, before any step
+    runs), so they are not added: the peak is the larger of the live arrays'
+    own peak and what the window held, its live arrays (`live_bytes`, read by
+    the tap at the window's two ends) with the largest scratch beside them."""
     peaks = []
     for d in devices:
         stats = d.memory_stats() or {}
-        peaks.append(int(stats.get("peak_bytes_in_use", 0))
-                     + int(stats.get("peak_bytes_reserved", 0)))
+        peaks.append(max(int(stats.get("peak_bytes_in_use", 0)),
+                         int(live_bytes)
+                         + int(stats.get("peak_bytes_reserved", 0))))
     return max(peaks)
 
 
@@ -320,6 +360,7 @@ def run(ctx):
         trace_dir = os.path.join(ctx["work_dir"], "trace")
     log_every = cfg.tracking.log_every
     settle = cell["check_steps"] + cell["warmup_steps"]
+    toy = cell.get("rehearse", {}) if rehearse else {}
     plan = {
         "check_steps": int(cell["check_steps"]),
         "log_every": log_every,
@@ -329,6 +370,8 @@ def run(ctx):
         "trace_dir": trace_dir,
         "trace_seconds": min(float(cell.get("trace_seconds", 5.0)),
                              float(ctx["seconds"])),
+        "resident_batches": int(toy.get("resident_batches",
+                                        cell.get("resident_batches", 0))),
     }
     cfg.tracking.log_every = 1  # until the window starts: every loss is logged
 
@@ -348,7 +391,7 @@ def run(ctx):
     window_s = tap.t1 - tap.t0
     steps = tap.steps_in_window
     clips = steps * global_batch
-    peak = memory_peak_bytes(devices)
+    peak = memory_peak_bytes(devices, tap.live_bytes)
     _note(ctx, "fit returned", memory_stats=devices[0].memory_stats())
     compile_snapshot = ctx["counters"].snapshot()
 
@@ -392,6 +435,11 @@ def run(ctx):
         "step_count_gap": abs(int(fit["steps"]) - (plan["window_start"] + steps)),
         "recompiles": fit.get("train_recompiles"),
     }
+    if plan["resident_batches"]:
+        # the window was fed the kept batches only, and the prefetcher's
+        # worker ended where its epoch was closed
+        structure["window_batches_from_loader"] = tap.pulled - plan["window_start"]
+        structure["loader_worker_left"] = 0 if tap.worker_ended else None
     limits = cell["limits"]
     if rehearse:
         limits = cell.get("rehearse", {}).get("limits", limits)

@@ -221,7 +221,7 @@ def run(ctx):
         raise RuntimeError("fit() returned before the window opened or closed")
     window_s = tap.t1 - tap.t0
     steps = tap.steps_in_window
-    peak = memory_peak_bytes(devices)
+    peak = memory_peak_bytes(devices, tap.live_bytes)
     _note(ctx, "fit returned", memory_stats=devices[0].memory_stats())
     compile_snapshot = ctx["counters"].snapshot()
     step_scopes = None

@@ -193,7 +193,7 @@ run.main(["--workload", {cell!r}, "--seed", "5", "--seconds", "1",
 """
 
 
-def _rehearse(fault=None):
+def _rehearse(fault=None, cell=CELL):
     """The whole command at the toy geometry in a process of its own (one CPU
     device, as the cell's mesh wants), the look for a chip lifted, with the
     trainer's step builder wrapped so that the timed path is broken."""
@@ -202,24 +202,29 @@ def _rehearse(fault=None):
 
     env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="")
     proc = subprocess.run(
-        [sys.executable, "-c", _DRIVER.format(root=ROOT, fault=fault, cell=CELL)],
+        [sys.executable, "-c", _DRIVER.format(root=ROOT, fault=fault, cell=cell)],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
+CELLS = [CELL, "slowfast_r50.train_resident"]  # one job, two feeds of the window
+
+
 @pytest.mark.slow
-def test_rehearsal_of_a_sound_run_is_correct():
-    line = _rehearse()
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_of_a_sound_run_is_correct(cell):
+    line = _rehearse(cell=cell)
     assert line["correct"] is True, line["compared"]
     assert line["rehearsal"] is True and line["device"]["platform"] == "cpu"
     assert list(line)[-1] == "compared"
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch"])
-def test_broken_timed_path_is_not_correct(fault):
-    line = _rehearse(fault)
+def test_broken_timed_path_is_not_correct(fault, cell):
+    line = _rehearse(fault, cell)
     assert line["correct"] is False, line["compared"]
     if fault == "state_unchanged":
         # no leaf moved: the change reads 1 by the measure, on any seed

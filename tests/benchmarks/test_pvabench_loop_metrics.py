@@ -20,7 +20,6 @@ NEW = {  # quantity: (unit, better, source, layer)
 # a reader with no entry yet: a traced window holds 13-23 records, under its
 # 100-record rule, so the entries wait for a window that can feed it
 UNLISTED = ("iter_p90_ms",)
-REGIMES = {"device_paced": "slowfast_r50.train", "host_paced": "x3d_s.train"}
 
 
 def record(gstep, iter_s, ready=2):
@@ -100,25 +99,41 @@ def test_record_readers_return_nothing_where_there_is_no_record(res, capsys):
 
 
 def test_new_entries_have_reader_workloads_and_moves():
+    """Each of PR 25's quantities has a reader and, in every regime, an entry
+    with the unit, direction, source, layer and `moves` it names, listing
+    that regime's cells. Which cells a regime has is `BENCHMARK.json`'s own
+    `clips_per_s_per_chip.<regime>` list: a cell moves when a `benchmark` PR
+    finds that its spread needs the other bound, and entries are appended."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         doc = json.load(f)
     by_name = {m["name"]: m for m in doc["per_layer"]}
     layers = {m["layer"] for m in doc["per_layer"]
               if quantity(m["name"]) not in NEW}
+    regimes = {m["name"].split(".", 1)[1]: m["workloads"]
+               for m in doc["end_to_end"]
+               if quantity(m["name"]) == "clips_per_s_per_chip"}
+    assert set(regimes) == {"device_paced", "host_paced"}
     spec = Spec(ROOT)
     for q, (unit, better, source, layer) in NEW.items():
         assert callable(metric_module(q).read)
         assert layer in layers  # a layer the benchmark already names
-        for regime, cell in REGIMES.items():
-            m = by_name[f"{q}.{regime}"]
+        for regime, cells in regimes.items():
+            m = dict(by_name[f"{q}.{regime}"])
+            listed = m.pop("workloads")
             assert m == {"name": f"{q}.{regime}", "unit": unit,
                          "better": better, "source": source, "layer": layer,
-                         "moves": f"clips_per_s_per_chip.{regime}",
-                         "workloads": [cell]}
-            assert m["name"] in spec.metric_names("per_layer", cell)
-    # appended: what was there stands first, in its order
+                         "moves": f"clips_per_s_per_chip.{regime}"}
+            # cells of its regime, and only those; a cell that gives the
+            # reader nothing to read (`prefetch_ready_at_pop` where nothing
+            # waits on the prefetcher) is left out
+            assert listed and set(listed) <= set(cells)
+            for cell in listed:
+                assert m["name"] in spec.metric_names("per_layer", cell)
+        # every cell whose loop runs is read by the loop's two quantities
+        if layer == LOOP:
+            assert all(set(by_name[f"{q}.{r}"]["workloads"]) == set(cells)
+                       for r, cells in regimes.items())
     names = [m["name"] for m in doc["per_layer"]]
-    assert [quantity(n) for n in names[-6:]] == [q for q in NEW for _ in REGIMES]
     for q in UNLISTED:
         assert callable(metric_module(q).read)
         assert q not in {quantity(n) for n in names}
