@@ -208,7 +208,7 @@ def kernels_phase(rehearse: bool) -> None:
 
 def _train_argv(work: str, rehearse: bool) -> list:
     """The reference recipe's geometry (scripts/run_slowfast_r50.sh,
-    bench.py WORKLOADS): slowfast_r50, 32 frames, 256^2, batch 8, alpha 4,
+    benchmarks/configs/slowfast_r50.json): slowfast_r50, 32 frames, 256^2, batch 8, alpha 4,
     bf16, 700 classes — on synthetic clips, for a handful of steps. The
     learning rate is a tenth of the recipe's 0.1, at which a from-scratch
     network on random clips leaves 7.6 for the hundreds within two steps."""

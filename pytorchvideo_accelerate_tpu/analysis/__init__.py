@@ -8,7 +8,7 @@ effects, and discarded telemetry spans. `# pva: disable=<rule> -- why`
 suppresses a line, auditable via `pva-tpu-doctor`'s lint snapshot.
 Taxonomy and runbook: docs/STATIC_ANALYSIS.md.
 
-Stdlib-only on purpose: the linter runs in CI, in `bench.py --smoke`,
+Stdlib-only on purpose: the linter runs in CI, in tests/test_zlint.py
 and from the doctor without importing jax or the code under analysis.
 The one runtime piece (`RecompileGuard` -> `pva_train_recompiles`
 gauge) closes the loop the static `recompile` rule can only hint at.

@@ -1,6 +1,6 @@
 """`pva-tpu-lint`: the console front of the analysis package.
 
-Exit code contract (scripts/lint.sh and the bench smoke gate rely on
+Exit code contract (scripts/lint.sh relies on
 it): 0 = clean tree, 1 = findings, 2 = usage error. Output is one
 `path:line:col: [rule] message` line per finding (the shape every
 editor/CI annotator parses), or a JSON list with `--format json`.

@@ -1,7 +1,7 @@
 """Rule engine for the pva-tpu-lint static-analysis pass.
 
-Everything here is stdlib-`ast` + `tokenize`: the linter must run in CI,
-in `bench.py --smoke`, and inside `pva-tpu-doctor` without importing jax
+Everything here is stdlib-`ast` + `tokenize`: the linter must run in CI
+and inside `pva-tpu-doctor` without importing jax
 (or the package under analysis — a module with a broken import must still
 be lintable).
 
@@ -167,8 +167,8 @@ def iter_suppressions(source: str) -> Iterator[Suppression]:
 # --- runner -----------------------------------------------------------------
 
 def default_rules() -> List[Rule]:
-    """The shipped rule set (one import site so the CLI, the tests, the
-    bench smoke gate, and the doctor all lint with identical rules)."""
+    """The shipped rule set (one import site so the CLI, the tests and
+    the doctor all lint with identical rules)."""
     from pytorchvideo_accelerate_tpu.analysis.rules_dtype import DtypeLiteralRule
     from pytorchvideo_accelerate_tpu.analysis.rules_host_sync import HostSyncRule
     from pytorchvideo_accelerate_tpu.analysis.rules_knob import KnobReadRule
@@ -261,7 +261,7 @@ def iter_py_files(paths: Sequence[str]) -> Iterator[str]:
 def run_lint(paths: Sequence[str],
              rules: Optional[Sequence[Rule]] = None) -> List[Finding]:
     """Lint every .py file under `paths`; returns all unsuppressed findings
-    (empty list == clean tree, the CI/bench gate)."""
+    (empty list == clean tree, the CI gate)."""
     rules = list(rules) if rules is not None else default_rules()
     findings: List[Finding] = []
     for fp in iter_py_files(paths):

@@ -74,14 +74,15 @@ DEFAULT_F32_ISLANDS = frozenset({
 
 
 def _frames(eqn) -> List[Tuple[str, str]]:
-    """[(function_name, file_basename)] user frames, innermost first."""
-    try:
-        from jax._src import source_info_util
+    """[(function_name, file_basename)] user frames, innermost first
+    (gc_sharding names its sites with this too). `user_frames` takes the
+    Traceback and gives no frames for a missing one; anything it raises
+    is an API that moved and must be seen: with no frames every site
+    reads `<unknown>` and no allowlist entry can match."""
+    from jax._src import source_info_util
 
-        return [(f.function_name, os.path.basename(f.file_name))
-                for f in source_info_util.user_frames(eqn.source_info)]
-    except Exception:
-        return []
+    return [(f.function_name, os.path.basename(f.file_name))
+            for f in source_info_util.user_frames(eqn.source_info.traceback)]
 
 
 def _allowlisted(frames: Sequence[Tuple[str, str]],

@@ -1,11 +1,10 @@
 """graphcheck FLOPs pass: analytical per-primitive FLOPs from the jaxpr.
 
-Why it exists (ROADMAP item 1, "honest MFU"): the only FLOPs source the
-repo had was XLA's cost model (`compiled.cost_analysis()`), whose
-availability varies by backend/version — which is exactly why `mfu` has
-been null on every round where capture failed. Shapes don't vary: a
-`dot_general`'s FLOPs are arithmetic over its avals, a conv's over its
-output grid and kernel. This pass walks the closed jaxpr (recursing
+A count of the step AS TRACED, from shapes alone: a `dot_general`'s
+FLOPs are arithmetic over its avals, a conv's over its output grid and
+kernel. (The work the benchmark's `step_mfu` divides by is the plain
+reference's, `benchmarks/lib/flops.py`: a lowering's padding is not
+work.) This pass walks the closed jaxpr (recursing
 through pjit/custom-grad calls, multiplying scanned bodies by their trip
 count) and counts:
 
@@ -15,25 +14,19 @@ count) and counts:
   conv eqns in the differentiated jaxpr, so fwd+bwd is counted
   naturally, remat recompute included);
 - `elementwise`/`reduce`: 1 FLOP per output (resp. input) element for
-  the plain arithmetic primitives — keeps parity with the XLA cost
-  model tight on conv nets where BN/activation traffic is a few
-  percent. Transcendentals (exp/log/...) are deliberately *excluded*:
-  XLA books them under "transcendental", not "flops", and the parity
-  check compares against "flops".
+  the plain arithmetic primitives (BN/activation traffic is a few
+  percent on conv nets). Transcendentals (exp/log/...) are deliberately
+  *excluded*, as XLA books them under "transcendental", not "flops".
 
 `while` bodies can't be statically counted (trip count is dynamic);
 they are counted ONCE and surfaced in `caveats` — a lying silent zero
 is worse than a flagged lower bound. `cond` takes the max branch.
-
-The result cross-checks against the cost model where capture succeeds
-(graphcheck's flops pass findings) and becomes `mfu_analytic`'s
-numerator in the trainer/multichip bench lanes when capture fails.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 # 1-FLOP-per-element arithmetic primitives (XLA cost-model "flops" class).
 # Selects and comparisons ARE counted: the guard-armed train step wraps
@@ -71,7 +64,7 @@ def _prod(xs) -> int:
 #
 # A `pallas_call` is opaque to this walk (its body is a kernel jaxpr whose
 # eqns describe ONE grid program, not the whole op), so an unregistered
-# Pallas kernel would silently undercount `mfu_analytic` — exactly the
+# Pallas kernel would silently undercount the step — exactly the
 # lying-numerator failure this pass exists to prevent. Every in-tree
 # kernel therefore registers a per-kernel FLOPs hook here, keyed by the
 # kernel FUNCTION name (the kernel jaxpr's debug_info.func_name, or the
@@ -233,7 +226,7 @@ def jaxpr_flops(closed_jaxpr) -> Dict[str, Any]:
                 eqn_counts["pallas_call"] += 1
                 if hook is None:
                     # a silent zero here would quietly deflate
-                    # mfu_analytic — surface it (check_flops turns the
+                    # the count — surface it (check_flops turns the
                     # list into findings)
                     unregistered.append(kname)
                 else:
@@ -267,7 +260,7 @@ def jaxpr_flops(closed_jaxpr) -> Dict[str, Any]:
                 # pipeline): the body is an OPEN Jaxpr param describing
                 # ONE shard's program — the generic ClosedJaxpr recursion
                 # below misses it, silently zeroing the whole pipelined
-                # trunk out of mfu_analytic. Every manual mesh slice runs
+                # trunk out of the count. Every manual mesh slice runs
                 # the body once, so global FLOPs = body x manual-shard
                 # count. (This counts the pipeline's fill/drain garbage
                 # ticks too: they execute on the MXU, so they belong in
@@ -327,26 +320,12 @@ def jaxpr_flops(closed_jaxpr) -> Dict[str, Any]:
     }
 
 
-def check_flops(closed_jaxpr, costmodel_flops: Optional[float],
-                rtol: float = 0.25, partitions: int = 1,
-                ) -> Tuple[List[dict], Dict[str, Any]]:
-    """The pass: analytic count + cross-check against the XLA cost model
-    when capture succeeded. A finding means the two FLOPs sources disagree
-    past `rtol` — one of them is lying, and MFU headlines built on either
-    are not trustworthy until resolved. No cost model = no finding (the
-    analytic number simply becomes the only source, `mfu_source:
-    analytic`).
-
-    `partitions`: device count the compiled program was partitioned over.
-    The analytic count is GLOBAL (the whole jaxpr, counted once), while
-    `cost_analysis()` reports the per-partition program — the cross-check
-    compares global/partitions against it. Approximate by construction:
-    replicated work (the optimizer update) runs whole on every partition
-    but is spread by the division; `rtol` absorbs it."""
+def check_flops(closed_jaxpr) -> Tuple[List[dict], Dict[str, Any]]:
+    """The pass: the analytic count, with a finding for every
+    `pallas_call` it cannot cost (no registered hook, or a hook that
+    failed) and for a count that is not finite."""
     analytic = jaxpr_flops(closed_jaxpr)
     summary = dict(analytic)
-    summary["costmodel_flops"] = costmodel_flops
-    summary["partitions"] = int(partitions)
     findings: List[dict] = []
     for kname in analytic["unregistered_pallas"]:
         findings.append({
@@ -354,8 +333,8 @@ def check_flops(closed_jaxpr, costmodel_flops: Optional[float],
             "site": f"pallas_call:{kname}",
             "message": (
                 f"pallas_call kernel {kname!r} has no registered FLOPs "
-                "hook: the analytic count books it as ZERO, silently "
-                "deflating mfu_analytic — register one via "
+                "hook: the analytic count books it as ZERO — register "
+                "one via "
                 "gc_flops.register_pallas_flops (docs/KERNELS.md § "
                 "adding a kernel)"),
             "details": {"kernel": kname},
@@ -372,30 +351,9 @@ def check_flops(closed_jaxpr, costmodel_flops: Optional[float],
                 "kernel); its FLOPs are booked as zero until fixed"),
             "details": {"error": err},
         })
-    if costmodel_flops and analytic["flops_total"] > 0:
-        per_part = analytic["flops_total"] / max(int(partitions), 1)
-        rel = abs(per_part - costmodel_flops) / max(costmodel_flops, 1.0)
-        summary["costmodel_rel_err"] = round(rel, 4)
-        if rel > rtol:
-            findings.append({
-                "pass": "flops",
-                "site": "whole-program",
-                "message": (
-                    f"analytic FLOPs {per_part:.3e} (global "
-                    f"{analytic['flops_total']:.3e} / {partitions} "
-                    f"partition(s)) vs XLA cost model "
-                    f"{costmodel_flops:.3e} disagree by "
-                    f"{rel:.1%} (> {rtol:.0%}): one of the two MFU "
-                    "numerators is wrong"),
-                "details": {"analytic": analytic["flops_total"],
-                            "costmodel": costmodel_flops,
-                            "partitions": int(partitions),
-                            "rel_err": rel},
-            })
     if summary["caveats"]:
         summary["lower_bound"] = True
-    # guard against NaN/inf arithmetic surprises: the denominator of a
-    # headline metric must be a finite positive number or absent
+    # guard against NaN/inf arithmetic surprises
     if not math.isfinite(summary["flops_total"]):
         findings.append({
             "pass": "flops", "site": "whole-program",

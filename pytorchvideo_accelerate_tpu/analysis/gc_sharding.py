@@ -33,32 +33,22 @@ batch idiom), not a regather.
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
+
+from pytorchvideo_accelerate_tpu.analysis.gc_dtype import (
+    _frames,
+    _site as _site_of,
+)
 
 DimMap = Dict[int, Tuple[str, ...]]  # dim index -> mesh axis names
 
 _ELEMENTWISE_SAFE = True  # same-shape eqns merge operand maps
 
 
-def _frames(eqn) -> List[Tuple[str, str]]:
-    try:
-        from jax._src import source_info_util
-
-        return [(f.function_name, os.path.basename(f.file_name))
-                for f in source_info_util.user_frames(eqn.source_info)]
-    except Exception:
-        return []
-
-
 def _site(eqn) -> str:
-    fr = _frames(eqn)
-    if not fr:
-        return "<unknown>"
-    func, base = fr[0]
-    return f"{base}:{func}"
+    return _site_of(_frames(eqn))
 
 
 def spec_to_dim_map(spec, ndim: int) -> DimMap:

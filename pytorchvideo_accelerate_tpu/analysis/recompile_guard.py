@@ -12,8 +12,8 @@ after the first real step (the legitimate compile); `sample()` reports
 growth since then and mirrors it into the `pva_train_recompiles` gauge
 of the obs metric registry. Trainer.fit() arms after step one, samples
 at every `log_every` drain and epoch end, and surfaces the total in its
-perf dict as `train_recompiles` — which bench.py carries on the
-headline line and asserts == 0 in `--smoke`.
+perf dict as `train_recompiles` — which the benchmark reads as
+`recompiles` and requires to be 0 (`benchmarks/jobs/train_fit.py`).
 
 `_cache_size` is a private-but-stable jax API (present in 0.9.0); if a
 future jax drops it the guard degrades to inert (reports None) rather than lying

@@ -21,7 +21,7 @@ Static detection is heuristic by construction: it tracks names bound to
 `jax.jit(...)` / `pjit(...)` results inside one module (`f = jax.jit(g)`
 and `self.f = jax.jit(g)`) and inspects calls through those names. The
 runtime counterpart (`analysis/recompile_guard.py` -> the
-`pva_train_recompiles` gauge, asserted zero in `bench.py --smoke`) gives
+`pva_train_recompiles` gauge; the benchmark's `recompiles` must read 0) gives
 the rule teeth beyond what syntax can prove.
 """
 

@@ -16,8 +16,9 @@ PR lands, and gates it forever:
 - **Dynamic counterpart** (`parallel/schedule_recorder.py`): the
   installed recorder logs every `collective_section` entry per host;
   `diff_schedules` reports the first cross-host divergence with both
-  hosts' trailing windows. The MULTICHIP bench lane records + diffs
-  emulated hosts every run and headlines `spmd_schedule_divergence`.
+  hosts' trailing windows (tests/test_zspmd.py records + diffs
+  emulated hosts: `test_recorder_clean_run_non_vacuous`,
+  `test_seeded_divergence_detected_with_evidence`).
 
 CLI: `pva-tpu-spmdcheck [paths...]` — exit 0 clean, 1 findings, 2
 usage/crash. `--selftest` seeds one violation per static kind, one
@@ -25,8 +26,8 @@ covered/uncovered primitive pair, and one injected schedule divergence
 through the REAL armed `collective_section`; every seed MUST be
 detected and every clean twin MUST stay clean.
 
-Gates: `spmdcheck_findings == 0` in `bench.py --smoke` and
-`scripts/analyze.sh`; `pva_spmd_findings` /
+Gates: `spmdcheck_findings == 0` in `scripts/analyze.sh` and
+tests/test_zspmd.py::test_full_tree_clean; `pva_spmd_findings` /
 `pva_spmd_schedule_divergence` gauges + flight-ring events;
 `pva-tpu-doctor diagnose()` carries `spmd_snapshot()`. See
 docs/STATIC_ANALYSIS.md § spmdcheck.

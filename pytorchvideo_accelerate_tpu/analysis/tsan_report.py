@@ -10,8 +10,9 @@ Three jobs:
   membership flap racing the health poller), TrackerHub fan-out with a
   raising tracker (the disable-on-failure path), flight-recorder
   record/dump re-entrancy, and a forced watchdog stall — and report what
-  the run proved. Zero findings on this scenario is a CI gate (`bench.py --smoke`,
-  `scripts/analyze.sh`), same contract as `pva-tpu-lint`.
+  the run proved. Zero findings on this scenario is a CI gate
+  (`scripts/analyze.sh`, tests/test_ztsan.py), same contract as
+  `pva-tpu-lint`.
 - **Report plumbing** (`publish`/`tsan_snapshot`): findings land in the
   obs registry (`pva_tsan_races`, `pva_tsan_lock_cycles` gauges), the
   flight-recorder ring, and `pva-tpu-doctor diagnose()`.

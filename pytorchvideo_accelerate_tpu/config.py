@@ -97,8 +97,8 @@ class DataConfig:
     frames_per_second: int = 30  # pva: disable=knob-read -- read via the clip_duration property below (the one derived config value)
     batch_size: int = 8  # per data-parallel shard, matching per-rank semantics
     # auto | thread | process (native shm decode workers). auto = threads:
-    # cv2/numpy release the GIL and threads won every measurement made
-    # (bench transport_crossover). process is an explicit opt-in for
+    # cv2/numpy release the GIL and threads won every measurement made.
+    # process is an explicit opt-in for
     # GIL-holding pure-Python transform stacks.
     transport: str = "auto"
     num_workers: int = 8
@@ -174,15 +174,14 @@ class ModelConfig:
     # "conv" = XLA grouped convolution; "shift" = tap decomposition into
     # fused VPU multiply-adds; "pallas" = hand-tiled halo kernel (one
     # HBM->VMEM DMA per output tile; stride-1 blocks only, strided entries
-    # fall back to conv). Same param tree in all cases; A/B on device with
-    # scripts/perf_sweep.py
+    # fall back to conv). Same param tree in all cases.
     depthwise_impl: str = "conv"
     # fused conv->norm->activation lowering for the slowfast/x3d/slow
     # residual-block hot paths (ops/pallas_fused.py; docs/KERNELS.md):
     # "off" = today's unfused graph, byte-for-byte; "auto" = hand-tiled
     # Pallas kernels on TPU and the scale-folded XLA formulation
     # elsewhere; "pallas"/"xla" force one lowering (parity tests,
-    # graphcheck, pva-tpu-kbench A/Bs). Same param tree in every mode —
+    # graphcheck). Same param tree in every mode —
     # checkpoints and converted weights are interchangeable across the
     # knob. Strided sites and non-BN convs keep the unfused path.
     fused_kernels: str = "off"
@@ -359,7 +358,7 @@ class ServeConfig:
     # advance computes only the new tubelets' queries. Changes the math:
     # serve a backbone FINETUNED with the matching model.attn_mask (the
     # quality gate + recipe in docs/SERVING.md), or eat the top-1 delta
-    # the bench STREAM lane reports. VideoMAE classifiers only —
+    # docs/SERVING.md § trunk-reuse describes. VideoMAE classifiers only —
     # MViT/conv/dual-rate families refuse loudly.
     stream_trunk: str = "full"  # full | causal | windowed
 
@@ -370,7 +369,7 @@ class FleetConfig:
     load harness defaults (docs/SERVING.md § fleet). `serve.*` configures
     ONE replica; `fleet.*` configures the tier around N of them."""
 
-    # replicas the bench fleet lane / CI harnesses stand up (production
+    # replicas the CI harnesses stand up (production
     # fleets register real processes with the pool instead)
     replicas: int = 2
     # health-poll cadence for pool membership; route-around on an observed
@@ -382,7 +381,7 @@ class FleetConfig:
     # open-loop load-harness defaults (fleet/loadgen.py, pva-tpu-loadgen)
     loadgen_rps: float = 50.0
     loadgen_duration_s: float = 5.0
-    # the SLO the SERVE_FLEET bench lane asserts (p99 over completions)
+    # the SLO pva-tpu-loadgen's verdict holds (p99 over completions)
     slo_p99_ms: float = 1500.0
 
 

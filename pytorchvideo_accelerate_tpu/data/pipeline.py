@@ -561,7 +561,7 @@ class ClipLoader:
         # and numpy transforms release the GIL, threads beat the forked
         # shm-ring transport 7x on the production decode path and broke
         # even (0.996x) even on a deliberately GIL-bound pure-Python
-        # augment stack (bench.py transport_crossover). An earlier >=16-core
+        # augment stack. An earlier >=16-core
         # heuristic here was extrapolation from a 1-core host — a guess,
         # not a measurement — so it is gone: the process transport is an
         # EXPLICIT opt-in for workloads whose transforms hold the GIL

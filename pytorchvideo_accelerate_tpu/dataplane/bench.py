@@ -1,4 +1,4 @@
-"""DATA_PLANE bench lane: local loader vs N remote decode workers.
+"""Data-plane comparison: local loader vs N remote decode workers.
 
 Same source, same seed, same epoch geometry — the ONLY variable is where
 decode happens. The consumer simulates a trainer (a fixed busy-step per
@@ -14,8 +14,8 @@ batch) and the lane measures what an operator needs to compare:
 
 The local loader runs ONE decode worker thread and the remote path runs N
 worker PROCESSES, so the comparison shows the actual lever: horizontal
-decode scale-out on a fixed trainer host. Host-CPU-real numbers in the
-bench_data tradition — trustworthy on any box, never device claims.
+decode scale-out on a fixed trainer host. Host-CPU numbers —
+trustworthy on any box, never device claims.
 
 Also the analyze.sh gate step (``python -m
 pytorchvideo_accelerate_tpu.dataplane.bench --smoke``): exit 1 on a parity
@@ -193,9 +193,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     def log(msg):
         print(msg, file=sys.stderr, flush=True)
 
-    # the CLI is the analyze.sh/CI gate: always the smoke shapes (the
-    # full-size lane lives in bench.py); --smoke only toggles nothing yet
-    # and is kept for flag symmetry with the other gate tools
+    # the CLI is the analyze.sh/CI gate: always the smoke shapes;
+    # --smoke toggles nothing and is kept for flag symmetry with the
+    # other gate tools
     out = run_dataplane_bench(smoke=True, workers=args.workers, log=log)
     print(json.dumps({k: v for k, v in out.items() if k != "stats"}))
     if not out["parity"]:

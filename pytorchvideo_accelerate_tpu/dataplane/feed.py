@@ -92,8 +92,8 @@ class _Worker:
 
 
 # every spawned worker process, for emergency reaping: a harness that
-# abandons a wedged feed (bench lane timeout) must be able to kill the
-# orphans rather than let them burn CPU under later measured lanes
+# abandons a wedged feed (a timeout) must be able to kill the
+# orphans rather than let them burn CPU under whatever runs next
 _SPAWNED: List[subprocess.Popen] = []
 _SPAWNED_LOCK = make_lock("dataplane.feed._SPAWNED_LOCK")
 

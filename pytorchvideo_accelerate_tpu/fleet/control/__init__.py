@@ -18,7 +18,7 @@ intelligence):
   the over-budget family sheds, the pool never degrades (budgets consume
   MEASURED MemoryLedger bytes on device, declared footprints elsewhere);
 - `canary.CanaryController` — fractional blue/green rollout with
-  direction-aware comparison (perfdiff vocabulary) evaluated PER model
+  direction-aware comparison (`analysis/perfdiff.diff_rounds`) evaluated PER model
   family on multi-model pools (a regression in one family strikes that
   family instead of diluting into a pool average), exemplar-linked
   evidence, and escalation-ladder auto-rollback.

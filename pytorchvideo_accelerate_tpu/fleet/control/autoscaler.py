@@ -33,8 +33,8 @@ never drained, no matter what the signals say: a fleet that scales to
 zero under a monitoring blip has no path back.
 
 Every decision lands in `history` (monotonic timestamp, action, the
-signal values that justified it) — the convergence evidence the
-FLEET_AUTO bench lane asserts on — and in the obs flight recorder.
+signal values that justified it) — the convergence evidence
+tests/test_zcontrol.py asserts on — and in the obs flight recorder.
 """
 
 from __future__ import annotations

@@ -17,9 +17,8 @@ way this repo knows:
   the canary);
 - **direction-aware deltas**: the comparison reuses
   `analysis/perfdiff.diff_rounds` — p99 up is bad, throughput down is
-  bad, same thresholds and vocabulary as the cross-round perf gate — so
-  a canary verdict and a bench perfdiff argue from one definition of
-  "regressed";
+  bad — so a canary verdict and any other comparison of two readings
+  argue from one definition of "regressed";
 - **exemplar-linked traces**: the verdict carries the canary side's
   `slowest_traces`, so a rollback isn't an anonymous number — it names
   the trace ids of the requests that condemned the artifact.
@@ -219,8 +218,8 @@ class CanaryController:
                          and getattr(r, "stats", None) is not None]
         canary = self._side_stats(self._canaries)
         baseline = self._side_stats(baseline_side)
-        # the cross-round perf gate's own direction-aware comparison:
-        # baseline plays the "old" round, the canary the "new" one
+        # the direction-aware comparison: baseline plays the "old"
+        # side, the canary the "new" one
         diff = diff_rounds(baseline, canary, threshold=self.threshold)
         families = self._families(baseline_side)
         per_family: Dict[str, dict] = {}

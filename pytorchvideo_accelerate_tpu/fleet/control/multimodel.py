@@ -1,7 +1,7 @@
 """Multi-model serving on one pool under a shared device budget.
 
-One fleet, several model families (`x3d_s` + `videomae_t` in the bench
-lane): each replica declares the family it serves (`replica.model`), the
+One fleet, several model families (`x3d_s` + `videomae_t`, say): each
+replica declares the family it serves (`replica.model`), the
 router narrows candidates per request (`submit(..., model=)`) and labels
 traffic per family (`pva_fleet_model_*{pool=,model=}`), and THIS module
 adds the two things routing alone cannot give:

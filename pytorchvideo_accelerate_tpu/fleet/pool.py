@@ -294,8 +294,8 @@ class ReplicaPool:
         self._down: frozenset = frozenset()
         self._closed = False
         reg = registry if registry is not None else obs.get_registry()
-        # labeled per pool: two pools on one registry (a bench lane plus
-        # an app fleet) must not fight over one callback slot. close()
+        # labeled per pool: two pools on one registry (a test harness
+        # plus an app fleet) must not fight over one callback slot. close()
         # deregisters THIS pool's label — otherwise the registry closure
         # would pin a closed pool alive and scrape stale membership forever
         self._g_healthy = reg.gauge(
@@ -409,7 +409,7 @@ def read_line_with_deadline(proc, timeout_s: float, *,
     `readline()` blocks forever, so a child that wedges BEFORE printing
     its bind/URL line would otherwise hang the caller past any timeout.
     One implementation for every spawn site (`spawn_serving_process`, the
-    chaos replica_kill leg, the bench fleet lane's traced replica) so the
+    chaos replica_kill leg) so the
     wedge-safe protocol cannot drift between them. Returns `(line, eof)`:
     line None on deadline or EOF, eof True when the child's stdout closed
     without the wanted line (a died-or-redirected child, NOT a timeout —

@@ -241,7 +241,7 @@ def _videomae_b_pretrain(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
 @register_model("videomae_t")
 def _videomae_t(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
     """Deliberately tiny VideoMAE classifier (the `tiny3d` of the
-    transformer family): CI smokes, the bench PIPELINE lane, and the
+    transformer family): CI smokes, tests/test_zpipeline.py, and the
     chaos pipeline-preemption leg compile it in seconds on a CPU host.
     Not a reference architecture."""
     return VideoMAEClassifier(

@@ -31,7 +31,7 @@ Dtype = Any
 # the fused-kernel lowering knob threaded from ModelConfig.fused_kernels
 # (docs/KERNELS.md): "off" = today's unfused graph byte-for-byte; "auto" =
 # Pallas kernels on TPU / folded-XLA elsewhere; "pallas"/"xla" force one
-# lowering (parity tests, graphcheck, kbench A/Bs)
+# lowering (parity tests, graphcheck)
 FUSED_MODES = ("off", "auto", "pallas", "xla")
 
 

@@ -207,7 +207,7 @@ class MemoryLedger:
 
     def source(self) -> str:
         """"measured" when the backend exposes memory_stats, else
-        "estimate" — the label every headline that carries ledger bytes
+        "estimate" — the label every report that carries ledger bytes
         must carry too (never fake device bytes on a CPU host)."""
         return "measured" if self.device_stats() is not None else "estimate"
 

@@ -20,7 +20,7 @@ Design constraints, in order (the `utils/sync.py` mold):
   generation ever happens while disarmed.
 - **Head-based sampling.** The sampling decision is made ONCE, where the
   trace starts (`Tracer.start`), from a seeded RNG — deterministic under a
-  seed, so chaos/bench runs replay identically. Everything downstream
+  seed, so chaos runs replay identically. Everything downstream
   (spans, queue hops, HTTP propagation) only asks "is there an active
   context?"; a continued trace (incoming `traceparent` with the sampled
   flag) is always recorded regardless of the local rate, because the head
@@ -38,9 +38,9 @@ Design constraints, in order (the `utils/sync.py` mold):
   per-thread CPU clocks tick at jiffy granularity on this image's kernel,
   so a live audit is either the overhead or quantization noise. The
   calibrated figure excludes lock contention (bounded separately by the
-  tsan gate) but counts the real work. The bench fleet lane divides it by
-  run wall time and asserts `trace_overhead_frac < 0.02` — the tracing
-  layer must never become the latency it exists to explain.
+  tsan gate) but counts the real work: over a run's wall time it is the
+  share the tracing layer costs, which must never become the latency it
+  exists to explain.
 
 Stdlib-only on purpose: worker threads, the serving process, and the merge
 CLI import this without jax. See docs/OBSERVABILITY.md § distributed
@@ -555,8 +555,8 @@ def configure_tracing(sample_rate: float, seed: int = 0,
                       capacity: int = TRACE_RING_DEFAULT,
                       output_dir: str = "") -> Optional[Tracer]:
     """Arm (sample_rate > 0) or disarm (0) process-wide tracing — called
-    from TrainConfig.obs wiring (`obs.trace_sample_rate`) and the bench
-    harness, never per-request."""
+    from TrainConfig.obs wiring (`obs.trace_sample_rate`) and test
+    harnesses, never per-request."""
     global _tracer
     if sample_rate <= 0.0:
         _tracer = None

@@ -1,7 +1,7 @@
 """`pva-tpu-trace`: merge trace rings + flight records into one timeline.
 
-Each process of a run (the trainer, N serving replicas, the bench fleet
-child) keeps its own bounded trace ring (obs/trace.py, dumped as
+Each process of a run (the trainer, N serving replicas, a load
+generator) keeps its own bounded trace ring (obs/trace.py, dumped as
 `trace_ring.json`) and its own flight-recorder ring (`flight_record.json`).
 Diagnosing a cross-process request — a p99 sample that crossed the router,
 an HTTP hop, and a replica's scheduler — needs all of them on ONE
@@ -129,8 +129,9 @@ def summarize(merged: dict, slowest: int = 5) -> dict:
 def linked_traces(merged: dict, require_names: Sequence[str] = (),
                   min_pids: int = 1) -> List[str]:
     """Trace ids whose events span >= `min_pids` processes AND include
-    every name in `require_names` — how the bench asserts "≥1 sampled
-    request spanning router→replica→engine"."""
+    every name in `require_names` — how a test asserts "≥1 sampled
+    request spanning router→replica→engine"
+    (tests/test_ztrace.py::test_multiprocess_merge_two_forced_children)."""
     by_trace: Dict[str, dict] = {}
     for e in merged.get("traceEvents", []):
         tid = e.get("args", {}).get("trace_id")

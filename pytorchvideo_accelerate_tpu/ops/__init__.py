@@ -1,7 +1,7 @@
 """Compute ops: attention backends (dense XLA, Pallas flash, ring/Ulysses
 context-parallel), depthwise-conv lowerings, and the fused conv/norm/act
 kernel tier for the slowfast/x3d hot paths (docs/KERNELS.md;
-`pva-tpu-kbench` microbenches each kernel against its XLA reference).
+tests/test_zkernels.py holds each kernel to its XLA reference).
 
 The fused kernels are NOT re-exported here on purpose: every in-tree
 pallas import is lazy (function-local, the attention/depthwise

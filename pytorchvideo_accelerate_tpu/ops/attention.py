@@ -144,23 +144,6 @@ def temporal_band_mask(t: int, hw: int, window: int):
     return jnp.repeat(jnp.repeat(band, hw, axis=0), hw, axis=1)
 
 
-def incremental_band_attention(q, k, v, q_slot, k_slot, window: int, hw: int,
-                               impl: str = "fused"):
-    """Incremental banded attention: the s-new-slots' queries against a
-    cached-window + new K/V, masked by absolute temporal-slot index.
-
-    q (B, nq*hw, H, D) — queries of the nq NEW slots only;
-    k/v (B, nk*hw, H, D) — cached ring keys ++ new keys;
-    q_slot (B, nq) / k_slot (B, nk) — absolute slot indices (traced).
-    This is the exact attention op the streaming KV advance runs per
-    layer, exposed standalone so pva-tpu-kbench can time it against the
-    full-recompute attention at real model shapes."""
-    band = banded_time_mask(q_slot, k_slot, window)          # (B, nq, nk)
-    mask = jnp.repeat(jnp.repeat(band, hw, axis=1), hw, axis=2)
-    fn = dense_attention if impl == "dense" else fused_attention
-    return fn(q, k, v, mask=mask[:, None])                   # (B,1,Nq,Nk)
-
-
 def dot_product_attention(q, k, v, backend: str = "dense",
                           axis_name: Optional[str] = None, mesh=None,
                           mask=None):

@@ -20,8 +20,8 @@ explicit-bandwidth answer where the shift decomposition's fused reads
 may re-amplify. Stride-1 only (the non-entry blocks, which dominate);
 strided calls under `pallas` fall back to the XLA grouped path.
 
-Which implementation wins is an empirical, device-level question —
-`scripts/perf_sweep.py` A/Bs them on real hardware. All impls create the
+Which implementation wins is an empirical, device-level question (the
+benchmark's `depthwise_roofline` reads the one selected). All impls create the
 SAME parameter ("kernel", shape (kt, kh, kw, 1, C)) at the module's own
 scope — exactly the tree `nn.Conv(feature_group_count=C, name=<same>)`
 would create — so converted/pretrained checkpoints load identically and

@@ -1,16 +1,15 @@
-"""XLA reference chains for `pva-tpu-kbench` and the kernel parity tests.
+"""XLA reference chains for the kernel parity tests and chip_smoke.py.
 
 Each reference is the UNFUSED op sequence the model graph runs with
 `model.fused_kernels=off` (conv, then the resolved norm affine as its
 own pass, then the activation) — the baseline every fused kernel in
-ops/pallas_fused.py is timed and parity-checked against. They take the
+ops/pallas_fused.py is parity-checked against. They take the
 same resolved (scale, bias) affine as the fused dispatchers so the two
 sides compute the same function by construction, differing only in
 lowering.
 
-Kept out of kbench.py so tests import the references without pulling
-the benchmark harness, and out of pallas_fused.py so the reference can
-never accidentally share code with the thing it is checking.
+Kept out of pallas_fused.py so the reference can never accidentally
+share code with the thing it is checking.
 """
 
 from __future__ import annotations

@@ -32,8 +32,8 @@ favorite on paper, but the pad copy is why this is an A/B candidate and
 not a default. In-kernel clamped DMA windows would remove the copy at
 the cost of per-tile boundary masking; do that if the sweep shows this
 lowering winning but by less than the pad traffic. Whether any of it
-beats XLA's schedule is a device question — `scripts/perf_sweep.py`
-A/Bs all three lowerings.
+beats XLA's schedule is a device question: `depthwise_roofline` in
+`x3d_s.train` (BENCHMARK.json) reads the lowering the config selects.
 
 Scope: stride 1 (the 22/26 X3D and 29/33 ir-CSN blocks; strided stage
 entries fall back to the XLA grouped path in ops/depthwise.py). Training

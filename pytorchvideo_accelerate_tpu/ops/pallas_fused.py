@@ -38,8 +38,8 @@ GroupNorm/LayerNorm affines fold the same way.
 Backend dispatch (`mode`): "auto" lowers to the Pallas kernels on TPU
 and to `_xla_*` — the scale-folded conv + bias + act formulation XLA
 fuses well — everywhere else; interpret-mode Pallas is a PARITY tool,
-never a production CPU path. "pallas"/"xla" force a lowering (kbench
-A/Bs them; graphcheck traces the forced-pallas graph so the
+never a production CPU path. "pallas"/"xla" force a lowering (the
+parity tests; graphcheck traces the forced-pallas graph so the
 registered-FLOPs hooks in analysis/gc_flops.py are exercised off-TPU).
 
 Training: every Pallas path carries a `jax.custom_vjp` — dx reuses the
@@ -49,7 +49,7 @@ contractions XLA fuses, dbias a sum; act' is recomputed from the
 pre-activation (one extra kernel pass instead of a saved residual —
 the remat trade the rest of the stack already makes). The XLA mode is
 plain autodiff. Parity against `jax.grad` of the unfused reference is
-asserted in tests/test_zkernels.py and at kbench time.
+asserted in tests/test_zkernels.py.
 
 Precision: accumulation and the bias/act epilogue run in deliberate
 f32 islands (`precision.f32_island`; allowlisted by qualname in
@@ -83,7 +83,7 @@ FUSED_ACTS = ("identity", "relu", "silu")
 
 def apply_act(x, act: str):
     """Epilogue activation on the f32 accumulator (shared by the Pallas
-    kernels, the XLA lowering, and the kbench references)."""
+    kernels, the XLA lowering, and the ops/kbench_refs.py references)."""
     if act == "relu":
         return jnp.maximum(x, 0.0)
     if act == "silu":
@@ -402,7 +402,7 @@ def _xla_conv_bias_act(x, wf, bias32, act: str):
 def _xla_dw_bias_act(x, kf, bias32, act: str):
     """Depthwise fold: the tap-decomposition lowering (ops/depthwise.py)
     with the affine folded in — the formulation that beats XLA's grouped
-    conv by two orders of magnitude on CPU hosts (kbench measures it)."""
+    conv by two orders of magnitude on CPU hosts."""
     y = depthwise_conv3d_shift(x, kf)
     return end_island(apply_act(f32_island(y) + bias32, act), x.dtype)
 

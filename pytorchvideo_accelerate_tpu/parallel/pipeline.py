@@ -28,8 +28,8 @@ pjit/GSPMD mesh idiom of the TPUv4 pjit paper):
   backwards (no custom VJP anywhere);
 - the fill/drain ticks where a stage chews on garbage ARE the pipeline
   bubble: `analytic_bubble_frac(P, M) = (P-1)/(M+P-1)` per direction.
-  More microbatches amortize it; the bench PIPELINE lane measures the
-  realized fraction with a two-point (M, 2M) timing fit.
+  More microbatches amortize it; the realized fraction needs a
+  two-point (M, 2M) timing fit on the chip (not measured).
 
 The param-tree contract that makes checkpoints interchange: the stacking
 happens IN-GRAPH, per step, from the model's ordinary `block{i}` param

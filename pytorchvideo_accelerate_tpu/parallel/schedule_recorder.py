@@ -15,8 +15,8 @@ exact op one host issued that the other never did.
 Arm/disarm follows the watchdog discipline in `parallel/hangcheck.py`:
 disarmed (the default) costs ONE module-global read inside
 `collective_section` and records nothing; `install_schedule_recorder`
-routes every section entry here. Single-process lanes (the forced-host
-MULTICHIP bench, chaos legs, the spmdcheck selftest) record several
+routes every section entry here. Single-process runs (chaos legs, the
+spmdcheck selftest, tests/test_zspmd.py) record several
 EMULATED hosts by replaying the same deterministic segment under
 `recorder.as_host(...)` labels — run-to-run schedule determinism is the
 property a real pod needs from every host, so the emulation diffs the

@@ -3,7 +3,7 @@
 The proving harness for the resilience substrate (docs/RELIABILITY.md),
 same contract as `pva-tpu-lint`/`pva-tpu-tsan`: a seeded scenario runs
 every recovery path the way production fails, asserts recovery, and
-`chaos_findings == 0` gates `bench.py --smoke` and `scripts/analyze.sh`.
+`chaos_findings == 0` gates `scripts/analyze.sh`.
 
 Legs (all seeded via one `--seed`, CPU-only, replayable):
 
@@ -316,7 +316,7 @@ def leg_preempt(report: dict, tmpdir: str, seed: int, log: Log) -> None:
     This leg is also the live regression test for the resume
     re-materialization in `Checkpointer.restore`: resuming mid-epoch and
     TRAINING on the restored state with jax's persistent compilation
-    cache enabled (bench configures one) heap-corrupted the pinned
+    cache enabled (every entry point configures one) heap-corrupted the pinned
     jaxlib until restore started copying every leaf into an XLA-owned
     buffer."""
     from pytorchvideo_accelerate_tpu.config import (
@@ -1684,8 +1684,8 @@ def leg_dataplane_kill(report: dict, tmpdir: str, seed: int,
 
     loader = make_loader()
     try:
-        # batch_digest is THE byte-identity definition (shared with the
-        # DATA_PLANE bench lane — the two gates must agree on it)
+        # batch_digest is THE byte-identity definition (shared with
+        # dataplane/bench.py — the two gates must agree on it)
         local = [batch_digest(b) for b, _ in
                  loader.epoch_items(0, from_start=True) if b is not None]
     finally:

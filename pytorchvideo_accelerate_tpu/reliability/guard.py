@@ -463,8 +463,8 @@ class TrainGuard:
             pass
 
     def perf_keys(self) -> Dict[str, int]:
-        """fit()'s perf-dict contribution (bench headline: asserted 0 on a
-        clean smoke run)."""
+        """fit()'s perf-dict contribution (both 0 on a clean run:
+        tests/test_fit_result.py)."""
         return {"guard_rollbacks": int(self.rollbacks),
                 "quarantined_clips": (len(self.quarantine)
                                       if self.quarantine is not None else 0)}

@@ -142,7 +142,7 @@ def quantized_leaf_count(tree: Any) -> int:
 
 def quant_bytes(tree: Any) -> Dict[str, int]:
     """{quantized, fp} payload bytes — the serving-memory win, reported
-    by the engine log and the kbench record."""
+    by the engine log."""
     import jax
 
     q = fp = 0

@@ -72,15 +72,15 @@ Parity contract: the incremental logits match `InferenceEngine.predict`
 over the assembled host window (`full_recompute`) for the exact-window
 families (frames / tokens-full / dual), and match the masked replay
 over the whole stream history (`full_recompute_history`) for the
-KV-trunk and stem families — gated in the bench STREAM lane and
-tests/test_zstream.py + tests/test_zkvcache.py.
+KV-trunk and stem families — gated in tests/test_zstream.py +
+tests/test_zkvcache.py.
 
 Compile discipline: advance/establish functions are jitted per
 (kind, geometry, stride, bucket) and cached forever; session slots,
 write offsets and the KV position counter are TRACED arguments, so
 steady-state streaming touches zero new executables
-(`compiled_stream_cache_sizes` is the RecompileGuard-style probe the
-bench lane asserts flat).
+(`compiled_stream_cache_sizes` is the RecompileGuard-style probe
+tests/test_zstream.py and tests/test_zkvcache.py assert flat).
 """
 
 from __future__ import annotations

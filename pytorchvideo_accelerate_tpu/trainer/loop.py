@@ -303,11 +303,6 @@ class Trainer:
         # user-registered checkpoint participants (reference
         # `accelerator.register_for_checkpointing`, run.py:199)
         self._registered: dict = {}
-        self._flops_per_step: Optional[float] = None  # XLA cost model, lazy
-        # analytic per-primitive counter (analysis/gc_flops.py): the
-        # mfu_analytic numerator — non-null even where cost-model capture
-        # fails, cross-checked against it by pva-tpu-graphcheck where not
-        self._analytic_flops_per_step: Optional[float] = None
 
         self.trackers: Optional[TrackerHub] = None
         if cfg.tracking.with_tracking and is_main_process():
@@ -731,42 +726,6 @@ class Trainer:
                 device_normalize=self._device_normalize,
             )
 
-    def _capture_step_flops(self, global_batch, gstep: int) -> None:
-        """Per-step FLOPs, both sources, once (after the first step so the
-        executable cache is warm); feeds the epoch-end MFU line.
-
-        Cost model first (XLA's own count — exact for what actually
-        compiled, but capture availability varies by backend/version: the
-        reason `mfu` was null on every suspect round), then the analytic
-        per-primitive counter (analysis/gc_flops.py — shape arithmetic
-        over the jaxpr, available everywhere the step traces). Both are
-        stashed; the epoch-end block reports `mfu` from the cost model
-        and `mfu_analytic` from the counter with `mfu_source` saying
-        which one backs the headline."""
-        self._flops_per_step = 0.0
-        try:
-            compiled = self.train_step.lower(
-                self.state, global_batch, self.rng.step_key(gstep)
-            ).compile()
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            self._flops_per_step = float(ca.get("flops", 0.0))
-        except Exception:  # cost_analysis availability varies by backend
-            pass
-        self._analytic_flops_per_step = 0.0
-        try:
-            from pytorchvideo_accelerate_tpu.analysis.graphcheck import (
-                analytic_step_flops,
-            )
-
-            flops, _caveats = analytic_step_flops(
-                self.train_step,
-                (self.state, global_batch, self.rng.step_key(gstep)))
-            self._analytic_flops_per_step = float(flops)
-        except Exception:  # a probe must never kill the training job
-            pass
-
     def register_for_checkpointing(self, name: str, obj) -> None:
         """Add a custom object to every checkpoint (reference
         `accelerator.register_for_checkpointing(lr_scheduler)`, run.py:199).
@@ -1092,9 +1051,6 @@ class Trainer:
         last_val_acc, last_train_loss = 0.0, float("nan")
         last_val_acc5, last_val_loss = 0.0, float("nan")
         last_perf: Dict[str, float] = {}
-        # provenance labels are STRINGS: they ride fit()'s return dict
-        # only, never last_perf — the trackers coerce values to float
-        last_mfu_labels: Dict[str, str] = {}
         # train-section wall time per epoch (excludes eval/ckpt; epoch 0
         # includes compile) — lets benchmarks measure steady-state throughput
         epoch_train_times = []
@@ -1116,21 +1072,18 @@ class Trainer:
         # any jit-cache growth is a mid-training XLA compile stall. Sampled
         # at every log_every boundary + epoch end into the
         # `pva_train_recompiles` gauge; fit() reports the count as
-        # `train_recompiles` and bench.py --smoke asserts it stays 0 —
-        # the runtime teeth behind pva-tpu-lint's static `recompile` rule.
+        # `train_recompiles` (asserted 0 by the benchmark's `recompiles`
+        # and tests/test_zgraphcheck.py) — the runtime teeth behind
+        # pva-tpu-lint's static `recompile` rule.
         recompile_guard = RecompileGuard(self.train_step)
         # obs window accounting: the collector aggregates named spans; every
         # log_every boundary drains them into a per-window step-time
         # breakdown (obs/iter_s, obs/iter_self_s, obs/step_s,
-        # obs/input_wait_s, ...) logged through the trackers, and
-        # epoch_spans carries the epoch totals for the perf dict
-        # (obs_step_s / obs_input_wait_frac / obs_h2d_s — the numbers
-        # bench.py reports on its headline line)
+        # obs/input_wait_s, ...) logged through the trackers
         collector = obs.get_collector() if self.obs_on else None
         loader_rows = LoaderRowCounts()
         # a trace-time fact of the step, logged once, with the first window
         fold_sites_gauge = ["pva_conv_lane_fold_sites"]
-        epoch_spans: Dict[str, float] = {}
         loop_thread = threading.get_ident()
         tokens_per_step = (
             self.train_loader.global_batch_size * self.train_loader.accum_steps
@@ -1140,8 +1093,6 @@ class Trainer:
             if collector is None:
                 return
             window, self_by_thread = collector.drain()
-            for name, (total, _count, _self) in window.items():
-                epoch_spans[name] = epoch_spans.get(name, 0.0) + total
             if log_step is None or not self.trackers or not window:
                 return
             vals = {f"obs/{n}_s": t for n, (t, _c, _s) in window.items()}
@@ -1233,7 +1184,6 @@ class Trainer:
                 # they precede this epoch's first window and would otherwise
                 # surface as a negative obs/unattributed_s in it
                 drain_spans()
-                epoch_spans.clear()
                 window_t0 = time.perf_counter()
 
                 # batches arrive pre-placed on the mesh: the device prefetch
@@ -1345,13 +1295,6 @@ class Trainer:
                                         self._guard_rollback(action)
                                         rolled_back = True
                                         break
-                                if self._flops_per_step is None:
-                                    # unconditional (not tracking-gated):
-                                    # fit()'s return dict and the bench
-                                    # harness both need FLOPs/step
-                                    with obs.span("compile_probe"):
-                                        self._capture_step_flops(
-                                            global_batch, gstep)
                                 if use_tqdm:
                                     progress.update(1)
                                 # device scalar; the host->device sync
@@ -1481,7 +1424,6 @@ class Trainer:
                     gstep = int(self.state.step)  # pva: disable=host-sync -- anomaly-recovery path, once per rollback
                     metrics = None
                     drain_spans()
-                    epoch_spans.clear()
                     window_t0 = time.perf_counter()
                     epoch = self.train_loader.state.epoch
                     continue
@@ -1519,10 +1461,8 @@ class Trainer:
                     f"train_loss={last_train_loss:.4f} "
                     f"({time.time() - t_epoch:.1f}s)"
                 )
-                # epoch throughput + (when XLA's cost model is available)
-                # achieved TFLOP/s and MFU against the chip's bf16 peak —
-                # computed unconditionally so fit()'s return dict carries
-                # them even without --with_tracking
+                # epoch throughput — computed unconditionally so fit()'s
+                # return dict carries it even without --with_tracking
                 steps_done = train_steps_this_epoch
                 t_train = epoch_train_times[-1]
                 if t_train > 0 and steps_done > 0:
@@ -1547,10 +1487,9 @@ class Trainer:
                     # instead of a missing-key failure, and never a lying 0
                     last_perf["train_recompiles"] = recompile_guard.sample()
                     if self.pipeline_plan is not None:
-                        # the analytic schedule numbers (the MEASURED
-                        # bubble comes from the bench lane's two-point
-                        # (M, 2M) timing fit — a single run can't separate
-                        # fill/drain idle from per-tick compute)
+                        # the analytic schedule numbers (a single run
+                        # can't separate fill/drain idle from per-tick
+                        # compute, so no measured bubble here)
                         plan = self.pipeline_plan
                         # host ints by construction (PipelinePlan fields)
                         last_perf["pipeline_stages"] = plan.stages
@@ -1568,58 +1507,9 @@ class Trainer:
                                 "analytic (P-1)/(M+P-1)",
                             ).set(last_perf["pipeline_bubble_frac_analytic"])
                     if tguard is not None:
-                        # guard verdicts ride the perf dict -> bench
-                        # headline; a clean run asserts both are 0
+                        # guard verdicts ride the perf dict; a clean run
+                        # reads 0 for both
                         last_perf.update(tguard.perf_keys())
-                    if self.obs_on:
-                        # the generalized, span-sourced successors of PR 1's
-                        # one-off input_wait plumbing — the keys bench.py
-                        # reports on its headline line
-                        last_perf["obs_step_s"] = (
-                            epoch_spans.get("step", 0.0) / steps_done)
-                        last_perf["obs_input_wait_frac"] = min(
-                            epoch_spans.get("input_wait", 0.0) / t_train, 1.0)
-                        last_perf["obs_h2d_s"] = (
-                            epoch_spans.get("h2d", 0.0) / steps_done)
-                    if (self._flops_per_step
-                            or self._analytic_flops_per_step):
-                        from pytorchvideo_accelerate_tpu.utils.hw import (
-                            resolve_peak,
-                        )
-
-                        # per-chip = whole-program FLOPs over the MESH's
-                        # device count: flops_per_step is the global cost
-                        # of one step, counted once — dividing by the mesh
-                        # size attributes it across data AND model shards
-                        # without double counting (a mesh smaller than
-                        # jax.devices() must not dilute the number either)
-                        n_dev = self.mesh.size
-                        peak, peak_source = resolve_peak(jax.devices()[0])
-                        if self._flops_per_step:
-                            tflops = (self._flops_per_step * sps / 1e12
-                                      / n_dev)
-                            last_perf["tflops_per_sec_per_chip"] = tflops
-                            if peak:
-                                last_perf["mfu"] = tflops / peak
-                        if self._analytic_flops_per_step and peak:
-                            # the analytic counter (analysis/gc_flops.py):
-                            # available everywhere the step traces, so the
-                            # bench can headline a non-null MFU even where
-                            # cost-model capture fails (the r03-r05 hole)
-                            last_perf["mfu_analytic"] = (
-                                self._analytic_flops_per_step * sps
-                                / 1e12 / n_dev / peak)
-                        if peak:
-                            # which FLOPs source backs the MFU story, and
-                            # which denominator: a "measured" peak is a
-                            # calibrated matmul-rate proxy (utils/hw.py),
-                            # never comparable to a datasheet fraction
-                            last_mfu_labels = {
-                                "mfu_source": (
-                                    "costmodel" if self._flops_per_step
-                                    else "analytic"),
-                                "mfu_peak_source": peak_source,
-                            }
                 if self.trackers:
                     epoch_metrics = {"train_loss_epoch": last_train_loss,
                                      "epoch": epoch}
@@ -1690,11 +1580,9 @@ class Trainer:
         self.val_loader.close()
         result = {"train_loss": last_train_loss, "steps": int(self.state.step),  # pva: disable=host-sync -- fit() exit: training is over, the sync is free
                   "epoch_train_times": epoch_train_times,
-                  "flops_per_step": self._flops_per_step,
-                  "analytic_flops_per_step": self._analytic_flops_per_step,
                   "preempted": preempted,
                   "step_records": list(self.step_records),
-                  **last_perf, **last_mfu_labels}
+                  **last_perf}
         if self.task == "reconstruct":
             result["val_recon_loss"] = last_val_loss
         else:

@@ -294,7 +294,8 @@ def _make_update_step(
     # (analysis/gc_donation.py) walks the compiled input_output_alias map
     # and proves every state leaf aliases — disarmed AND guard-armed (the
     # jnp.where skip branch above must not break aliasing) — with zero
-    # donatable leaves left undeclared; bench --smoke gates on it. An
+    # donatable leaves left undeclared
+    # (tests/test_zgraphcheck.py::test_donation_round_trip_on_tiny3d). An
     # aval drift here (a leaf that changes dtype/shape across the step)
     # would silently double-buffer that leaf — the pass reports the bytes.
     return jax.jit(step, donate_argnums=0)

@@ -6,7 +6,7 @@ tracking.py:1260-1290, main-process fan-out at accelerator.py:3356-3386).
 Same shape here: a small Tracker protocol, concrete writers, and "all"
 resolving to whatever is importable — wandb is absent in this image, so it
 gates cleanly; tensorboard writes via tf.summary; jsonl is always available
-and is what the bench/driver parse.
+and is what scripts/trace_gap_probe.py and the tests parse.
 """
 
 from __future__ import annotations

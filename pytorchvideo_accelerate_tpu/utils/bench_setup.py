@@ -1,21 +1,18 @@
-"""Shared train-step scaffolding for the measurement tools.
+"""Shared train-step scaffolding for the tools that build a step.
 
-bench.py (throughput), scripts/perf_sweep.py (variant A/B), and
-utils/memfit.py (compile-time batch fitting) all need the same setup:
+analysis/graphcheck.py (jaxpr/HLO passes), utils/memfit.py (compile-time
+batch fitting) and chip_smoke.py (bring-up) all need the same setup:
 model from the registry, synthetic host batch of the right family shape
 (slowfast dual-pathway vs single clip; label unless pretraining; optional
 micro-batch axis), init, optimizer state, compiled step. One builder keeps
-the three tools measuring the same thing — family/batch-layout changes
+the tools looking at the same thing — family/batch-layout changes
 land here once.
 """
 
 from __future__ import annotations
 
-import logging
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
-
-logger = logging.getLogger(__name__)
 
 
 def is_pretrain_model(model_name: str) -> bool:
@@ -56,10 +53,9 @@ def build_step_setup(
     input_u8: bool = False,  # raw-u8 batches + in-graph normalize (the
     #                          host_cast=u8 production path; supervised only)
     mesh_cfg=None,  # MeshConfig for a non-default layout (e.g. the 2-D
-    #                 (data, model) shapes the multichip bench sweeps)
-    mixed_precision: str = "bf16",  # "fp32" for numerics probes (the
-    #                 multichip parity lane: bf16 summation-order noise
-    #                 compounds across update steps)
+    #                 (data, model) shapes graphcheck's pipelined target uses)
+    mixed_precision: str = "bf16",  # "fp32" for numerics probes (bf16
+    #                 summation-order noise compounds across update steps)
     global_batch: Optional[int] = None,  # fixed TOTAL batch instead of
     #                 batch_per_chip * n_chips — the mesh-parity lane needs
     #                 the identical batch on every mesh shape
@@ -186,20 +182,6 @@ def build_step_setup(
                      n_chips=n_chips, global_batch=B, host_batch=host_batch,
                      device_batch=device_batch, pretrain=pretrain,
                      input_u8=input_u8, tx=tx)
-
-
-def xla_flops(compiled) -> Optional[float]:
-    """Per-step FLOPs from XLA's cost model; None when unavailable (varies
-    by backend — the reason is logged, not swallowed)."""
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        return float(ca.get("flops", 0.0)) or None
-    except Exception as e:
-        logger.warning("cost_analysis unavailable: %s: %s",
-                       type(e).__name__, e)
-        return None
 
 
 def fetch_loss(metrics) -> float:

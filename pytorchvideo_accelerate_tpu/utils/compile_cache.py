@@ -1,7 +1,7 @@
 """The one rule for JAX's persistent compilation cache.
 
 Every entry point that compiles a model (`run.main`, `serving.server.main`,
-`bench.py`, `scripts/perf_sweep.py`, `chip_smoke.py`) calls
+`benchmarks/run.py`, `chip_smoke.py`) calls
 `enable_compile_cache()` before its first compile:
 
 - `JAX_COMPILATION_CACHE_DIR` set: JAX has already read it into its own

@@ -5,18 +5,18 @@ One CPU host can impersonate an N-chip slice: XLA's
 fake CPU devices, which is how mesh semantics (sharded steps, collective
 layouts, mesh-reshape restores) are tested without a TPU — tier-1's
 conftest does it in-process, but the flag latches at backend init, so any
-ALREADY-INITIALIZED process (the bench parent, a chaos scenario, a user
+ALREADY-INITIALIZED process (a test, a chaos scenario, a user
 REPL) can only get a differently-sized device set by spawning a fresh
 interpreter. This module is that spawn, packaged:
 
 - `forced_host_env(n)` — the env block (JAX_PLATFORMS=cpu + XLA_FLAGS)
   for a subprocess that should see `n` CPU devices;
 - `run_forced_host(code, n)` — run a python snippet under that env and
-  parse its LAST stdout line as JSON (the bench child convention: logs to
+  parse its LAST stdout line as JSON (the child convention: logs to
   stderr, one machine-readable line to stdout).
 
-Used by tests/test_zmesh.py, the bench MULTICHIP lane, and the
-pva-tpu-chaos mesh-reshape preemption leg. Stdlib-only on purpose: the
+Used by tests/test_zmesh.py and the pva-tpu-chaos mesh-reshape
+preemption leg. Stdlib-only on purpose: the
 caller never needs jax imported (and must not let its own device count
 leak into the child).
 """
@@ -33,10 +33,9 @@ _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
 def last_json_line(stdout: str) -> Optional[dict]:
-    """The bench child-output protocol, in one place: logs go to stderr and
+    """The child-output protocol, in one place: logs go to stderr and
     exactly one machine-readable JSON object is the final stdout line —
-    scan lines in reverse, return the first that parses, None if none do.
-    Shared by bench.py `run_child` and `run_forced_host`."""
+    scan lines in reverse, return the first that parses, None if none do."""
     for line in reversed((stdout or "").strip().splitlines()):
         try:
             return json.loads(line)

@@ -4,15 +4,16 @@
 # stress pass (lockset races + lock-order cycles over the threaded
 # data/train/serve layers), the pva-tpu-graphcheck jaxpr/HLO passes over
 # the real train/eval/serve steps (donation aliasing, dtype policy,
-# sharding propagation, analytic FLOPs), the pva-tpu-spmdcheck
+# sharding propagation, analytic FLOP coverage), the pva-tpu-spmdcheck
 # collective-schedule divergence pass (multi-host readiness), then the
 # pva-tpu-chaos fault-injection
 # scenario (retry/preemption/shedding recovery asserted under seeded
 # faults — including the PR-9 self-healing legs: guard_nan NaN-rollback,
 # corrupt-clip quarantine, and the wedged-collective hang detector).
-# After the gates, a NON-fatal pva-tpu-perfdiff report compares the two
-# newest BENCH_r*.json rounds (perf trends inform here; the fatal perf
-# gates live in bench --smoke).
+# Speed is not judged here: the benchmark is `python3 benchmarks/run.py`
+# (BENCHMARK.json, PERF.md). The fused kernels' parity with their XLA
+# references and the fleet control loops' verdicts are tier-1 tests
+# (tests/test_zkernels.py, tests/test_zcontrol.py, tests/test_zhbmobs.py).
 # Exit codes: 0 clean, 1 findings, 2 usage — CI gates on nonzero.
 # Extra args pass through to the lint step only
 # (e.g. `scripts/analyze.sh --select host-sync`).
@@ -27,7 +28,7 @@ env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
 
 # compiled-graph gate (docs/STATIC_ANALYSIS.md § graphcheck): the four
 # jaxpr/HLO passes — donation aliasing, dtype policy, sharding
-# propagation, analytic-vs-costmodel FLOPs — over the real train/eval/
+# propagation, analytic FLOP coverage — over the real train/eval/
 # serve step functions; exit 1 on any finding
 env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
   JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
@@ -42,14 +43,6 @@ env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
   JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
   python -m pytorchvideo_accelerate_tpu.analysis.spmdcheck
 
-# fused-kernel parity gate (docs/KERNELS.md): pva-tpu-kbench --smoke
-# asserts every fused Pallas/folded kernel matches its XLA reference
-# (benched shape + interpret mode) before any speedup is believed;
-# exit 1 on a parity violation
-env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-  python -m pytorchvideo_accelerate_tpu.ops.kbench --smoke
-
 # disaggregated data-plane gate (docs/INPUT_PIPELINE.md § disaggregated
 # data plane): 2 remote decode-worker processes must produce a byte-
 # identical batch stream to the local loader on the same source/seed,
@@ -58,66 +51,9 @@ env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
   JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
   python -m pytorchvideo_accelerate_tpu.dataplane.bench --smoke
 
-# fleet-control gate (docs/SERVING.md § fleet intelligence): one
-# FLEET_AUTO lane pass in smoke shape; the control-loop VERDICTS are
-# fatal here — autoscaler converged, zero session failures across the
-# scale-down re-home, exactly one seeded-regression rollback with the
-# blues restored, the clean green promoted, both model families served
-# under the shared budget, zero burn-rate alert false positives and the
-# budget-lies admission flip held (pva-tpu-hbm). The lane's perf numbers
-# stay non-fatal (they inform via the perfdiff report below, like every
-# other lane's).
-env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-  python - "${ROOT}/bench.py" <<'PY'
-import json
-import subprocess
-import sys
-
-from pytorchvideo_accelerate_tpu.utils.forcehost import last_json_line
-
-proc = subprocess.run(
-    [sys.executable, sys.argv[1], "--child", "__fleet_auto__", "--smoke"],
-    capture_output=True, text=True, timeout=600)
-out = last_json_line(proc.stdout) or {}
-checks = {
-    "autoscale_converged": out.get("autoscale_converged") is True,
-    "fleet_session_failures": out.get("fleet_session_failures") == 0,
-    "canary_rollback": out.get("canary_rollback") == 1,
-    "canary_blue_restored": out.get("canary_blue_restored") is True,
-    "canary_promoted": out.get("canary_promoted") is True,
-    "budget_shed_ok": out.get("budget_shed_ok") is True,
-    "fleet_models_served": out.get("fleet_models_served", 0) >= 2,
-    # pva-tpu-hbm (docs/OBSERVABILITY.md): the seeded SLO breach fired
-    # its burn-rate rule exactly once and cleared -- zero fires outside
-    # the excursion -- and measured-byte admission refused the family
-    # the declared estimate would have admitted
-    "alert_false_positives": out.get("alert_false_positives") == 0,
-    "alert_fired_once": out.get("alert_fired_once") is True,
-    "alert_cleared": out.get("alert_cleared") is True,
-    "budget_lies_refused": out.get("budget_lies_refused") is True,
-}
-bad = sorted(k for k, ok in checks.items() if not ok)
-if proc.returncode or bad:
-    print(f"[fleet-auto] FAILED verdict(s): {bad or 'child crashed'} "
-          f"(rc {proc.returncode})", file=sys.stderr)
-    sys.stderr.write(proc.stdout[-800:] + proc.stderr[-800:])
-    sys.exit(1)
-print("[fleet-auto] control-loop verdicts clean: "
-      + json.dumps({k: out.get(k) for k in checks}))
-PY
-
 rc=0
 env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
   JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
   python -m pytorchvideo_accelerate_tpu.reliability.chaos --smoke || rc=$?
-
-# perf-diff report (non-fatal): pct deltas between the two newest bench
-# rounds (selection lives in the tool's no-path mode); suspect rounds
-# are refused per the standing no-CPU-numbers-as-device-numbers rule
-echo "[perfdiff] two newest rounds in ${ROOT}" >&2
-env PYTHONPATH="${ROOT}${PYTHONPATH:+:${PYTHONPATH}}" \
-  python -m pytorchvideo_accelerate_tpu.analysis.perfdiff \
-  --dir "${ROOT}" || true
 
 exit "$rc"

@@ -1,8 +1,8 @@
 #!/usr/bin/env bash
 # X3D-S on Kinetics (BASELINE config 2: single v5e chip, bf16).
 # Sampling per the X3D paper's S config: 13 frames, stride 6, 160^2 crops.
-# Depthwise-conv lowering is A/B-able on device (scripts/perf_sweep.py);
-# pass --model.depthwise_impl shift to use the tap-decomposition path.
+# Pass --model.depthwise_impl shift to use the tap-decomposition
+# depthwise-conv lowering.
 set -euo pipefail
 
 python -m pytorchvideo_accelerate_tpu.run \

@@ -1,20 +1,19 @@
 """No hidden CPU on the chip path (CPU-side checks; the chip run itself is
 `python chip_smoke.py` through the chip tool): the smoke script refuses a
 host without a TPU and starts no child, the compile cache goes where the
-environment or the checkout says, `utils/hw` never guesses a TPU's peak,
-and serving replicas inherit the environment as it is."""
+environment or the checkout says, and serving replicas inherit the
+environment as it is."""
 
 import ast
 import json
 import os
 import subprocess
 import sys
-from types import SimpleNamespace
 
 import jax
 import pytest
 
-from pytorchvideo_accelerate_tpu.utils import compile_cache, hw
+from pytorchvideo_accelerate_tpu.utils import compile_cache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(ROOT, "chip_smoke.py")
@@ -72,33 +71,6 @@ def test_cache_helper_defaults_to_the_checkout_from_any_cwd(
         dirs.append(compile_cache.enable_compile_cache())
     assert dirs == [os.path.join(ROOT, ".jax_cache")] * 2
     assert config_updates == dirs
-
-
-def _fake(platform, kind):
-    return SimpleNamespace(platform=platform, device_kind=kind)
-
-
-def test_hw_knows_the_v5e_by_its_exact_kind_and_raises_on_unknown_tpus():
-    assert hw.peak_tflops(_fake("tpu", "TPU v5 lite")) == 197.0
-    assert hw.resolve_peak(_fake("tpu", "TPU v5 lite")) == (197.0,
-                                                            "datasheet")
-    assert hw.peak_tflops(_fake("cpu", "cpu")) is None
-    for kind in ("TPU v5 lite pod", "tpu v5 lite", "TPU v9"):
-        with pytest.raises(ValueError, match="no bf16 peak on record"):
-            hw.peak_tflops(_fake("tpu", kind))
-        with pytest.raises(ValueError, match="no bf16 peak on record"):
-            hw.resolve_peak(_fake("tpu", kind))
-
-
-def test_hw_never_measures_a_peak_on_a_tpu(monkeypatch):
-    def boom(*a, **k):
-        raise AssertionError("measured path taken on a TPU")
-
-    monkeypatch.setattr(hw, "measured_peak_tflops", boom)
-    assert hw.resolve_peak(_fake("tpu", "TPU v4")) == (275.0, "datasheet")
-    monkeypatch.undo()
-    with pytest.raises(ValueError, match="never from a measured"):
-        hw.measured_peak_tflops(_fake("tpu", "TPU v5 lite"))
 
 
 def test_spawn_serving_process_passes_no_platform_of_its_own(monkeypatch):

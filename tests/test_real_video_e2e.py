@@ -93,10 +93,9 @@ def test_slowfast_overfits_real_videos_and_multiview_evaluates(
     assert result["val_accuracy"] == 1.0, result
     assert result["val_accuracy_top5"] == 1.0
     assert np.isfinite(result["train_loss"])
-    # throughput/MFU now ride the result dict unconditionally (VERDICT r3
-    # item 4 — no --with_tracking needed)
+    # throughput rides the result dict unconditionally (no
+    # --with_tracking needed)
     assert result["clips_per_sec"] > 0
-    assert "flops_per_step" in result
 
 
 def test_evaluate_scores_real_videos_multiview(video_tree, tmp_path):

@@ -86,8 +86,8 @@ def _dw_case(x_shape):
             lambda dt: [(x_shape, dt), ((3, 3, 3, 1, c), dt)])
 
 
-# name -> (function, dtype -> [(shape, dtype), ...]); shapes are those
-# ops/kbench.py calls the real model shapes, plus one stage of x3d_s
+# name -> (function, dtype -> [(shape, dtype), ...]); shapes are the
+# real model shapes, plus one stage of x3d_s
 # (13 frames; W 40/20/10/5 at inner C 54/108/216/432) and of each
 # slowfast_r50 pathway (32f/256^2: slow T=8, fast T=32) for the halo kernels
 CASES = {

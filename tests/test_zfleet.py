@@ -464,8 +464,7 @@ def test_loadgen_heavy_tail_mix_and_open_loop_honesty():
     assert rep["failed"] == 0
 
 
-# --- one real-engine end-to-end (tiny shapes; the bench SERVE_FLEET lane
-# runs the full artifact/hot-swap path) --------------------------------------
+# --- one real-engine end-to-end (tiny shapes) --------------------------------
 
 
 def test_fleet_end_to_end_real_engines(tmp_path):

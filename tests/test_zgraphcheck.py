@@ -1,9 +1,10 @@
 """pva-tpu-graphcheck (analysis/graphcheck + gc_* passes): one seeded
 violation + one clean fixture per pass, the donation round-trip on the
-real tiny3d train step (disarmed AND guard-armed), analytic-vs-costmodel
-FLOPs parity where capture works, the dtype-literal lint rule, the
-perfdiff null-vs-number "appeared" semantics, CLI exit codes, the doctor
-snapshot, and the full-tree clean gate.
+real tiny3d train step (disarmed AND guard-armed), the analytic FLOP
+count of the real step, the frames both passes name their sites by, the
+dtype-literal lint rule, the `diff_rounds` null-vs-number "appeared"
+semantics, CLI exit codes, the doctor snapshot, and the full-tree clean
+gate.
 
 Late-alphabet name on purpose: tier-1 is timeout-bound and kills
 mid-suite — the expensive step-building integration lives behind ONE
@@ -131,6 +132,34 @@ def test_dtype_fp32_policy_is_a_noop():
     assert findings == [] and summary["skipped"] is True
 
 
+# --- the frames both passes name their sites by -----------------------------
+
+def _named_for_frames(x, w):
+    return x.astype(jnp.float32) @ w
+
+
+@pytest.mark.parametrize("which", ["dtype", "sharding"])
+def test_frames_name_the_user_function(which):
+    """An equation traced from a named function yields that function's
+    name: with no frames every site reads `<unknown>` and no allowlist
+    entry of either pass can match."""
+    import os
+
+    from pytorchvideo_accelerate_tpu.analysis import gc_dtype, gc_sharding
+
+    cj = jax.make_jaxpr(_named_for_frames)(
+        jnp.ones((4, 16), jnp.bfloat16), jnp.ones((16, 8), jnp.float32))
+    here = os.path.basename(__file__)
+    for eqn in cj.jaxpr.eqns:
+        if which == "dtype":
+            frames = gc_dtype._frames(eqn)
+            site = gc_dtype._site(frames)
+        else:
+            frames, site = gc_sharding._frames(eqn), gc_sharding._site(eqn)
+        assert ("_named_for_frames", here) in frames, eqn
+        assert site == f"{here}:_named_for_frames"
+
+
 # --- sharding pass ----------------------------------------------------------
 
 def test_sharding_seeded_contract_mismatch_detected():
@@ -206,16 +235,6 @@ def test_flops_scan_multiplies_by_trip_count():
     assert five["by_class"]["dot"] == 5 * base["by_class"]["dot"]
 
 
-def test_flops_seeded_costmodel_disagreement_detected():
-    cj = jax.make_jaxpr(lambda a, b: a @ b)(jnp.ones((64, 32)),
-                                            jnp.ones((32, 16)))
-    true_flops = jaxpr_flops(cj)["flops_total"]
-    findings, summary = check_flops(cj, costmodel_flops=true_flops * 2.0)
-    assert len(findings) == 1
-    findings, summary = check_flops(cj, costmodel_flops=true_flops)
-    assert findings == [] and summary["costmodel_rel_err"] == 0.0
-
-
 def test_flops_conv_counts_only_valid_taps():
     from jax import lax
 
@@ -273,7 +292,7 @@ def test_fused_lowering_targets_stay_clean(report):
     the graph its verified properties: donation still fully aliases
     through the fused-"auto" train step, and the forced-pallas serve
     forward's pallas_call eqns are COSTED by the registered FLOPs hooks
-    (an opaque zero would silently deflate mfu_analytic)."""
+    (an opaque zero would silently deflate the count)."""
     s = report["targets"]["train_step_fused"]["passes"]["donation"][
         "summary"]
     assert s["declared"] > 0 and s["aliased"] == s["declared"], s
@@ -285,14 +304,11 @@ def test_fused_lowering_targets_stay_clean(report):
     assert f["unregistered_pallas"] == [], f
 
 
-def test_analytic_vs_costmodel_parity_where_capture_works(report):
+def test_analytic_count_of_the_real_step_is_positive_and_counts_convs(
+        report):
     s = report["targets"]["train_step"]["passes"]["flops"]["summary"]
     assert s["flops_total"] > 0
     assert s["by_class"]["conv"] > 0  # tiny3d is a conv net
-    if s.get("costmodel_flops"):
-        # dead-code elimination and fused simplifications keep the two
-        # sources apart by a bounded margin; 25% is the finding threshold
-        assert s["costmodel_rel_err"] <= 0.25, s
 
 
 def test_doctor_snapshot_after_run(report):
@@ -323,8 +339,7 @@ def test_registry_gauges_published(report):
 @pytest.mark.parametrize("guard_skip", [False, True])
 def test_donated_step_recompile_free(guard_skip):
     """train_recompiles == 0 must hold with donation landed, with and
-    without the guard's in-graph skip branch (the satellite contract the
-    bench --smoke gate asserts end-to-end)."""
+    without the guard's in-graph skip branch."""
     import optax
 
     from pytorchvideo_accelerate_tpu.analysis import RecompileGuard

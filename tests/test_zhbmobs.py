@@ -55,7 +55,7 @@ class _Recorder:
 @pytest.fixture(autouse=True)
 def _disarm_module_defaults():
     """Every test leaves the process-default singletons disarmed — the
-    arming discipline other suites (and the bench children) rely on."""
+    arming discipline other suites rely on."""
     yield
     obs_memory.configure(enabled=False)
     obs_history.configure(enabled=False)
@@ -156,7 +156,7 @@ def test_ledger_watermark_warns_edge_triggered():
 
 
 def test_model_budget_measured_bytes_flip_declared_admission():
-    """The budget-lies probe (the bench FLEET_AUTO smoke assert): a
+    """The budget-lies probe: a
     family that under-declares is admitted on declared figures, refused
     the moment the ledger can measure its real bytes."""
     obs_memory.configure(

@@ -3,9 +3,8 @@
 Named `test_zkernels` ON PURPOSE: the tier-1 suite is timeout-bound and
 runs alphabetically, so the kernel additions sort late — a slow run
 kills these, never the pre-existing suite (the test_zserving
-convention). Everything here is tiny-shape CPU work; the real-shape
-microbenches live in `pva-tpu-kbench` (scripts/analyze.sh runs its
---smoke parity gate out of band).
+convention). Everything here is tiny-shape CPU work; time at real
+shapes is the benchmark's (`conv_roofline`, `depthwise_roofline`).
 
 Contracts locked here:
 - every fused op matches its unfused XLA reference — both lowerings
@@ -14,8 +13,7 @@ Contracts locked here:
   eval/train parity (batch_stats updates included) on the same
   variables;
 - the fused train step holds `train_recompiles == 0` after warmup,
-  guard-disarmed AND guard-armed (the RecompileGuard contract bench
-  --smoke asserts);
+  guard-disarmed AND guard-armed (the RecompileGuard contract);
 - `pallas_call` eqns are costed by the registered-FLOPs hooks and an
   unregistered kernel is a graphcheck finding (gc_flops satellite).
 """
@@ -311,7 +309,7 @@ def test_pallas_flops_hooks_cost_fused_kernels():
     out_elems = 1 * 4 * 8 * 8 * 8
     assert res["by_class"]["pallas"] == 2.0 * out_elems * 27 + 2.0 * out_elems
     assert res["unregistered_pallas"] == []
-    findings, _ = check_flops(cj, costmodel_flops=None)
+    findings, _ = check_flops(cj)
     assert not findings
 
     # backward kernels are registered too — a grad graph stays clean
@@ -329,45 +327,10 @@ def test_pallas_flops_hooks_cost_fused_kernels():
         _zkernels_opaque,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=True)(x))(jnp.ones((8, 128)))
-    findings, summary = check_flops(oj, costmodel_flops=None)
+    findings, summary = check_flops(oj)
     assert summary["unregistered_pallas"] == ["_zkernels_opaque"]
     assert len(findings) == 1 and "registered FLOPs hook" in \
         findings[0]["message"]
-
-
-def test_kbench_cases_and_headline_keys():
-    """The microbench lane's case set and headline-key contract (bench.py
-    finalize() passes `kbench_*` through; names must stay stable for
-    pva-tpu-perfdiff attribution)."""
-    from pytorchvideo_accelerate_tpu.ops.kbench import (
-        build_cases,
-        headline_keys,
-    )
-
-    cases = build_cases(smoke=True)
-    names = [c.name for c in cases]
-    assert names == ["dw_x3d_res3", "pw_x3d_res3", "conv133_sf_res4",
-                     "conv311_sf_res4", "attn_causal_inc",
-                     "attn_windowed_inc"]
-    for c in cases:
-        assert c.attribution
-        # conv cases: (x, w, scale, bias); KV-trunk incremental
-        # attention: (q, k, v, q_slots, k_slots)
-        want = 5 if c.name.startswith("attn_") else 4
-        assert len(c.args) == want and len(c.small_args) == want
-    record = {
-        "platform": "cpu", "parity_ok": True,
-        "best_kernel": "dw_x3d_res3", "best_speedup": 23.0,
-        "kernels": {n: {"speedup": 2.0} for n in names},
-    }
-    keys = headline_keys(record)
-    assert keys["kbench_platform"] == "cpu"
-    assert keys["kbench_parity_ok"] is True
-    assert keys["kbench_best"] == "dw_x3d_res3:23.0x"
-    for n in names:
-        assert keys[f"kbench_{n}_speedup"] == 2.0
-    # the headline never carries raw millisecond timings (refusal rule)
-    assert not any("ms" in k for k in keys)
 
 
 def test_even_kernel_and_mode_validation():

@@ -264,6 +264,23 @@ def test_loss_parity_1_vs_8_devices(ref_point, mesh_point):
     np.testing.assert_allclose(got, ref, rtol=PARITY_RTOL)
 
 
+@pytest.mark.parametrize("point", ["ref_point", "mesh_point"])
+def test_step_compiles_once_over_its_steps(point, request):
+    """Zero steady-state recompiles under the (data, model) layout too,
+    not just on one device: after a first step, further steps on fresh
+    batches add no executable to the step's jit cache."""
+    from pytorchvideo_accelerate_tpu.analysis import RecompileGuard
+    from pytorchvideo_accelerate_tpu.obs.registry import Registry
+
+    setup = request.getfixturevalue(point)
+    _run(setup, k=1)
+    guard = RecompileGuard(setup.step, registry=Registry())
+    guard.arm()
+    assert guard.supported
+    _run(setup)
+    assert guard.sample() == 0
+
+
 def test_mesh_reshape_checkpoint_roundtrip(tmp_path, ref_point, mesh_point,
                                            devices8):
     """A checkpoint written under (2, 4) restores under (8, 1) AND under a

@@ -751,25 +751,29 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     """obs.enabled=true (the default): the per-window step-time breakdown
     is logged with the iteration as the parent span, the SELF times of the
     loop's spans sum to the measured window wall time (so what is left
-    unattributed is near zero), and fit() returns the span-sourced obs
-    keys and one record a step."""
+    unattributed is near zero), and fit() returns one record a step."""
     from pytorchvideo_accelerate_tpu.trainer.loop import Trainer
 
     cfg = _cfg(tmp_path)
     result = Trainer(cfg).fit()
-    for key in ("obs_step_s", "obs_input_wait_frac", "obs_h2d_s"):
-        assert key in result, sorted(result)
-    assert result["obs_step_s"] > 0.0
-    assert 0.0 <= result["obs_input_wait_frac"] <= 1.0
-    # span-sourced input wait tracks the prefetcher's own accounting
-    assert np.isclose(result["obs_input_wait_frac"],
-                      result["input_wait_frac"], atol=0.02)
 
     lines = _read_jsonl(cfg)
     windows = [ln for ln in lines
                if "obs/window_wall_s" in ln and "obs/step_s" in ln
                and "obs/eval_s" not in ln]
     assert windows, f"no train obs windows logged: {lines}"
+    # the per-window keys are the step-time breakdown (fit() returns no
+    # epoch-wide copy of them): dispatch, input wait and the prefetch
+    # worker's host-to-device copies
+    assert all(w["obs/step_s"] > 0.0 for w in windows)
+    assert all(0.0 <= w["obs/input_wait_s"] <= w["obs/window_wall_s"]
+               for w in windows)
+    assert sum(w.get("obs/h2d_s", 0.0) for w in windows) > 0.0
+    # span-sourced input wait tracks the prefetcher's own accounting
+    t_train = result["epoch_train_times"][-1]
+    assert np.isclose(
+        sum(ln.get("obs/input_wait_s", 0.0) for ln in lines) / t_train,
+        result["input_wait_frac"], atol=0.02)
     # self times sum to wall within 10%, asserted over the AGGREGATE of
     # the train windows: a single scheduler/GC pause can blow any one
     # sub-100ms window without any product bug (plus a small absolute
@@ -979,7 +983,7 @@ def test_zz_obs_disabled_restores_prior_logging_keys(tmp_path,
     cfg = _cfg(tmp_path, **{"obs.enabled": False,
                             "data.synthetic_num_videos": 8})
     result = Trainer(cfg).fit()
-    assert "obs_step_s" not in result
+    assert not [k for k in result if k.startswith("obs")], sorted(result)
     assert "input_wait_frac" in result  # PR 1's keys survive unchanged
     lines = _read_jsonl(cfg)
     obs_keys = {k for ln in lines for k in ln if str(k).startswith("obs")}

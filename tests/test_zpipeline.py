@@ -283,6 +283,16 @@ def test_microbatch_fold_matches_plain_accumulation():
                                    micro_dim=True), key)
     assert float(m1["loss"]) == float(m2["loss"])
     assert _leaves_max_diff(s1.params, s2.params) < 1e-6
+    # and the pipelined layout holds the zero-recompile contract: a second
+    # step, from the first one's state, adds no executable
+    from pytorchvideo_accelerate_tpu.analysis.recompile_guard import (
+        cache_size,
+    )
+
+    before = cache_size(step_pipe)
+    step_pipe(s2, shard_batch(mesh, {"video": x[::-1].copy(), "label": lab},
+                              micro_dim=True), jax.random.key(8))
+    assert before == cache_size(step_pipe) == 1
 
 
 def test_guard_skip_batch_under_pipelined_step():
@@ -417,7 +427,8 @@ def test_watchdog_attributes_pipelined_stage_stall():
 def test_graphcheck_builds_the_pipelined_target():
     """graphcheck's target list includes train_step_pipelined on a
     multi-device host (donation/dtype/flops coverage for the stage
-    region; the passes themselves run in the bench gate)."""
+    region; the passes themselves run in tests/test_zgraphcheck.py's
+    `report`)."""
     from pytorchvideo_accelerate_tpu.analysis.graphcheck import (
         build_targets,
     )

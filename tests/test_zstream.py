@@ -174,6 +174,41 @@ def test_incremental_parity_and_wraparound(fix, request):
         assert se.end_session(s)
 
 
+def test_advance_ships_stride_over_window_of_the_bytes(frames_stream):
+    """The per-advance host->device payload is exactly `stride / window`
+    of a full window's (a quarter at stride T/4)."""
+    geom = (T, CROP, CROP, 3, "float32")
+    assert frames_stream.full_h2d_bytes(geom) == T * CROP * CROP * 3 * 4
+    assert (frames_stream.advance_h2d_bytes(geom, S) * T
+            == frames_stream.full_h2d_bytes(geom) * S)
+    assert S * 4 <= T
+
+
+def test_ring_pool_and_weights_register_with_the_armed_ledger():
+    """With the memory ledger armed, an engine pins its weights and a
+    streaming engine's ring pool lands under `stream_rings:<name>` at the
+    pool's actual device bytes; on a host whose backend reports no memory
+    stats the label reads "estimate", never "measured"."""
+    from pytorchvideo_accelerate_tpu.obs import memory as obs_memory
+
+    led = obs_memory.configure(enabled=True, stats_fn=lambda: None)
+    try:
+        se = _build_stream("tiny3d")
+        rng = np.random.default_rng(5)
+        win = rng.standard_normal((T, CROP, CROP, 3)).astype(np.float32)
+        se.advance_batch([{"sid": "ledger-a", "window": win, "stride": S}])
+        snap = led.snapshot()
+        ring = snap["components"]["stream_rings:test-tiny3d"]
+        pool = next(iter(se._pools.values()))
+        assert ring == pool["measured_bytes"] > 0
+        assert any(c.startswith("model_weights:") and b > 0
+                   for c, b in snap["components"].items()), snap
+        assert snap["source"] == "estimate" and snap["peak_bytes"] >= ring
+        assert se.end_session("ledger-a")
+    finally:
+        obs_memory.configure(enabled=False)
+
+
 def test_eviction_under_budget_and_admission(frames_stream):
     """The HBM budget is enforced at establish: to exercise it cheaply,
     shrink the registered pool's free list instead of allocating a

@@ -493,9 +493,8 @@ def test_rule_scoped_to_traced_modules_and_suppressible():
 
 
 def test_rule_clean_on_the_real_tree():
-    """The shipped tree must be clean under the new rule (the same
-    clean-tree gate bench --smoke runs; scoped here to the traced modules
-    so the failure message names the culprit)."""
+    """The shipped tree must be clean under the new rule (scoped here to
+    the traced modules so the failure message names the culprit)."""
     from pytorchvideo_accelerate_tpu.analysis.core import lint_source
     from pytorchvideo_accelerate_tpu.analysis.rules_trace import (
         TRACE_HANDOFF_MODULES,
