@@ -117,7 +117,7 @@ def _rel_err(got, want) -> float:
 
 
 def kernels_phase(rehearse: bool) -> None:
-    """Each of the seven `pallas_call`s in ops/, compiled for the chip at a
+    """Each of the `pallas_call`s in ops/, compiled for the chip at a
     BASELINE-width shape in the models' compute dtype (bf16), against its
     float32 reference on the same values."""
     import jax
@@ -126,6 +126,7 @@ def kernels_phase(rehearse: bool) -> None:
 
     from pytorchvideo_accelerate_tpu.ops import kbench_refs as refs
     from pytorchvideo_accelerate_tpu.ops import pallas_fused as pf
+    from pytorchvideo_accelerate_tpu.ops import gated_delta
     from pytorchvideo_accelerate_tpu.ops.attention import dense_attention
     from pytorchvideo_accelerate_tpu.ops.pallas_attention import (
         flash_attention,
@@ -201,6 +202,37 @@ def kernels_phase(rehearse: bool) -> None:
     b, t, hw, c = (1, 2, 6, 8) if rehearse else (2, 8, 16, 256)
     fused("fused_conv133", pf.fused_conv3d_bn_act, refs.ref_conv_bn_act,
           "relu", arr((b, t, hw, hw, c)), arr((1, 3, 3, c, c), 0.05))
+    # qwen3_next_80b_a3b's DeltaNet heads (2 value heads a key head, 128
+    # wide) over 1000 tokens: the chunked rule as the backend and the shapes
+    # choose it (on the chip the kernel pair of ops/pallas_gated_delta.py,
+    # in the rehearsal the XLA form) against the per-token recurrence
+    b, t, hk, hv, d = (1, 40, 1, 2, 16) if rehearse else (2, 1000, 4, 8, 128)
+    q, k = (arr((b, t, hk, d)) for _ in range(2))
+    q, k = (x / jnp.linalg.norm(x.astype(jnp.float32), axis=-1,
+                                keepdims=True).astype(x.dtype) for x in (q, k))
+    v, g = arr((b, t, hv, d)), arr((b, t, hv, d))
+    decay = -jnp.asarray(rng.uniform(0.01, 0.5, (b, t, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 1.0, (b, t, hv)), jnp.float32)
+
+    def read_out(fn):
+        return lambda *xs: jnp.sum(
+            fn(*xs)[0].astype(jnp.float32) * g.astype(jnp.float32))
+
+    with gated_delta.count_sites() as sites:
+        got = jax.jit(lambda *xs: gated_delta.gated_delta_rule(*xs)[0])(
+            q, k, v, decay, beta)
+        grads = jax.jit(jax.grad(read_out(gated_delta.gated_delta_rule),
+                                 argnums=(0, 1, 2, 3, 4)))(q, k, v, decay, beta)
+    check(len(sites) == (0 if rehearse else 2),
+          f"gated_delta_rule took the kernel at {len(sites)} of 2 traces")
+    want = reference(lambda *xs: gated_delta.gated_delta_recurrence(*xs)[0],
+                     q, k, v, decay, beta)
+    want_grads = reference(jax.grad(
+        read_out(gated_delta.gated_delta_recurrence),
+        argnums=(0, 1, 2, 3, 4)), q, k, v, decay, beta)
+    report("gated_delta_fwd", v.shape, _rel_err(got, want))
+    report("gated_delta_bwd", v.shape, max(
+        _rel_err(a, w) for a, w in zip(grads, want_grads)))
 
 
 # --- train ------------------------------------------------------------------

@@ -127,6 +127,31 @@ def _attn_flops(matmuls: int) -> Callable[[Any], float]:
     return hook
 
 
+def _gdn_flops(backward: bool) -> Callable[[Any], float]:
+    # ops/pallas_gated_delta kernels: q (B, T, hk*dk), v (B, T, hv*dv), gamma
+    # (B, hv, chunks, C); a state operand or result (..., dk, dv) gives dk.
+    # The products a chunk: a key head's q k^T and k k^T, a value head's
+    # Neumann inverse (forward) and its products with the state and d
+    def hook(eqn) -> float:
+        q, _, v, gam = (x.aval for x in eqn.invars[:4])
+        state = (eqn.invars[5] if backward else eqn.outvars[1]).aval
+        b, hv, n, c = gam.shape
+        dk, dv = state.shape[-2:]
+        hk = q.shape[-1] // dk
+        if backward:
+            key_head = 6 * 2.0 * c * c * dk
+            value_head = 2.0 * c * dv * (7 * dk + 5 * c)
+        else:
+            levels = max(c.bit_length() - 2, 0)
+            key_head = 2 * 2.0 * c * c * dk
+            inverse = (2.0 * c ** 3 * (2 + 2 * (levels - 1)) if levels
+                       else 0.0)
+            value_head = inverse + 2.0 * c * dv * (3 * dk + 2 * c)
+        return b * n * (hk * key_head + hv * value_head)
+
+    return hook
+
+
 # in-tree kernels: the conv kernels by the stable `name=` their
 # pallas_call gives (also the kernel's name in a device trace), the
 # attention kernels by kernel function name
@@ -138,6 +163,9 @@ PALLAS_FLOPS_HOOKS.update({
     "_fwd_kernel": _attn_flops(2),
     "_bwd_dq_kernel": _attn_flops(3),
     "_bwd_dkv_kernel": _attn_flops(4),
+    "pva_gdn_fwd": _gdn_flops(backward=False),
+    "pva_gdn_fwd_saving": _gdn_flops(backward=False),
+    "pva_gdn_bwd": _gdn_flops(backward=True),
 })
 
 

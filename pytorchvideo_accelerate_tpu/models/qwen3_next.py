@@ -145,7 +145,7 @@ class GatedDeltaNet(nn.Module):
 
             q = end_island(l2norm(q) * dk ** -0.5, dt)
             k = end_island(l2norm(k), dt)
-            q, k = (jnp.repeat(y, hv // hk, axis=2) for y in (q, k))
+            # hk key heads: value heads h*hv/hk .. share key head h
             o, _ = gated_delta_rule(q, k, v, g, beta)
         with jax.named_scope("out"):
             scale = self.param("norm", nn.initializers.ones, (dv,))

@@ -7,12 +7,27 @@ Per head, with a state S (dk x dv) that starts at zero, for every token t
     S <- S + k_t d^T
     o_t = S^T q_t
 
-`gated_delta_rule` computes this in chunks of `chunk` tokens: inside a chunk
+`gated_delta_rule` computes this in chunks of tokens: inside a chunk
 everything is matrix products, and only the state crosses from one chunk to
-the next (a `lax.scan` over chunks whose body is rematerialised, so the
-backward pass keeps one state a chunk and nothing else of the chunk).
-`gated_delta_recurrence` is the per-token form, the definition the chunked
-form has to equal (tests/test_gated_delta.py).
+the next. `gated_delta_recurrence` is the per-token form, the definition the
+chunked form has to equal (tests/test_gated_delta.py). One algorithm, two
+lowerings, chosen by what the code can observe (`takes_kernel`: the backend;
+`kernel_shapes`: the heads' width) and by nothing a caller sets:
+
+* on a TPU, for dk and dv multiples of 128, the Pallas kernel pair of
+  ops/pallas_gated_delta.py: a chunk's operands, its triangular inverse and
+  the state stay in VMEM; q, k, v are read and o is written once, where the
+  layer has them (a head is a 128-lane column block of (B, T, H*d)), g and
+  beta go in as rows a chunk. The forward a gradient is taken through also
+  writes each chunk's entering state (float32, (B, H, chunks, dk, dv): what
+  the checkpointed scan below keeps too, 268 MB a layer at the 8k cell's
+  shapes and the kernel's chunk of 128) and its (I + A)^-1 (compute dtype,
+  134 MB), alive inside the layer's remat unit only; the backward kernel
+  walks the chunks last to first and recomputes the rest;
+* everywhere else (the CPU, the toy model's 16-wide heads) the XLA form
+  below: a `lax.scan` over chunks of `chunk` tokens whose body is
+  rematerialised, so the backward pass keeps one state a chunk and nothing
+  else of the chunk; the tests' second witness beside the recurrence.
 
 Inside one chunk, with gamma_i = g_1 + ... + g_i (so gamma <= 0) and
 M_ij = exp(gamma_i - gamma_j) for j <= i:
@@ -33,21 +48,69 @@ for `Precision.HIGHEST`, so a float32 policy is float32 on the chip too.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+from typing import Iterator, List
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pytorchvideo_accelerate_tpu.ops import pallas_gated_delta as kernel
 from pytorchvideo_accelerate_tpu.precision import end_island, f32_island
 
 CHUNK = 64
 _MASKED = -1e30  # exponent of an entry above the diagonal: exp gives 0
 
+_sites: contextvars.ContextVar = contextvars.ContextVar(
+    "pva_gdn_scan_kernel_sites", default=None)
+
+
+@contextlib.contextmanager
+def count_sites() -> Iterator[List[tuple]]:
+    """Collects, while a model is traced inside the block, one entry (the
+    operands' shapes) for every `gated_delta_rule` call that took the
+    kernel: the lowering is static, so its engagement is a fact of the trace
+    (the `pva_gdn_scan_kernel_sites` gauge, trainer/steps.py)."""
+    sites: List[tuple] = []
+    token = _sites.set(sites)
+    try:
+        yield sites
+    finally:
+        _sites.reset(token)
+
+
+def takes_kernel() -> bool:
+    """The backend half of the rule (`lane_fold.takes_fold`'s): off the TPU
+    the kernel could only be interpreted."""
+    return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def kernel_shapes(dk: int, dv: int) -> bool:
+    """The shape half: the kernel reads a head as a 128-lane column block of
+    the layer's (B, T, H*d) arrays."""
+    return dk % kernel.LANES == 0 and dv % kernel.LANES == 0
+
+
+def _repeat_key_heads(q, k, hv):
+    """q, k with hk key heads -> hv value heads (hk divides hv)."""
+    rep = hv // q.shape[2]
+    if rep == 1:
+        return q, k
+    return tuple(jnp.repeat(x, rep, axis=2) for x in (q, k))
+
 
 def gated_delta_recurrence(q, k, v, g, beta):
-    """The definition, token by token. q, k: (B, T, H, dk); v: (B, T, H, dv);
-    g (log decay, <= 0), beta: (B, T, H). Returns o (B, T, H, dv) in float32
-    and the last state (B, H, dk, dv)."""
+    """The definition, token by token. q, k: (B, T, Hk, dk), Hk dividing H
+    (value heads h*H/Hk .. share key head h); v: (B, T, H, dv); g (log
+    decay, <= 0), beta: (B, T, H). Returns o (B, T, H, dv) in float32 and the
+    last state (B, H, dk, dv)."""
     q, k, v, g, beta = (f32_island(x) for x in (q, k, v, g, beta))
+    q, k = _repeat_key_heads(q, k, v.shape[2])
     b, _, h, dk = q.shape
     hi = lax.Precision.HIGHEST
 
@@ -80,19 +143,21 @@ def _unit_lower_inverse(a, mm):
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     """The chunked form; arguments and results as `gated_delta_recurrence`,
-    o in v's dtype. T need not be a multiple of `chunk`: the tail is padded
-    with tokens that leave the state alone (beta 0, no decay)."""
+    o in v's dtype. T need not be a multiple of the chunk: the tail is padded
+    with tokens that leave the state alone (beta 0, no decay). On a TPU, for
+    dk and dv multiples of 128, the Pallas kernel pair computes it
+    (ops/pallas_gated_delta.py; its chunk length is its own); everywhere else
+    the XLA form below, in chunks of `chunk`."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk must be a power of two, got {chunk}")
+    if takes_kernel() and kernel_shapes(q.shape[-1], v.shape[-1]):
+        return _kernel_rule(q, k, v, g, beta)
+    q, k = _repeat_key_heads(q, k, v.shape[2])
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     out_dtype = v.dtype
-    pad = -t % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
-    n = (t + pad) // chunk
+    q, k, v, g, beta = _pad_tokens(-t % chunk, q, k, v, g, beta)
+    n = q.shape[1] // chunk
 
     def chunks(x):  # (B, T, H, ...) -> (N, B, H, C, ...)
         x = x.reshape(b, n, chunk, *x.shape[2:])
@@ -142,3 +207,34 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)             # (B, N, C, H, dv)
     o = o.reshape(b, n * chunk, h, dv)[:, :t]
     return end_island(o, out_dtype), state
+
+
+def _pad_tokens(pad, *arrays):
+    if not pad:
+        return arrays
+    return tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                 for x in arrays)
+
+
+def _kernel_rule(q, k, v, g, beta):
+    """`gated_delta_rule` through the kernel pair: heads stay where the layer
+    has them (a head is a column block of the (B, T, H*d) view), the key
+    heads are not repeated, and g and beta go in as rows a chunk."""
+    sites = _sites.get()
+    if sites is not None:
+        sites.append((q.shape, v.shape))
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    c, out_dtype = kernel.CHUNK, v.dtype
+    q, k, v, g, beta = _pad_tokens(-t % c, q, end_island(k, q.dtype),
+                                   end_island(v, q.dtype), g, beta)
+    tp = q.shape[1]
+
+    def rows(x):  # (B, T, H) -> (B, H, N, C)
+        return jnp.moveaxis(f32_island(x), 1, 2).reshape(b, hv, tp // c, c)
+
+    o, state = kernel.gdn_chunks(
+        q.reshape(b, tp, hk * dk), k.reshape(b, tp, hk * dk),
+        v.reshape(b, tp, hv * dv), jnp.cumsum(rows(g), axis=-1), rows(beta),
+        hk, _interpret())
+    return end_island(o.reshape(b, tp, hv, dv)[:, :t], out_dtype), state

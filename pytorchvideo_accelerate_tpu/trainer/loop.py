@@ -1082,8 +1082,9 @@ class Trainer:
         # obs/input_wait_s, ...) logged through the trackers
         collector = obs.get_collector() if self.obs_on else None
         loader_rows = LoaderRowCounts()
-        # a trace-time fact of the step, logged once, with the first window
-        fold_sites_gauge = ["pva_conv_lane_fold_sites"]
+        # trace-time facts of the step, logged once, with the first window
+        site_gauges = {"obs/conv_lane_fold_sites": "pva_conv_lane_fold_sites",
+                       "obs/gdn_scan_kernel_sites": "pva_gdn_scan_kernel_sites"}
         loop_thread = threading.get_ident()
         tokens_per_step = (
             self.train_loader.global_batch_size * self.train_loader.accum_steps
@@ -1116,10 +1117,11 @@ class Trainer:
             share = loader_rows.window_share()
             if share is not None:
                 vals["obs/loader_rows_in_place_share"] = share
-            if fold_sites_gauge:
-                gauge = obs.get_registry().get(fold_sites_gauge.pop())
+            while site_gauges:
+                key, name = site_gauges.popitem()
+                gauge = obs.get_registry().get(name)
                 if gauge is not None:
-                    vals["obs/conv_lane_fold_sites"] = gauge.value()
+                    vals[key] = gauge.value()
             if tokens_per_step and window_wall and "iter" in window:
                 # a next-token model's rate over the window: the window's
                 # iterations (the `iter` span's count), host arithmetic

@@ -24,6 +24,7 @@ device resharding is needed to slice micro-batches.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -34,7 +35,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pytorchvideo_accelerate_tpu.obs.registry import get_registry
-from pytorchvideo_accelerate_tpu.ops import lane_fold
+from pytorchvideo_accelerate_tpu.ops import gated_delta, lane_fold
 from pytorchvideo_accelerate_tpu.parallel.mesh import batch_axes
 from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
 
@@ -301,6 +302,25 @@ def _make_update_step(
     return jax.jit(step, donate_argnums=0)
 
 
+@contextlib.contextmanager
+def _count_lowering_sites():
+    """Around the train step's `model.apply`: which shape-and-backend rules
+    engaged is a fact of the trace, set as gauges while the step is traced
+    (no op added) and logged once with the first window (trainer/loop.py)."""
+    with lane_fold.count_sites() as folded, \
+            gated_delta.count_sites() as scans:
+        yield
+    registry = get_registry()
+    registry.gauge(
+        "pva_conv_lane_fold_sites",
+        "ConvBNAct sites of the traced train step lowered as a "
+        "lane-filling contraction (ops/lane_fold.py)").set(len(folded))
+    registry.gauge(
+        "pva_gdn_scan_kernel_sites",
+        "gated_delta_rule calls of the traced train step lowered as the "
+        "Pallas kernel pair (ops/pallas_gated_delta.py)").set(len(scans))
+
+
 def make_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -397,7 +417,7 @@ def make_train_step(
                              + (1.0 - w) * x[::-1].astype(jnp.float32))
                     batch[k] = mixed.astype(x.dtype)
             labels2 = batch["label"][::-1]
-        with lane_fold.count_sites() as folded:
+        with _count_lowering_sites():
             logits, updates = model.apply(
                 {"params": params, "batch_stats": batch_stats},
                 model_inputs(batch),
@@ -405,11 +425,6 @@ def make_train_step(
                 rngs={"dropout": key},
                 mutable=["batch_stats"],
             )
-        # a fact of the trace, set while the step is traced (no op added)
-        get_registry().gauge(
-            "pva_conv_lane_fold_sites",
-            "ConvBNAct sites of the traced train step lowered as a "
-            "lane-filling contraction (ops/lane_fold.py)").set(len(folded))
         if labels2 is not None:
             loss_a, correct_a, count = _loss_and_metrics(
                 logits, batch["label"], mask, label_smoothing)
@@ -519,8 +534,9 @@ def make_lm_step(
     def forward_loss(params, batch_stats, batch, key):
         del key  # no dropout, no mask to draw
         tokens, targets, weights = _next_token_batch(batch)
-        out = model.apply({"params": params}, tokens, targets=targets,
-                          weights=weights, train=True)
+        with _count_lowering_sites():
+            out = model.apply({"params": params}, tokens, targets=targets,
+                              weights=weights, train=True)
         count = out["count"]
         loss = out["loss_sum"] / jnp.maximum(count, 1.0)
         rows = out["expert_rows"].astype(jnp.float32)     # (layers, held)

@@ -186,7 +186,8 @@ def per_window(work_dir, skip):
     """The worker threads' spans of the steady log windows, ms per step, from
     the jsonl tracker's `obs/*` lines; `batch` is the loader's wait for a
     batch's rows, `decode` the pool's threads summed (over `num_workers`: the
-    least a batch can take), `h2d` the placement."""
+    least a batch can take), `h2d` the placement; and the trace-time site
+    gauges the first window logged (`obs/*_sites`)."""
     lines = []
     for path in glob.glob(os.path.join(work_dir, "runs", "*.jsonl")):
         with open(path) as f:
@@ -199,6 +200,8 @@ def per_window(work_dir, skip):
     if not steps:
         return {}
     out = {"windows": len(steady), "steps": steps}
+    out.update({k[4:]: v[k] for v in windows for k in v
+                if k.startswith("obs/") and k.endswith("_sites")})
     for name in ("batch", "decode", "h2d"):
         out[f"{name}_ms_per_step"] = 1000 * sum(
             v.get(f"obs/{name}_s", 0.0) for _n, v in steady) / steps

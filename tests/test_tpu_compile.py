@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from pytorchvideo_accelerate_tpu.ops import gated_delta
 from pytorchvideo_accelerate_tpu.ops import pallas_fused as pf
+from pytorchvideo_accelerate_tpu.ops import pallas_gated_delta as pgd
 from pytorchvideo_accelerate_tpu.ops.pallas_attention import flash_attention
 from pytorchvideo_accelerate_tpu.ops.pallas_depthwise import (
     pallas_depthwise3d_s1,
@@ -86,6 +88,21 @@ def _dw_case(x_shape):
             lambda dt: [(x_shape, dt), ((3, 3, 3, 1, c), dt)])
 
 
+def _gdn_case(grad):
+    """qwen3_next_80b_a3b.train_8k's DeltaNet layer: 2 sequences of 8192
+    tokens, 16 key heads under 32 value heads, 128 wide."""
+    b, t, hk, hv, d = 2, 8192, 16, 32, 128
+    rows = (b, hv, t // pgd.CHUNK, pgd.CHUNK)
+
+    def fwd(q, k, v, gam, beta):
+        return pgd.gdn_chunks(q, k, v, gam, beta, hk, False)
+
+    fn = fwd if not grad else jax.grad(
+        lambda *a: fwd(*a)[0].astype(F32).sum(), argnums=(0, 1, 2, 3, 4))
+    return fn, lambda dt: [((b, t, hk * d), dt), ((b, t, hk * d), dt),
+                           ((b, t, hv * d), dt), (rows, F32), (rows, F32)]
+
+
 # name -> (function, dtype -> [(shape, dtype), ...]); shapes are the
 # real model shapes, plus one stage of x3d_s
 # (13 frames; W 40/20/10/5 at inner C 54/108/216/432) and of each
@@ -112,6 +129,8 @@ CASES = {
     "dw_x3d_res3": _dw_case((2, 13, 20, 20, 108)),
     "dw_x3d_res2": _dw_case((8, 13, 40, 40, 54)),
     "dw_x3d_res5": _dw_case((8, 13, 5, 5, 432)),
+    "gated_delta_fwd": _gdn_case(grad=False),
+    "gated_delta_fwd_saving_bwd": _gdn_case(grad=True),
 }
 
 
@@ -168,3 +187,47 @@ def test_fast_stem_lane_fold_compiles_lane_dense(v5e, monkeypatch):
     for dims, minor in outputs:
         assert dims.split(",")[int(minor)] == "128", convs
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_gated_delta_kernels_sit_under_the_scan_scope(v5e, monkeypatch):
+    """A `GatedDeltaNet` layer's gradient at `qwen3_next_80b_a3b.train_8k`'s
+    shapes, inside the model's rematerialised mixer unit, compiled for the chip
+    with the rule's backend half forced: the forward, the rematerialised
+    forward and the backward `pallas_call` are all there, and each sits under
+    an `op_name` that contains `gdn/scan/`, the rule by which
+    `gdn_scan_roofline` and `gdn_ms_per_step` select device time
+    (benchmarks/lib/hlo.py): a `custom_vjp`'s backward keeps the call's name
+    stack."""
+    import re
+
+    import flax.linen as nn
+
+    from pytorchvideo_accelerate_tpu.models.qwen3_next import (
+        Qwen3NextArch,
+        _Mixer,
+    )
+
+    monkeypatch.setattr(gated_delta, "takes_kernel", lambda: True)
+    monkeypatch.setattr(gated_delta, "_interpret", lambda: False)
+    layer = nn.remat(_Mixer)(Qwen3NextArch(), jnp.bfloat16,
+                             "linear_attention", name="mixer_0")
+    x_shape = (2, 8192, 2048)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0),
+                           jnp.zeros(x_shape, jnp.bfloat16)))["params"]
+
+    def loss(params, x):
+        with gated_delta.count_sites() as sites:
+            y = layer.apply({"params": params}, x)
+        assert len(sites) == 1
+        return jnp.sum(y.astype(F32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree.map(lambda a: v5e(a.shape, a.dtype), params),
+        v5e(x_shape, jnp.bfloat16)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call" in line and "pva_gdn" in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert sorted(re.search(r"pva_gdn_\w+", n).group(0) for n in names) == [
+        "pva_gdn_bwd", "pva_gdn_fwd_saving", "pva_gdn_fwd_saving"], names
+    assert all("gdn/scan/" in n for n in names), names

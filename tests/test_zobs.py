@@ -831,6 +831,10 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     # took the lane fold (ops/lane_fold.py; none on the CPU)
     assert [ln["obs/conv_lane_fold_sites"] for ln in lines
             if "obs/conv_lane_fold_sites" in ln] == [0.0]
+    # and the `gated_delta_rule` calls that took the Pallas kernel pair
+    # (ops/gated_delta.py; a conv model has none)
+    assert [ln["obs/gdn_scan_kernel_sites"] for ln in lines
+            if "obs/gdn_scan_kernel_sites" in ln] == [0.0]
 
 
 def test_zz_fit_spans_on_the_profilers_clock(tmp_path, _tiny_slow_r50):
