@@ -287,6 +287,7 @@ def _videomae_t_pretrain(cfg: ModelConfig, dtype, mesh=None, pipeline=None):
 def _qwen3_next(cfg: ModelConfig, dtype, published: dict):
     """The held share of a Qwen3-Next decoder: published widths, with the
     depth, the vocabulary slice and the experts held as the config says."""
+    from pytorchvideo_accelerate_tpu.models.lm_common import check_share
     from pytorchvideo_accelerate_tpu.models.qwen3_next import (
         Qwen3Next,
         Qwen3NextArch,
@@ -298,14 +299,8 @@ def _qwen3_next(cfg: ModelConfig, dtype, published: dict):
         num_hidden_layers=cfg.num_layers or arch.num_hidden_layers,
         vocab_size=cfg.vocab_size or arch.vocab_size,
         experts_held=cfg.experts_held, expert_offset=cfg.expert_offset)
-    if arch.num_hidden_layers % arch.full_attention_interval:
-        raise ValueError(
-            f"model.num_layers={arch.num_hidden_layers} is not whole periods "
-            f"of {arch.full_attention_interval} layers")
-    if not 0 <= arch.expert_offset <= arch.num_experts - arch.held:
-        raise ValueError(
-            f"experts [{arch.expert_offset}, {arch.expert_offset + arch.held}) "
-            f"are not among the model's {arch.num_experts}")
+    check_share(arch.num_hidden_layers, arch.full_attention_interval,
+                arch.expert_offset, arch.held, arch.num_experts)
     return Qwen3Next(arch, dtype=dtype, remat=True)
 
 
@@ -330,6 +325,53 @@ def _qwen3_next_t(cfg: ModelConfig, dtype, mesh=None):
         linear_value_head_dim=16, num_experts=8, num_experts_per_tok=2,
         moe_intermediate_size=32, shared_expert_intermediate_size=32,
         vocab_size=256))
+
+
+def _smallthinker(cfg: ModelConfig, dtype, published: dict):
+    """The held share of a SmallThinker decoder: published widths, with the
+    depth (the layouts' first layers), the vocabulary slice and the experts
+    held as the config says."""
+    from pytorchvideo_accelerate_tpu.models.lm_common import check_share
+    from pytorchvideo_accelerate_tpu.models.smallthinker import (
+        SmallThinker,
+        SmallThinkerArch,
+    )
+
+    arch = SmallThinkerArch(**published)
+    layers = cfg.num_layers or arch.num_hidden_layers
+    arch = dataclasses.replace(
+        arch, num_hidden_layers=layers,
+        vocab_size=cfg.vocab_size or arch.vocab_size,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset)
+    check_share(layers, arch.period, arch.expert_offset, arch.held,
+                arch.moe_num_primary_experts)
+    if layers > len(arch.sliding_window_layout):
+        raise ValueError(f"model.num_layers={layers} is more than the "
+                         f"{len(arch.sliding_window_layout)} layers published")
+    return SmallThinker(arch, dtype=dtype, remat=True)
+
+
+@register_model("smallthinker_21b_a3b", task="next_token")
+def _smallthinker_21b_a3b(cfg: ModelConfig, dtype, mesh=None):
+    """SmallThinker-21BA3B-Instruct at its published widths (the defaults of
+    `SmallThinkerArch`: huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct
+    config.json). One chip holds a share: `--model.num_layers`,
+    `--model.vocab_size`, `--model.experts_held` (docs/TOKENS.md)."""
+    return _smallthinker(cfg, dtype, {})
+
+
+@register_model("smallthinker_t", task="next_token")
+def _smallthinker_t(cfg: ModelConfig, dtype, mesh=None):
+    """Deliberately tiny SmallThinker: every mechanism (a full layer without
+    positions, three windowed rotary layers, 7 query heads on one key-value
+    head, the router ahead of the attention), toy widths — tests and the CPU
+    rehearsal. Not a reference architecture."""
+    return _smallthinker(cfg, dtype, dict(
+        hidden_size=64, num_hidden_layers=4, num_attention_heads=7,
+        num_key_value_heads=1, head_dim=16, sliding_window_size=32,
+        rope_layout=(0, 1, 1, 1), sliding_window_layout=(0, 1, 1, 1),
+        moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+        moe_ffn_hidden_size=32, vocab_size=256))
 
 
 def available_models():

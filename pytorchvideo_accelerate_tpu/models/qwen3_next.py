@@ -34,6 +34,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from pytorchvideo_accelerate_tpu.models.lm_common import (
+    _dense,
+    _normal,
+    held_experts,
+    lm_outputs,
+    rms,
+)
 from pytorchvideo_accelerate_tpu.ops.attention import (
     causal_gqa_attention,
     rotate_half,
@@ -73,21 +80,11 @@ class Qwen3NextArch:
 
     @property
     def held(self) -> int:
-        return self.experts_held or self.num_experts
+        return held_experts(self.experts_held, self.num_experts)
 
     def layer_type(self, i: int) -> str:
         return ("full_attention" if (i + 1) % self.full_attention_interval == 0
                 else "linear_attention")
-
-
-def _normal(stddev=0.02):
-    return nn.initializers.normal(stddev)
-
-
-def rms(x, eps):
-    """x * rsqrt(mean(x^2) + eps): float32 statistics, result in float32."""
-    x = f32_island(x)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
 class ZeroCentredNorm(nn.Module):
@@ -97,11 +94,6 @@ class ZeroCentredNorm(nn.Module):
     def __call__(self, x):
         w = self.param("scale", nn.initializers.zeros, (x.shape[-1],))
         return end_island(rms(x, self.eps) * (1.0 + w), x.dtype)
-
-
-def _dense(mod, name, x, features, dtype):
-    w = mod.param(name, _normal(), (x.shape[-1], features))
-    return jnp.dot(x, w.astype(dtype))
 
 
 class GatedDeltaNet(nn.Module):
@@ -244,39 +236,6 @@ class _Mixture(nn.Module):
         return h + y, rows
 
 
-def next_token_loss(hidden, head_kernel, targets, weight, block: int):
-    """Summed cross-entropy of `hidden` (N, D) against `targets` (N,) through
-    the head (D, V), each position times its `weight` (N,), float32, `block`
-    positions at a time (each block's logits are rematerialised in the
-    backward pass); also the weighted number of argmaxes that hit."""
-    n, d = hidden.shape
-    pad = -n % block
-    if pad:
-        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
-        targets = jnp.pad(targets, (0, pad))
-        weight = jnp.pad(weight, (0, pad))
-    shape = ((n + pad) // block, block)
-
-    @jax.checkpoint
-    def one(carry, xs):
-        h, y, w = xs
-        with jax.named_scope("lm_head"):
-            logits = f32_island(jnp.dot(h, head_kernel.astype(h.dtype)))
-        with jax.named_scope("loss"):
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
-            hit = (jnp.argmax(logits, axis=-1) == y).astype(jnp.float32)
-            loss, correct = carry
-            return (loss + ((logz - picked) * w).sum(),
-                    correct + (hit * w).sum()), None
-
-    zero = jnp.zeros((), jnp.float32)
-    (loss, correct), _ = jax.lax.scan(
-        one, (zero, zero),
-        (hidden.reshape(*shape, d), targets.reshape(shape), weight.reshape(shape)))
-    return loss, correct
-
-
 class Qwen3Next(nn.Module):
     arch: Qwen3NextArch
     dtype: Any = jnp.bfloat16
@@ -299,15 +258,4 @@ class Qwen3Next(nn.Module):
             rows.append(r)
         x = ZeroCentredNorm(a.rms_norm_eps, name="final_norm")(x)
         head = self.param("lm_head", _normal(), (a.hidden_size, a.vocab_size))
-        if targets is None:
-            with jax.named_scope("lm_head"):
-                return f32_island(jnp.dot(x, head.astype(self.dtype)))
-        b, t, d = x.shape
-        if weights is None:
-            weights = jnp.ones((b, t), jnp.float32)
-        loss_sum, correct = next_token_loss(
-            x.reshape(b * t, d), head, targets.reshape(b * t),
-            weights.reshape(b * t), self.loss_block)
-        return {"loss_sum": loss_sum, "correct": correct,
-                "count": weights.sum(),
-                "expert_rows": jnp.stack(rows)}      # (layers, held)
+        return lm_outputs(x, head, targets, weights, self.loss_block, rows)

@@ -25,7 +25,9 @@ definition of "slot qi may read slot kj iff 0 <= qi - kj < window".
 
 from __future__ import annotations
 
-from typing import Optional
+import contextlib
+import contextvars
+from typing import Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,17 +79,39 @@ def rotate_half(x, positions, theta: float, rotary_dim: int):
     return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
 
 
+_window_sites: contextvars.ContextVar = contextvars.ContextVar(
+    "pva_attn_window_sites", default=None)
+
+
+@contextlib.contextmanager
+def count_window_sites() -> Iterator[List[tuple]]:
+    """Collects, while a model is traced inside the block, one entry (T,
+    window) for every `causal_gqa_attention` call lowered under a band (the
+    `pva_attn_window_sites` gauge, trainer/steps.py)."""
+    sites: List[tuple] = []
+    token = _window_sites.set(sites)
+    try:
+        yield sites
+    finally:
+        _window_sites.reset(token)
+
+
 def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
-                         block_q: int = 512):
+                         block_q: int = 512, window: Optional[int] = None):
     """Causal softmax attention with grouped queries: q (B, T, Hq, D), k and v
     (B, T, Hkv, D), Hq a multiple of Hkv, each key-value head serving
-    Hq / Hkv query heads. Token t reads keys 0..t.
+    Hq / Hkv query heads. Token t reads keys 0..t; with `window`, the
+    trailing `window` of them: keys s with 0 <= t - s < window.
 
     Computed a block of `block_q` queries at a time against the keys up to
     that block's end, so the keys behind the diagonal cost nothing and the
     float32 scores of one block (B, Hq, block_q, keys so far) are the
     largest array alive; each block is rematerialised in the backward pass.
-    A sequence no longer than `block_q` is one dense masked product."""
+    A sequence no longer than `block_q` is one dense masked product. Under a
+    band a block reads the keys from the first one its first query may read,
+    rounded down to a block: at most `window + block_q` rounded up, so the
+    key blocks wholly behind the window cost nothing either. A window no
+    shorter than the sequence is no band and lowers as none."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     if hq % hkv:
@@ -95,6 +119,14 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
     if scale is None:
         scale = d ** -0.5
     group = hq // hkv
+    if window is not None and window >= t:
+        window = None
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window}")
+        sites = _window_sites.get()
+        if sites is not None:
+            sites.append((t, window))
 
     @jax.checkpoint
     def block(q_blk, k_seen, v_seen, start):
@@ -106,8 +138,12 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
         rows = rows.reshape(b, hkv, group * n, d)
         # f32 softmax logits: the island every attention impl shares
         logits = f32_island(jnp.einsum("bhrd,bkhd->bhrk", rows, k_seen)) * scale
+        # `start`: the block's first query, counted from the first key read
         pos = start + jnp.arange(group * n) % n
-        seen = jnp.arange(k_seen.shape[1])[None, :] <= pos[:, None]
+        if window is None:
+            seen = jnp.arange(k_seen.shape[1])[None, :] <= pos[:, None]
+        else:
+            seen = banded_time_mask(pos, jnp.arange(k_seen.shape[1]), window)
         probs = jax.nn.softmax(jnp.where(seen, logits, -1e30), axis=-1)
         out = jnp.einsum("bhrk,bkhd->bhrd", probs.astype(q.dtype), v_seen)
         out = out.reshape(b, hkv, group, n, d).transpose(0, 3, 1, 2, 4)
@@ -116,7 +152,11 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
     outs = []
     for start in range(0, t, block_q):
         end = min(start + block_q, t)
-        outs.append(block(q[:, start:end], k[:, :end], v[:, :end], start))
+        # the first key the block's first query may read, down to a block
+        lo = 0 if window is None else \
+            max(start - window + 1, 0) // block_q * block_q
+        outs.append(block(q[:, start:end], k[:, lo:end], v[:, lo:end],
+                          start - lo))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 

@@ -9,7 +9,8 @@ there is no exchange; the partial sum is what goes on (docs/TOKENS.md).
 
     p = softmax_f32(x W_r);  (w_k, e_k) = top-k of p;  w_k <- w_k / sum_k w_k
     y = sum over k with offset <= e_k < offset + held of
-            w_k * (silu(x W_gate[e_k]) * (x W_up[e_k])) W_down[e_k]
+            w_k * (act(x W_gate[e_k]) * (x W_up[e_k])) W_down[e_k]
+                                        (act: SiLU by default, ReLU for ReGLU experts)
 
 No capacity factor and no dropped token: every (token, held expert) pair is
 computed, whatever the imbalance. The pairs are sorted by expert, so each held
@@ -89,7 +90,8 @@ _collect.defvjp(
     lambda res, g: (_spread(g, *res), None, None))
 
 
-def _share(x, weights, experts, w_gate, w_up, w_down, expert_offset, bound):
+def _share(x, weights, experts, w_gate, w_up, w_down, expert_offset, bound,
+           activation=jax.nn.silu):
     """The held experts' part for tokens x (N, D) through buffers of `bound`
     rows; right only where at most `bound` pairs are local. Returns (y (N, D),
     rows (held,) int32)."""
@@ -119,7 +121,7 @@ def _share(x, weights, experts, w_gate, w_up, w_down, expert_offset, bound):
             return lax.ragged_dot(lhs, w.astype(x.dtype), rows,
                                   precision=precision)
 
-        ys = grouped(jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up),
+        ys = grouped(activation(grouped(xs, w_gate)) * grouped(xs, w_up),
                      w_down)
     with jax.named_scope("combine"):
         w_rows = jnp.where(valid, weights.reshape(pairs)[order], 0.0)
@@ -129,12 +131,13 @@ def _share(x, weights, experts, w_gate, w_up, w_down, expert_offset, bound):
 
 
 def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
-                 num_experts: int):
+                 num_experts: int, activation=jax.nn.silu):
     """The held experts' part of the mixture for tokens x (N, D), given the
     routing (`route`) over `num_experts`. `w_gate`, `w_up` (held, D, F) and
     `w_down` (held, F, D) are the experts [expert_offset, expert_offset +
-    held). Returns (y (N, D) in x's dtype, rows (held,) int32: how many
-    tokens each held expert computed)."""
+    held); `activation` is the gate's (SiLU: SwiGLU experts; ReLU: ReGLU).
+    Returns (y (N, D) in x's dtype, rows (held,) int32: how many tokens each
+    held expert computed)."""
     n, _ = x.shape
     top_k = experts.shape[-1]
     held = w_gate.shape[0]
@@ -143,7 +146,7 @@ def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
     bound = min(worst, -(-int(SLACK * expected) // ROW_TILE) * ROW_TILE)
     args = (w_gate, w_up, w_down, expert_offset)
     if bound == worst:
-        return _share(x, weights, experts, *args, bound)
+        return _share(x, weights, experts, *args, bound, activation)
     chunks = -(-worst // bound)
     per = -(-n // chunks)                      # tokens a chunk; its worst case
     chunk_bound = per * min(top_k, held)       # fits `bound` rows
@@ -154,7 +157,7 @@ def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
         experts = jnp.pad(experts, ((0, pad), (0, 0)), constant_values=-1)
         # rematerialised: the backward pass keeps a chunk's inputs, not its rows
         y, rows = lax.map(
-            jax.checkpoint(lambda c: _share(*c, *args, chunk_bound)),
+            jax.checkpoint(lambda c: _share(*c, *args, chunk_bound, activation)),
             tuple(a.reshape(chunks, per, a.shape[-1])
                   for a in (x, weights, experts)))
         return y.reshape(chunks * per, -1)[:n], rows.sum(axis=0)
@@ -162,5 +165,5 @@ def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
     local = experts - expert_offset
     count = jnp.sum((local >= 0) & (local < held))
     return lax.cond(count <= bound,
-                    lambda *a: _share(*a, *args, bound), in_chunks,
+                    lambda *a: _share(*a, *args, bound, activation), in_chunks,
                     x, weights, experts)

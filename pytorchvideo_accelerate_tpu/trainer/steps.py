@@ -35,7 +35,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pytorchvideo_accelerate_tpu.obs.registry import get_registry
-from pytorchvideo_accelerate_tpu.ops import gated_delta, lane_fold
+from pytorchvideo_accelerate_tpu.ops import attention, gated_delta, lane_fold
 from pytorchvideo_accelerate_tpu.parallel.mesh import batch_axes
 from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
 
@@ -308,7 +308,8 @@ def _count_lowering_sites():
     engaged is a fact of the trace, set as gauges while the step is traced
     (no op added) and logged once with the first window (trainer/loop.py)."""
     with lane_fold.count_sites() as folded, \
-            gated_delta.count_sites() as scans:
+            gated_delta.count_sites() as scans, \
+            attention.count_window_sites() as bands:
         yield
     registry = get_registry()
     registry.gauge(
@@ -319,6 +320,10 @@ def _count_lowering_sites():
         "pva_gdn_scan_kernel_sites",
         "gated_delta_rule calls of the traced train step lowered as the "
         "Pallas kernel pair (ops/pallas_gated_delta.py)").set(len(scans))
+    registry.gauge(
+        "pva_attn_window_sites",
+        "causal_gqa_attention calls of the traced train step lowered under "
+        "a sliding-window band (ops/attention.py)").set(len(bands))
 
 
 def make_train_step(

@@ -137,3 +137,49 @@ def test_router_gradient_flows_through_the_normalised_weights(layer):
     got, want = jax.grad(program)(p["router"]), jax.grad(reference)(p["router"])
     assert float(jnp.abs(want).max()) > 1e-3
     assert float(jnp.abs(got - want).max()) < 1e-4 * float(jnp.abs(want).max())
+
+
+# --- ReGLU experts with no shared expert (benchmarks/reference/smallthinker.py)
+
+def _reglu_arch(held, offset=0):
+    return {"moe_num_primary_experts": E, "moe_num_active_primary_experts": K,
+            "norm_topk_prob": True, "experts_held": held,
+            "expert_offset": offset}
+
+
+@pytest.mark.parametrize("shares", [4, 2, 1], ids=lambda s: f"{s}_shares")
+def test_relu_shares_add_up_to_the_uncut_reference_layer(layer, shares):
+    """The same 16 experts with `activation=relu` and no shared expert, as
+    `shares` shares: their parts add up to the uncut ReGLU layer of the other
+    family's reference, and each share alone is that reference's share."""
+    from benchmarks.reference import smallthinker as st
+
+    p, x = layer["p"], layer["x"]
+    flat = x.reshape(-1, D)
+    held = E // shares
+    weights, chosen = moe.route(flat, p["router"], K)
+    w_ref, e_ref = st.routing(p["router"], flat, _reglu_arch(E))
+    assert bool(jnp.all(chosen == e_ref))
+    whole, rows_whole = st.mixture(p, flat, w_ref, e_ref, _reglu_arch(E),
+                                   None, False, False, None)
+    total, rows = jnp.zeros_like(flat), []
+    for s in range(shares):
+        cut = slice(s * held, (s + 1) * held)
+        y, r = moe.expert_share(flat, weights, chosen, p["w_gate"][cut],
+                                p["w_up"][cut], p["w_down"][cut], s * held, E,
+                                activation=jax.nn.relu)
+        total = total + y
+        rows.append(r)
+        y_ref, r_ref = st.mixture(_share_params(p, s * held, held), flat,
+                                  w_ref, e_ref, _reglu_arch(held, s * held),
+                                  None, False, False, None)
+        assert float(jnp.abs(y - y_ref).max()) < 1e-5
+        assert bool(jnp.all(r == r_ref))
+    assert float(jnp.abs(total - whole).max()) < 1e-5
+    assert bool(jnp.all(jnp.concatenate(rows) == rows_whole))
+    assert int(jnp.concatenate(rows).sum()) == N * K  # every pair, once
+    # and the gate is the ReLU: the SiLU layer is another layer
+    silu, _ = moe.expert_share(flat, weights, chosen, p["w_gate"], p["w_up"],
+                               p["w_down"], 0, E)
+    if shares == 1:
+        assert float(jnp.abs(silu - whole).max()) > 1e-2
