@@ -126,7 +126,7 @@ def kernels_phase(rehearse: bool) -> None:
 
     from pytorchvideo_accelerate_tpu.ops import kbench_refs as refs
     from pytorchvideo_accelerate_tpu.ops import pallas_fused as pf
-    from pytorchvideo_accelerate_tpu.ops import gated_delta
+    from pytorchvideo_accelerate_tpu.ops import attention, gated_delta
     from pytorchvideo_accelerate_tpu.ops.attention import dense_attention
     from pytorchvideo_accelerate_tpu.ops.pallas_attention import (
         flash_attention,
@@ -233,6 +233,42 @@ def kernels_phase(rehearse: bool) -> None:
     report("gated_delta_fwd", v.shape, _rel_err(got, want))
     report("gated_delta_bwd", v.shape, max(
         _rel_err(a, w) for a, w in zip(grads, want_grads)))
+    # the token cells' attention cores: qwen3_next_80b_a3b.train_8k's (groups
+    # of 8, heads of 256) and smallthinker_21b_a3b.train_16k's under its band
+    # (groups of 7, heads of 128), as the backend and the shapes choose the
+    # lowering (on the chip the flash kernels of ops/pallas_attention.py, in
+    # the rehearsal the XLA form) against the XLA form in float32
+    cases = [("causal_attention", (1, 48, 4, 2, 16) if rehearse
+              else (2, 8192, 16, 2, 256), None),
+             ("window_attention", (1, 48, 7, 1, 16) if rehearse
+              else (1, 16384, 28, 4, 128), 20 if rehearse else 4096)]
+    with attention.count_kernel_sites() as sites:
+        for name, (b, t, hq, hkv, d), window in cases:
+            q, g = arr((b, t, hq, d)), arr((b, t, hq, d))
+            k, v = arr((b, t, hkv, d)), arr((b, t, hkv, d))
+
+            def read_out(fn):
+                return lambda *xs: jnp.sum(
+                    fn(*xs).astype(jnp.float32) * g.astype(jnp.float32))
+
+            def core(*xs):
+                return attention.causal_gqa_attention(*xs, window=window)
+
+            def xla_form(*xs):
+                return attention.blocked_causal_attention(
+                    *xs, d ** -0.5, 512, window)
+
+            if window is None:
+                report(f"{name}_fwd", q.shape, _rel_err(
+                    jax.jit(core)(q, k, v), reference(xla_form, q, k, v)))
+            grads = jax.jit(jax.grad(read_out(core), argnums=(0, 1, 2)))(
+                q, k, v)
+            want_grads = reference(jax.grad(read_out(xla_form),
+                                            argnums=(0, 1, 2)), q, k, v)
+            report(f"{name}_bwd", q.shape, max(
+                _rel_err(a, w) for a, w in zip(grads, want_grads)))
+    check(len(sites) == (0 if rehearse else 3),
+          f"causal_gqa_attention took the kernels at {len(sites)} of 3 traces")
 
 
 # --- train ------------------------------------------------------------------

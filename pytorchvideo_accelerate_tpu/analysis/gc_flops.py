@@ -114,15 +114,18 @@ def _dw_kernel_flops(eqn) -> float:
     return 2.0 * _prod(out.shape) * k.shape[0] + 2.0 * _prod(out.shape)
 
 
-def _attn_flops(matmuls: int) -> Callable[[Any], float]:
-    # ops/pallas_attention kernels: q/k (BH, Nq, D)/(BH, Nk, D); each
-    # "matmul" is a (Nq, Nk) x D contraction class (fwd: QK^T + PV = 2;
-    # dq: S recompute + dP + dS@K = 3; dkv: S + dV + dP + dK = 4)
+def _attn_flops(products: int) -> Callable[[Any], float]:
+    # ops/pallas_attention kernels: a grid step is one (block_q, block_k)
+    # tile of every query head of a key head, and the grid's last axis walks
+    # only the tiles the mask lets through. The kernel's refs after the three
+    # prefetched tables: q (block_q, group * d), k (block_k, d). Products a
+    # tile: forward q k^T + p v = 2; dq: scores, dp, ds k = 3; dk/dv: scores,
+    # dv, dp, dk = 4
     def hook(eqn) -> float:
-        q, k = (v.aval for v in eqn.invars[:2])
-        bh, nq, d = q.shape
-        nk = k.shape[1]
-        return 2.0 * matmuls * bh * nq * nk * d
+        refs = eqn.params["jaxpr"].invars
+        (block_q, width), (block_k, _) = refs[3].aval.shape, refs[4].aval.shape
+        steps = _prod(eqn.params["grid_mapping"].grid)
+        return 2.0 * products * steps * block_q * block_k * width
 
     return hook
 
@@ -152,17 +155,16 @@ def _gdn_flops(backward: bool) -> Callable[[Any], float]:
     return hook
 
 
-# in-tree kernels: the conv kernels by the stable `name=` their
-# pallas_call gives (also the kernel's name in a device trace), the
-# attention kernels by kernel function name
+# in-tree kernels, by the stable `name=` their pallas_call gives (also the
+# kernel's name in a device trace)
 PALLAS_FLOPS_HOOKS.update({
     "pva_fused_pointwise_bn_act": _pw_kernel_flops,
     "pva_fused_conv3d_bn_act": _conv_kernel_flops,
     "pva_fused_depthwise_bn_act": _dw_kernel_flops,
     "pva_depthwise3d_s1": _dw_kernel_flops,
-    "_fwd_kernel": _attn_flops(2),
-    "_bwd_dq_kernel": _attn_flops(3),
-    "_bwd_dkv_kernel": _attn_flops(4),
+    "pva_attn_fwd": _attn_flops(2),
+    "pva_attn_dq": _attn_flops(3),
+    "pva_attn_dkv": _attn_flops(4),
     "pva_gdn_fwd": _gdn_flops(backward=False),
     "pva_gdn_fwd_saving": _gdn_flops(backward=False),
     "pva_gdn_bwd": _gdn_flops(backward=True),

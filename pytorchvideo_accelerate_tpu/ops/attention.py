@@ -32,6 +32,7 @@ from typing import Iterator, List, Optional
 import jax
 import jax.numpy as jnp
 
+from pytorchvideo_accelerate_tpu.ops import pallas_attention
 from pytorchvideo_accelerate_tpu.precision import f32_island
 
 
@@ -81,19 +82,53 @@ def rotate_half(x, positions, theta: float, rotary_dim: int):
 
 _window_sites: contextvars.ContextVar = contextvars.ContextVar(
     "pva_attn_window_sites", default=None)
+_kernel_sites: contextvars.ContextVar = contextvars.ContextVar(
+    "pva_attn_kernel_sites", default=None)
 
 
 @contextlib.contextmanager
-def count_window_sites() -> Iterator[List[tuple]]:
-    """Collects, while a model is traced inside the block, one entry (T,
-    window) for every `causal_gqa_attention` call lowered under a band (the
-    `pva_attn_window_sites` gauge, trainer/steps.py)."""
+def _collect(var: contextvars.ContextVar) -> Iterator[List[tuple]]:
     sites: List[tuple] = []
-    token = _window_sites.set(sites)
+    token = var.set(sites)
     try:
         yield sites
     finally:
-        _window_sites.reset(token)
+        var.reset(token)
+
+
+def count_window_sites():
+    """Collects, while a model is traced inside the block, one entry (T,
+    window) for every `causal_gqa_attention` call lowered under a band (the
+    `pva_attn_window_sites` gauge, trainer/steps.py)."""
+    return _collect(_window_sites)
+
+
+def count_kernel_sites():
+    """The same for every `causal_gqa_attention` call that took the Pallas
+    flash kernels, one entry (q's shape, window): the lowering is static, so
+    its engagement is a fact of the trace (the `pva_attn_kernel_sites`
+    gauge)."""
+    return _collect(_kernel_sites)
+
+
+def takes_kernel() -> bool:
+    """The backend half of the rule that picks `causal_gqa_attention`'s
+    lowering (`lane_fold.takes_fold`'s, `gated_delta.takes_kernel`'s): off the
+    TPU the kernels could only be interpreted."""
+    return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def kernel_shapes(t: int, d: int) -> bool:
+    """The shape half: the kernels read a head as a 128-lane column block of
+    the layer's (B, T, H*d) arrays, and a sequence of one block has no key
+    block to skip and no scores worth keeping out of HBM: it stays the XLA
+    form's one dense masked product (so does the 128-token sample a model is
+    initialised on, un-jitted: no Mosaic compile outside the jitted step)."""
+    return d % 128 == 0 and t > pallas_attention.BLOCK_Q
 
 
 def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
@@ -103,22 +138,23 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
     Hq / Hkv query heads. Token t reads keys 0..t; with `window`, the
     trailing `window` of them: keys s with 0 <= t - s < window.
 
-    Computed a block of `block_q` queries at a time against the keys up to
-    that block's end, so the keys behind the diagonal cost nothing and the
-    float32 scores of one block (B, Hq, block_q, keys so far) are the
-    largest array alive; each block is rematerialised in the backward pass.
-    A sequence no longer than `block_q` is one dense masked product. Under a
-    band a block reads the keys from the first one its first query may read,
-    rounded down to a block: at most `window + block_q` rounded up, so the
-    key blocks wholly behind the window cost nothing either. A window no
-    shorter than the sequence is no band and lowers as none."""
+    One algorithm, two lowerings, chosen by what the code can observe
+    (`takes_kernel`: the backend; `kernel_shapes`: the heads' width and the
+    sequence's length) and by nothing a caller sets. On a TPU, for heads a
+    multiple of 128 wide and a sequence longer than one block, the Pallas flash
+    kernels of ops/pallas_attention.py: a tile of float32 scores
+    and the softmax's running max and sum stay in VMEM, and only the key
+    blocks the diagonal and the band let through are fetched (docs/KERNELS.md).
+    Everywhere else (the CPU, the toy models' narrow heads) the XLA form,
+    `blocked_causal_attention`, in blocks of `block_q` queries: the definition
+    the tests hold the kernels to. A window no shorter than the sequence is
+    no band and lowers as none."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     if hq % hkv:
         raise ValueError(f"{hq} query heads over {hkv} key-value heads")
     if scale is None:
         scale = d ** -0.5
-    group = hq // hkv
     if window is not None and window >= t:
         window = None
     if window is not None:
@@ -127,6 +163,29 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
         sites = _window_sites.get()
         if sites is not None:
             sites.append((t, window))
+    if takes_kernel() and kernel_shapes(t, d):
+        sites = _kernel_sites.get()
+        if sites is not None:
+            sites.append((q.shape, window))
+        return pallas_attention.causal_flash_attention(
+            q, k, v, scale, window, _interpret())
+    return blocked_causal_attention(q, k, v, scale, block_q, window)
+
+
+def blocked_causal_attention(q, k, v, scale: float, block_q: int,
+                             window: Optional[int]):
+    """`causal_gqa_attention` as XLA products (`window`: None or shorter than
+    the sequence), computed a block of `block_q` queries at a time against the
+    keys up to that block's end, so the keys behind the diagonal cost nothing
+    and the float32 scores of one block (B, Hq, block_q, keys so far) are the
+    largest array alive; each block is rematerialised in the backward pass.
+    A sequence no longer than `block_q` is one dense masked product. Under a
+    band a block reads the keys from the first one its first query may read,
+    rounded down to a block: at most `window + block_q` rounded up, so the
+    key blocks wholly behind the window cost nothing either."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    group = hq // hkv
 
     @jax.checkpoint
     def block(q_blk, k_seen, v_seen, start):
@@ -218,9 +277,7 @@ def dot_product_attention(q, k, v, backend: str = "dense",
             "causal/windowed trunks need backend='dense' "
             "(model.attention) — see docs/SERVING.md § trunk-reuse")
     if backend == "pallas":
-        from pytorchvideo_accelerate_tpu.ops.pallas_attention import flash_attention
-
-        return flash_attention(q, k, v)
+        return pallas_attention.flash_attention(q, k, v)
     if backend == "ring":
         from pytorchvideo_accelerate_tpu.parallel.ring_attention import (
             make_ring_attention, ring_attention,

@@ -1085,7 +1085,8 @@ class Trainer:
         # trace-time facts of the step, logged once, with the first window
         site_gauges = {"obs/conv_lane_fold_sites": "pva_conv_lane_fold_sites",
                        "obs/gdn_scan_kernel_sites": "pva_gdn_scan_kernel_sites",
-                       "obs/attn_window_sites": "pva_attn_window_sites"}
+                       "obs/attn_window_sites": "pva_attn_window_sites",
+                       "obs/attn_kernel_sites": "pva_attn_kernel_sites"}
         loop_thread = threading.get_ident()
         tokens_per_step = (
             self.train_loader.global_batch_size * self.train_loader.accum_steps

@@ -309,7 +309,8 @@ def _count_lowering_sites():
     (no op added) and logged once with the first window (trainer/loop.py)."""
     with lane_fold.count_sites() as folded, \
             gated_delta.count_sites() as scans, \
-            attention.count_window_sites() as bands:
+            attention.count_window_sites() as bands, \
+            attention.count_kernel_sites() as flashes:
         yield
     registry = get_registry()
     registry.gauge(
@@ -324,6 +325,10 @@ def _count_lowering_sites():
         "pva_attn_window_sites",
         "causal_gqa_attention calls of the traced train step lowered under "
         "a sliding-window band (ops/attention.py)").set(len(bands))
+    registry.gauge(
+        "pva_attn_kernel_sites",
+        "causal_gqa_attention calls of the traced train step lowered as the "
+        "Pallas flash kernels (ops/pallas_attention.py)").set(len(flashes))
 
 
 def make_train_step(

@@ -222,6 +222,9 @@ def test_three_fit_steps_reproduce_the_references_losses(tmp_path):
     sites = [v["obs/attn_window_sites"] for _s, v in got["logged"]
              if "obs/attn_window_sites" in v]
     assert sites == [3]
+    # and none took the flash kernels: not on the CPU, not at 16-wide heads
+    assert [v["obs/attn_kernel_sites"] for _s, v in got["logged"]
+            if "obs/attn_kernel_sites" in v] == [0]
 
 
 def test_model_input_spec_and_share_validation():

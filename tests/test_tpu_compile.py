@@ -16,10 +16,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from pytorchvideo_accelerate_tpu.ops import gated_delta
+from pytorchvideo_accelerate_tpu.ops import attention, gated_delta
 from pytorchvideo_accelerate_tpu.ops import pallas_fused as pf
 from pytorchvideo_accelerate_tpu.ops import pallas_gated_delta as pgd
-from pytorchvideo_accelerate_tpu.ops.pallas_attention import flash_attention
+from pytorchvideo_accelerate_tpu.ops.pallas_attention import (
+    causal_flash_attention,
+    flash_attention,
+)
 from pytorchvideo_accelerate_tpu.ops.pallas_depthwise import (
     pallas_depthwise3d_s1,
 )
@@ -103,6 +106,24 @@ def _gdn_case(grad):
                            ((b, t, hv * d), dt), (rows, F32), (rows, F32)]
 
 
+def _causal_case(b, t, hq, hkv, d, window, grad):
+    """`causal_gqa_attention`'s kernels at a token cell's shapes."""
+    def fwd(q, k, v):
+        return causal_flash_attention(q, k, v, d ** -0.5, window, False)
+
+    fn = fwd if not grad else jax.grad(
+        lambda *a: fwd(*a).astype(F32).sum(), argnums=(0, 1, 2))
+    return fn, lambda dt: [((b, t, hq, d), dt), ((b, t, hkv, d), dt),
+                           ((b, t, hkv, d), dt)]
+
+
+# qwen3_next_80b_a3b.train_8k: 2 x 8192, 16 query heads on 2 key-value heads
+# of 256; smallthinker_21b_a3b.train_16k: 1 x 16,384, 28 on 4 of 128, its
+# full layer and its three under the 4096-token band
+_CAUSAL = {"causal_8k_heads_of_256": (2, 8192, 16, 2, 256, None),
+           "causal_16k_groups_of_7": (1, 16384, 28, 4, 128, None),
+           "window_16k_groups_of_7": (1, 16384, 28, 4, 128, 4096)}
+
 # name -> (function, dtype -> [(shape, dtype), ...]); shapes are the
 # real model shapes, plus one stage of x3d_s
 # (13 frames; W 40/20/10/5 at inner C 54/108/216/432) and of each
@@ -131,6 +152,8 @@ CASES = {
     "dw_x3d_res5": _dw_case((8, 13, 5, 5, 432)),
     "gated_delta_fwd": _gdn_case(grad=False),
     "gated_delta_fwd_saving_bwd": _gdn_case(grad=True),
+    **{f"{name}_{'fwd_dq_dkv' if grad else 'fwd'}": _causal_case(*shape, grad)
+       for name, shape in _CAUSAL.items() for grad in (False, True)},
 }
 
 
@@ -231,3 +254,63 @@ def test_gated_delta_kernels_sit_under_the_scan_scope(v5e, monkeypatch):
     assert sorted(re.search(r"pva_gdn_\w+", n).group(0) for n in names) == [
         "pva_gdn_bwd", "pva_gdn_fwd_saving", "pva_gdn_fwd_saving"], names
     assert all("gdn/scan/" in n for n in names), names
+
+
+@pytest.mark.parametrize("family,scope", [
+    ("qwen3_next", "attn/core/"),
+    ("smallthinker_full", "attn/core/"),
+    ("smallthinker_window", "swa/core/"),
+])
+def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
+                                                    scope):
+    """An attention layer's gradient at each token cell's shapes, inside the
+    family's rematerialised mixer unit, compiled for the chip with the rule's
+    backend half forced: the forward, the rematerialised forward, dq and dk/dv
+    `pallas_call`s are all there and each sits under an `op_name` that contains
+    `attn/core/` (`swa/core/` for a windowed layer), the rule by which
+    `causal_attention_roofline` and `window_attention_roofline` select device
+    time and raise over 100% (benchmarks/lib/scoped.py): a backward kernel
+    outside the scope would turn a gain into `output_malformed`."""
+    import re
+
+    import flax.linen as nn
+
+    from pytorchvideo_accelerate_tpu.models import qwen3_next, smallthinker
+
+    monkeypatch.setattr(attention, "takes_kernel", lambda: True)
+    monkeypatch.setattr(attention, "_interpret", lambda: False)
+    if family == "qwen3_next":
+        layer = nn.remat(qwen3_next._Mixer)(
+            qwen3_next.Qwen3NextArch(), jnp.bfloat16, "full_attention",
+            name="mixer_3")
+        x_shape, window = (2, 8192, 2048), None
+    else:
+        arch = smallthinker.SmallThinkerArch()
+        window = arch.sliding_window_size if family.endswith("window") else None
+        layer = nn.remat(smallthinker._Mixer)(
+            arch, jnp.bfloat16, rotary=window is not None, window=window,
+            name="mixer_0")
+        x_shape = (1, 16384, arch.hidden_size)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0),
+                           jnp.zeros(x_shape, jnp.bfloat16)))["params"]
+
+    def loss(params, x):
+        with attention.count_kernel_sites() as sites:
+            y = layer.apply({"params": params}, x)
+        assert [w for _shape, w in sites] == [window]
+        return sum(jnp.sum(leaf.astype(F32) ** 2)
+                   for leaf in jax.tree.leaves(y)
+                   if jnp.issubdtype(leaf.dtype, jnp.floating))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree.map(lambda a: v5e(a.shape, a.dtype), params),
+        v5e(x_shape, jnp.bfloat16)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call" in line and "pva_attn" in line]
+    op_names = [re.search(r'op_name="([^"]*)"', line).group(1)
+                for line in calls]
+    assert sorted(re.search(r"pva_attn_\w+", n).group(0)
+                  for n in op_names) == ["pva_attn_dkv", "pva_attn_dq",
+                                         "pva_attn_fwd", "pva_attn_fwd"], op_names
+    assert all(scope in n for n in op_names), op_names

@@ -835,6 +835,82 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     # (ops/gated_delta.py; a conv model has none)
     assert [ln["obs/gdn_scan_kernel_sites"] for ln in lines
             if "obs/gdn_scan_kernel_sites" in ln] == [0.0]
+    # and the `causal_gqa_attention` calls that took the Pallas flash
+    # kernels (ops/attention.py; a conv model has none)
+    assert [ln["obs/attn_kernel_sites"] for ln in lines
+            if "obs/attn_kernel_sites" in ln] == [0.0]
+
+
+def _toy_token_model(family, head_dim):
+    """Four layers of a token family at toy widths but for the attention
+    heads' (the shape half of `causal_gqa_attention`'s rule reads it)."""
+    import jax.numpy as jnp
+
+    if family == "qwen3_next":
+        from pytorchvideo_accelerate_tpu.models.qwen3_next import (
+            Qwen3Next,
+            Qwen3NextArch,
+        )
+
+        return Qwen3Next(Qwen3NextArch(
+            hidden_size=64, num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=head_dim, linear_num_key_heads=1,
+            linear_num_value_heads=2, linear_key_head_dim=16,
+            linear_value_head_dim=16, num_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            vocab_size=256), dtype=jnp.float32, remat=True)
+    from pytorchvideo_accelerate_tpu.models.smallthinker import (
+        SmallThinker,
+        SmallThinkerArch,
+    )
+
+    return SmallThinker(SmallThinkerArch(
+        hidden_size=64, num_hidden_layers=4, num_attention_heads=7,
+        num_key_value_heads=1, head_dim=head_dim, sliding_window_size=32,
+        rope_layout=(0, 1, 1, 1), sliding_window_layout=(0, 1, 1, 1),
+        moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+        moe_ffn_hidden_size=32, vocab_size=256), dtype=jnp.float32,
+        remat=True)
+
+
+@pytest.mark.parametrize("family,forced,head_dim,kernels,bands", [
+    ("qwen3_next", True, 128, 1, 0),
+    ("smallthinker", True, 128, 4, 3),
+    ("qwen3_next", False, 128, 0, 0),
+    ("smallthinker", False, 128, 0, 3),
+    ("smallthinker", True, 16, 0, 3),
+], ids=["qwen3_next_tpu_rule", "smallthinker_tpu_rule", "qwen3_next_cpu_rule",
+        "smallthinker_cpu_rule", "smallthinker_tpu_rule_toy_heads"])
+def test_attn_kernel_sites_gauge(monkeypatch, family, forced, head_dim,
+                                 kernels, bands):
+    """`pva_attn_kernel_sites`, set while the next-token step is traced: the
+    one attention layer of a Qwen3-Next period, all four of a SmallThinker
+    period (the three under the band still count as `pva_attn_window_sites`)
+    where the rule holds; none by the CPU's own rule, none at the toy models'
+    16-wide heads."""
+    import jax
+    import optax
+
+    from pytorchvideo_accelerate_tpu.config import MeshConfig
+    from pytorchvideo_accelerate_tpu.ops import attention
+    from pytorchvideo_accelerate_tpu.parallel.mesh import make_train_mesh
+    from pytorchvideo_accelerate_tpu.trainer.steps import make_lm_step
+    from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
+
+    if forced:
+        monkeypatch.setattr(attention, "takes_kernel", lambda: True)
+    model = _toy_token_model(family, head_dim)
+    # past one block of the kernels: a shorter sequence keeps the XLA form
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 1024), "int32")}
+    mesh = make_train_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    tx = optax.sgd(0.01)
+    params = jax.eval_shape(
+        lambda t: model.init(jax.random.key(0), t), batch["tokens"])["params"]
+    state = jax.eval_shape(lambda p: TrainState.create(p, {}, tx), params)
+    make_lm_step(model, tx, mesh).trace(state, batch, jax.random.key(0))
+    registry = obs.get_registry()
+    assert registry.get("pva_attn_kernel_sites").value() == kernels
+    assert registry.get("pva_attn_window_sites").value() == bands
 
 
 def test_zz_fit_spans_on_the_profilers_clock(tmp_path, _tiny_slow_r50):
