@@ -234,14 +234,17 @@ def kernels_phase(rehearse: bool) -> None:
     report("gated_delta_bwd", v.shape, max(
         _rel_err(a, w) for a, w in zip(grads, want_grads)))
     # the token cells' attention cores: qwen3_next_80b_a3b.train_8k's (groups
-    # of 8, heads of 256) and smallthinker_21b_a3b.train_16k's under its band
-    # (groups of 7, heads of 128), as the backend and the shapes choose the
+    # of 8, heads of 256), smallthinker_21b_a3b.train_16k's under its band
+    # (groups of 7, heads of 128) and ouro_2_6b.train_4k's (groups of ONE,
+    # heads of 128, 4096 tokens), as the backend and the shapes choose the
     # lowering (on the chip the flash kernels of ops/pallas_attention.py, in
     # the rehearsal the XLA form) against the XLA form in float32
     cases = [("causal_attention", (1, 48, 4, 2, 16) if rehearse
               else (2, 8192, 16, 2, 256), None),
              ("window_attention", (1, 48, 7, 1, 16) if rehearse
-              else (1, 16384, 28, 4, 128), 20 if rehearse else 4096)]
+              else (1, 16384, 28, 4, 128), 20 if rehearse else 4096),
+             ("causal_attention_g1", (1, 48, 4, 4, 16) if rehearse
+              else (1, 4096, 16, 16, 128), None)]
     with attention.count_kernel_sites() as sites:
         for name, (b, t, hq, hkv, d), window in cases:
             q, g = arr((b, t, hq, d)), arr((b, t, hq, d))
@@ -267,8 +270,8 @@ def kernels_phase(rehearse: bool) -> None:
                                             argnums=(0, 1, 2)), q, k, v)
             report(f"{name}_bwd", q.shape, max(
                 _rel_err(a, w) for a, w in zip(grads, want_grads)))
-    check(len(sites) == (0 if rehearse else 3),
-          f"causal_gqa_attention took the kernels at {len(sites)} of 3 traces")
+    check(len(sites) == (0 if rehearse else 5),
+          f"causal_gqa_attention took the kernels at {len(sites)} of 5 traces")
 
 
 # --- train ------------------------------------------------------------------

@@ -200,10 +200,11 @@ class ModelConfig:
     # accuracy recovers (the recipe in docs/SERVING.md).
     attn_mask: str = "none"  # none | causal | windowed
     attn_window: int = 0     # temporal slots (= frames / tubelet_t)
-    # Token models (models/qwen3_next.py, models/smallthinker.py;
-    # docs/TOKENS.md): the share of the published model held here. 0 = as
-    # published. The router keeps its published width whatever is held; the
-    # traffic draws its ids from the held vocabulary slice [0, vocab_size).
+    # Token models (models/qwen3_next.py, models/smallthinker.py,
+    # models/ouro.py; docs/TOKENS.md): the share of the published model held
+    # here. 0 = as published. The router keeps its published width whatever
+    # is held (models/ouro.py has no experts and takes none); the traffic
+    # draws its ids from the held vocabulary slice [0, vocab_size).
     num_layers: int = 0      # decoder layers, whole periods of the pattern
     vocab_size: int = 0      # rows of the embedding and the head held here
     experts_held: int = 0    # routed experts held, of the model's num_experts

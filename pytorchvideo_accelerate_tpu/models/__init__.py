@@ -374,6 +374,43 @@ def _smallthinker_t(cfg: ModelConfig, dtype, mesh=None):
         moe_ffn_hidden_size=32, vocab_size=256))
 
 
+def _ouro(cfg: ModelConfig, dtype, published: dict):
+    """An Ouro decoder: published widths, with the depth and the vocabulary
+    slice as the config says. It has no experts to hold a share of."""
+    from pytorchvideo_accelerate_tpu.models.ouro import Ouro, OuroArch
+
+    if cfg.experts_held or cfg.expert_offset:
+        raise ValueError(
+            f"model {cfg.name!r} has no experts: model.experts_held="
+            f"{cfg.experts_held} and model.expert_offset={cfg.expert_offset} "
+            "must stay 0")
+    arch = OuroArch(**published)
+    arch = dataclasses.replace(
+        arch, num_hidden_layers=cfg.num_layers or arch.num_hidden_layers,
+        vocab_size=cfg.vocab_size or arch.vocab_size)
+    return Ouro(arch, dtype=dtype, remat=True)
+
+
+@register_model("ouro_2_6b", task="next_token")
+def _ouro_2_6b(cfg: ModelConfig, dtype, mesh=None):
+    """Ouro-2.6B at its published widths (the defaults of `OuroArch`:
+    huggingface.co/ByteDance/Ouro-2.6B config.json): 48 layers run four times
+    a step. One chip holds a pipeline stage's layers: `--model.num_layers`,
+    `--model.vocab_size` (docs/TOKENS.md)."""
+    return _ouro(cfg, dtype, {})
+
+
+@register_model("ouro_t", task="next_token")
+def _ouro_t(cfg: ModelConfig, dtype, mesh=None):
+    """Deliberately tiny Ouro: every mechanism (two layers run three times,
+    sandwich norms, the exit gate and the exit-weighted loss), toy widths —
+    tests and the CPU rehearsal. Not a reference architecture."""
+    return _ouro(cfg, dtype, dict(
+        hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+        num_key_value_heads=4, head_dim=16, intermediate_size=96,
+        vocab_size=256, total_ut_steps=3))
+
+
 def available_models():
     return sorted(_REGISTRY)
 

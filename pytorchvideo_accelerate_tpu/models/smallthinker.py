@@ -44,18 +44,13 @@ import jax
 import jax.numpy as jnp
 
 from pytorchvideo_accelerate_tpu.models.lm_common import (
-    _dense,
+    Attention,
+    Norm,
     _normal,
     held_experts,
     lm_outputs,
-    rms,
-)
-from pytorchvideo_accelerate_tpu.ops.attention import (
-    causal_gqa_attention,
-    rotate_half,
 )
 from pytorchvideo_accelerate_tpu.ops.moe import expert_share, route
-from pytorchvideo_accelerate_tpu.precision import end_island
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,44 +86,6 @@ class SmallThinkerArch:
         both = list(zip(self.rope_layout, self.sliding_window_layout))
         return next(p for p in range(1, len(both) + 1)
                     if len(both) % p == 0 and both == both[:p] * (len(both) // p))
-
-
-class Norm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        w = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return end_island(rms(x, self.eps) * w, x.dtype)
-
-
-class Attention(nn.Module):
-    """Causal grouped-query attention, rotary or not, banded or not."""
-
-    arch: SmallThinkerArch
-    dtype: Any
-    rotary: bool
-    window: Optional[int]
-
-    @nn.compact
-    def __call__(self, x):
-        a, dt = self.arch, self.dtype
-        b, t, _ = x.shape
-        hq, hkv, d = a.num_attention_heads, a.num_key_value_heads, a.head_dim
-        with jax.named_scope("qkv"):
-            q = _dense(self, "q_proj", x, hq * d, dt).reshape(b, t, hq, d)
-            k = _dense(self, "k_proj", x, hkv * d, dt).reshape(b, t, hkv, d)
-            v = _dense(self, "v_proj", x, hkv * d, dt).reshape(b, t, hkv, d)
-            if self.rotary:
-                positions = jnp.arange(t)
-                q = rotate_half(q, positions, a.rope_theta, d)
-                k = rotate_half(k, positions, a.rope_theta, d)
-        with jax.named_scope("core"):
-            o = causal_gqa_attention(q, k, v, scale=d ** -0.5,
-                                     window=self.window)
-        with jax.named_scope("out"):
-            return _dense(self, "o_proj", o.reshape(b, t, hq * d),
-                          a.hidden_size, dt)
 
 
 class Router(nn.Module):

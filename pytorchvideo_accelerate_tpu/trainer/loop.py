@@ -83,7 +83,7 @@ from pytorchvideo_accelerate_tpu.trainer.checkpoint import (
 from pytorchvideo_accelerate_tpu.trainer.metrics import MeanLoss, SumMetrics
 from pytorchvideo_accelerate_tpu.trainer.optim import build_lr_schedule, build_optimizer
 from pytorchvideo_accelerate_tpu.trainer.steps import (
-    LM_LOG_KEYS,
+    lm_log_values,
     make_eval_step,
     make_lm_eval_step,
     make_lm_step,
@@ -1086,7 +1086,8 @@ class Trainer:
         site_gauges = {"obs/conv_lane_fold_sites": "pva_conv_lane_fold_sites",
                        "obs/gdn_scan_kernel_sites": "pva_gdn_scan_kernel_sites",
                        "obs/attn_window_sites": "pva_attn_window_sites",
-                       "obs/attn_kernel_sites": "pva_attn_kernel_sites"}
+                       "obs/attn_kernel_sites": "pva_attn_kernel_sites",
+                       "obs/ut_steps": "pva_ut_steps"}
         loop_thread = threading.get_ident()
         tokens_per_step = (
             self.train_loader.global_batch_size * self.train_loader.accum_steps
@@ -1323,10 +1324,7 @@ class Trainer:
                                     if self.task == "next_token":
                                         # the step's own counters, same
                                         # fetch (none under accumulation)
-                                        vals.update({
-                                            name: metrics[k] for k, name
-                                            in LM_LOG_KEYS.items()
-                                            if k in metrics})
+                                        vals.update(lm_log_values(metrics))
                                     deferred.defer(vals, step=gstep)
                                 if (isinstance(self.checkpointing_steps, int)
                                         and gstep % self.checkpointing_steps

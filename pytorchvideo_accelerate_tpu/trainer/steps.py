@@ -506,6 +506,15 @@ LM_LOG_KEYS = {
 }
 
 
+def lm_log_values(metrics: dict) -> dict:
+    """What of the next-token step's metrics the deferred logger takes: the
+    `LM_LOG_KEYS` the step put out, and a looped model's `ut_*` as they are."""
+    vals = {name: metrics[k] for k, name in LM_LOG_KEYS.items()
+            if k in metrics}
+    vals.update({k: v for k, v in metrics.items() if k.startswith("ut_")})
+    return vals
+
+
 def _next_token_batch(batch: dict):
     """tokens (B, T) -> (inputs, targets, weights): position t is scored on
     token t + 1, the last position of a sequence on nothing."""
@@ -539,7 +548,14 @@ def make_lm_step(
     `moe_expert_rows_mean` (the fullest held expert's rows and the mean, the
     layer where the ratio is worst), and the two ratios a log window reports,
     `moe_local_pair_share` (pairs a token a layer) and
-    `moe_expert_load_max_over_mean`."""
+    `moe_expert_load_max_over_mean`. A model without experts returns no
+    `expert_rows`: its step puts out `moe_local_pairs` 0 (no pair was computed)
+    and none of the other `moe_*`. A looped model (models/ouro.py) returns
+    `ut`, sums over the scored positions by pass: its step puts out
+    `ut_exit_mass_<t>` (the mean share of positions' exit distribution on pass
+    t), `ut_expected_steps` (the mean of sum_t t p_t) and `ut_loss_<t>` (pass
+    t's mean cross-entropy). The gauge `pva_ut_steps` says, while the step is
+    traced, how many times it runs the layer stack (1 without a loop)."""
 
     def forward_loss(params, batch_stats, batch, key):
         del key  # no dropout, no mask to draw
@@ -549,18 +565,36 @@ def make_lm_step(
                               weights=weights, train=True)
         count = out["count"]
         loss = out["loss_sum"] / jnp.maximum(count, 1.0)
-        rows = out["expert_rows"].astype(jnp.float32)     # (layers, held)
         positions = jnp.float32(tokens.size)
-        ratio = rows.max(axis=1) / jnp.maximum(rows.mean(axis=1), 1e-9)
-        worst = jnp.argmax(ratio)
-        outputs = {
-            "tokens": positions,
-            "moe_local_pairs": rows.sum(),
-            "moe_expert_rows_max": rows.max(axis=1)[worst],
-            "moe_expert_rows_mean": rows.mean(axis=1)[worst],
-            "moe_local_pair_share": rows.sum() / (positions * rows.shape[0]),
-            "moe_expert_load_max_over_mean": ratio[worst],
-        }
+        if "expert_rows" in out:
+            rows = out["expert_rows"].astype(jnp.float32)     # (layers, held)
+            ratio = rows.max(axis=1) / jnp.maximum(rows.mean(axis=1), 1e-9)
+            worst = jnp.argmax(ratio)
+            outputs = {
+                "tokens": positions,
+                "moe_local_pairs": rows.sum(),
+                "moe_expert_rows_max": rows.max(axis=1)[worst],
+                "moe_expert_rows_mean": rows.mean(axis=1)[worst],
+                "moe_local_pair_share": rows.sum() / (positions * rows.shape[0]),
+                "moe_expert_load_max_over_mean": ratio[worst],
+            }
+        else:
+            outputs = {"tokens": positions,
+                       "moe_local_pairs": jnp.zeros((), jnp.float32)}
+        ut = out.get("ut")
+        passes = 1 if ut is None else ut["exit_mass"].shape[0]
+        get_registry().gauge(
+            "pva_ut_steps",
+            "times the traced next-token step runs its layer stack "
+            "(models/ouro.py total_ut_steps; 1 without a loop)").set(passes)
+        if ut is not None:
+            scored = jnp.maximum(count, 1.0)
+            mass, ce = ut["exit_mass"] / scored, ut["loss"] / scored
+            for t in range(passes):
+                outputs[f"ut_exit_mass_{t + 1}"] = mass[t]
+                outputs[f"ut_loss_{t + 1}"] = ce[t]
+            outputs["ut_expected_steps"] = jnp.dot(
+                mass, jnp.arange(1, passes + 1, dtype=jnp.float32))
         return loss, (batch_stats, out["correct"], count, outputs)
 
     grad_fn = jax.value_and_grad(forward_loss, has_aux=True)

@@ -119,10 +119,12 @@ def _causal_case(b, t, hq, hkv, d, window, grad):
 
 # qwen3_next_80b_a3b.train_8k: 2 x 8192, 16 query heads on 2 key-value heads
 # of 256; smallthinker_21b_a3b.train_16k: 1 x 16,384, 28 on 4 of 128, its
-# full layer and its three under the 4096-token band
+# full layer and its three under the 4096-token band; ouro_2_6b.train_4k:
+# 1 x 4096, 16 on 16 of 128 (groups of one)
 _CAUSAL = {"causal_8k_heads_of_256": (2, 8192, 16, 2, 256, None),
            "causal_16k_groups_of_7": (1, 16384, 28, 4, 128, None),
-           "window_16k_groups_of_7": (1, 16384, 28, 4, 128, 4096)}
+           "window_16k_groups_of_7": (1, 16384, 28, 4, 128, 4096),
+           "causal_4k_groups_of_one": (1, 4096, 16, 16, 128, None)}
 
 # name -> (function, dtype -> [(shape, dtype), ...]); shapes are the
 # real model shapes, plus one stage of x3d_s
@@ -260,6 +262,7 @@ def test_gated_delta_kernels_sit_under_the_scan_scope(v5e, monkeypatch):
     ("qwen3_next", "attn/core/"),
     ("smallthinker_full", "attn/core/"),
     ("smallthinker_window", "swa/core/"),
+    ("ouro", "attn/core/"),
 ])
 def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
                                                     scope):
@@ -275,7 +278,7 @@ def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
 
     import flax.linen as nn
 
-    from pytorchvideo_accelerate_tpu.models import qwen3_next, smallthinker
+    from pytorchvideo_accelerate_tpu.models import ouro, qwen3_next, smallthinker
 
     monkeypatch.setattr(attention, "takes_kernel", lambda: True)
     monkeypatch.setattr(attention, "_interpret", lambda: False)
@@ -284,6 +287,10 @@ def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
             qwen3_next.Qwen3NextArch(), jnp.bfloat16, "full_attention",
             name="mixer_3")
         x_shape, window = (2, 8192, 2048), None
+    elif family == "ouro":   # one layer execution: the loop's remat unit
+        layer = nn.remat(ouro._Layer)(ouro.OuroArch(), jnp.bfloat16,
+                                      name="layer_0")
+        x_shape, window = (1, 4096, 2048), None
     else:
         arch = smallthinker.SmallThinkerArch()
         window = arch.sliding_window_size if family.endswith("window") else None
