@@ -134,9 +134,11 @@ def next_token_loss(hidden, head_kernel, targets, weight, block: int):
     return loss, correct
 
 
-def lm_outputs(x, head, targets, weights, loss_block: int, rows):
+def lm_outputs(x, head, targets, weights, loss_block: int, shares):
     """What a token model returns after its last norm: the logits (B, T, V)
-    in float32 without `targets`, else the dict `make_lm_step` reads."""
+    in float32 without `targets`, else the dict `make_lm_step` reads.
+    `shares`: each mixture layer's (rows by held expert, whether they fit
+    the tight row buffers), as `ops/moe.py` `expert_share` returns them."""
     if targets is None:
         with jax.named_scope("lm_head"):
             return f32_island(jnp.dot(x, head.astype(x.dtype)))
@@ -148,7 +150,8 @@ def lm_outputs(x, head, targets, weights, loss_block: int, rows):
         weights.reshape(b * t), loss_block)
     return {"loss_sum": loss_sum, "correct": correct,
             "count": weights.sum(),
-            "expert_rows": jnp.stack(rows)}      # (layers, held)
+            "expert_rows": jnp.stack([r for r, _ in shares]),  # (layers, held)
+            "expert_tight": jnp.stack([t for _, t in shares])}  # (layers,)
 
 
 def exit_weighted_loss(hidden, head_kernel, gate_kernel, gate_bias, targets,

@@ -177,7 +177,8 @@ class GatedAttention(nn.Module):
 
 class SparseMoe(nn.Module):
     """The routed experts held here plus the shared expert; returns
-    (y, rows (held,)): the tokens each held expert computed."""
+    (y, (rows (held,), tight)): the tokens each held expert computed, and
+    whether they fit the tight row buffers (`expert_share`)."""
 
     arch: Qwen3NextArch
     dtype: Any
@@ -195,8 +196,8 @@ class SparseMoe(nn.Module):
         w_gate = self.param("w_gate", _normal(), (a.held, d, f))
         w_up = self.param("w_up", _normal(), (a.held, d, f))
         w_down = self.param("w_down", _normal(), (a.held, f, d))
-        y, rows = expert_share(flat, weights, experts, w_gate, w_up, w_down,
-                               a.expert_offset, a.num_experts)
+        y, rows, tight = expert_share(flat, weights, experts, w_gate, w_up,
+                                      w_down, a.expert_offset, a.num_experts)
         with jax.named_scope("shared"):
             fs = a.shared_expert_intermediate_size
             hidden = jax.nn.silu(_dense(self, "shared_gate_proj", flat, fs, dt)) \
@@ -205,7 +206,7 @@ class SparseMoe(nn.Module):
             gate = jax.nn.sigmoid(f32_island(
                 _dense(self, "shared_expert_gate", flat, 1, dt)))
             y = y + shared * gate.astype(dt)
-        return y.reshape(b, t, d), rows
+        return y.reshape(b, t, d), (rows, tight)
 
 
 class _Mixer(nn.Module):
@@ -232,8 +233,8 @@ class _Mixture(nn.Module):
     @nn.compact
     def __call__(self, h):
         normed = ZeroCentredNorm(self.arch.rms_norm_eps, name="post_norm")(h)
-        y, rows = SparseMoe(self.arch, self.dtype, name="moe")(normed)
-        return h + y, rows
+        y, share = SparseMoe(self.arch, self.dtype, name="moe")(normed)
+        return h + y, share
 
 
 class Qwen3Next(nn.Module):
@@ -251,11 +252,11 @@ class Qwen3Next(nn.Module):
         x = jnp.take(embed, tokens, axis=0).astype(self.dtype)
         mixer_cls = nn.remat(_Mixer) if self.remat else _Mixer
         mixture_cls = nn.remat(_Mixture) if self.remat else _Mixture
-        rows = []
+        shares = []
         for i in range(a.num_hidden_layers):
             x = mixer_cls(a, self.dtype, a.layer_type(i), name=f"mixer_{i}")(x)
-            x, r = mixture_cls(a, self.dtype, name=f"mixture_{i}")(x)
-            rows.append(r)
+            x, share = mixture_cls(a, self.dtype, name=f"mixture_{i}")(x)
+            shares.append(share)
         x = ZeroCentredNorm(a.rms_norm_eps, name="final_norm")(x)
         head = self.param("lm_head", _normal(), (a.hidden_size, a.vocab_size))
-        return lm_outputs(x, head, targets, weights, self.loss_block, rows)
+        return lm_outputs(x, head, targets, weights, self.loss_block, shares)

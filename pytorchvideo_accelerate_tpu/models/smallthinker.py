@@ -105,7 +105,8 @@ class Router(nn.Module):
 
 class Experts(nn.Module):
     """The routed ReGLU experts held here, given the routing; returns
-    (y, rows (held,)): the tokens each held expert computed."""
+    (y, (rows (held,), tight)): the tokens each held expert computed, and
+    whether they fit the tight row buffers (`expert_share`)."""
 
     arch: SmallThinkerArch
     dtype: Any
@@ -118,11 +119,11 @@ class Experts(nn.Module):
         w_gate = self.param("w_gate", _normal(), (a.held, d, f))
         w_up = self.param("w_up", _normal(), (a.held, d, f))
         w_down = self.param("w_down", _normal(), (a.held, f, d))
-        y, rows = expert_share(x.reshape(b * t, d), weights, experts, w_gate,
-                               w_up, w_down, a.expert_offset,
-                               a.moe_num_primary_experts,
-                               activation=jax.nn.relu)
-        return y.reshape(b, t, d), rows
+        y, rows, tight = expert_share(x.reshape(b * t, d), weights, experts,
+                                      w_gate, w_up, w_down, a.expert_offset,
+                                      a.moe_num_primary_experts,
+                                      activation=jax.nn.relu)
+        return y.reshape(b, t, d), (rows, tight)
 
 
 class _Mixer(nn.Module):
@@ -152,9 +153,9 @@ class _Mixture(nn.Module):
     @nn.compact
     def __call__(self, h, weights, experts):
         normed = Norm(self.arch.rms_norm_eps, name="post_norm")(h)
-        y, rows = Experts(self.arch, self.dtype, name="moe")(normed, weights,
-                                                             experts)
-        return h + y, rows
+        y, share = Experts(self.arch, self.dtype, name="moe")(normed, weights,
+                                                              experts)
+        return h + y, share
 
 
 class SmallThinker(nn.Module):
@@ -172,13 +173,14 @@ class SmallThinker(nn.Module):
         x = jnp.take(embed, tokens, axis=0).astype(self.dtype)
         mixer_cls = nn.remat(_Mixer) if self.remat else _Mixer
         mixture_cls = nn.remat(_Mixture) if self.remat else _Mixture
-        rows = []
+        shares = []
         for i in range(a.num_hidden_layers):
             window = a.sliding_window_size if a.sliding_window_layout[i] else None
             x, w_k, e_k = mixer_cls(a, self.dtype, bool(a.rope_layout[i]),
                                     window, name=f"mixer_{i}")(x)
-            x, r = mixture_cls(a, self.dtype, name=f"mixture_{i}")(x, w_k, e_k)
-            rows.append(r)
+            x, share = mixture_cls(a, self.dtype,
+                                   name=f"mixture_{i}")(x, w_k, e_k)
+            shares.append(share)
         x = Norm(a.rms_norm_eps, name="final_norm")(x)
         head = self.param("lm_head", _normal(), (a.hidden_size, a.vocab_size))
-        return lm_outputs(x, head, targets, weights, self.loss_block, rows)
+        return lm_outputs(x, head, targets, weights, self.loss_block, shares)

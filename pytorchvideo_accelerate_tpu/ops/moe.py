@@ -22,11 +22,16 @@ Shapes are static, so the row buffers need a bound. The worst case (every
 pair local: tokens x min(k, held) rows) is `num_experts / held` times the
 expected load, and buffers of that size cost that much memory and traffic in
 every step. So the layer looks at the count it has just made: where the local
-pairs fit `SLACK` times the expected load (they do, short of a `SLACK`-fold
-imbalance) all tokens go through buffers of that many rows at once; where
-they do not, the tokens go through the same function in chunks small enough
-that a chunk's worst case fits the same buffers. Either way every pair is
-computed; only the second way is slower.
+pairs fit `SLACK` (2) times the expected load, in whole tiles (`buffer_rows`;
+they do, short of a 2-fold skew toward the held experts), all tokens go
+through buffers of that many rows at once; where they do not, the tokens go
+through the same function in chunks small enough that a chunk's worst case
+fits the same buffers. One `lax.cond` on the count chooses, and says which
+(`expert_share`'s third output); each way is rematerialised in the backward
+pass (`_way`). Either way every pair is computed with the same products and
+sums; only the second way is slower. Where twice the expected load reaches
+the worst case (half the experts held or more) there is one way and no
+`cond`.
 
 Moving rows is gathers, never a scatter: the rows leave in sorted order
 (`x[token of row]`) and come back by each token collecting its k rows
@@ -34,8 +39,6 @@ Moving rows is gathers, never a scatter: the rows leave in sorted order
 """
 
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -57,8 +60,17 @@ def route(x, router_kernel, top_k: int, norm_topk: bool = True):
     return weights, experts.astype(jnp.int32)
 
 
-SLACK = 4    # row buffers hold this many times the expected local pairs
+SLACK = 2    # row buffers hold this many times the expected local pairs
 ROW_TILE = 512  # buffers are whole tiles of the grouped product
+
+
+def buffer_rows(n: int, top_k: int, held: int, num_experts: int) -> int:
+    """The row buffers a layer of `n` tokens takes where its local pairs fit
+    them: `SLACK` times the expected local pairs in whole tiles, at most the
+    worst case (every pair local)."""
+    worst = n * min(top_k, held)
+    expected = n * top_k * held / num_experts
+    return min(worst, -(-int(SLACK * expected) // ROW_TILE) * ROW_TILE)
 
 
 @jax.custom_vjp
@@ -137,16 +149,17 @@ def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
     `w_down` (held, F, D) are the experts [expert_offset, expert_offset +
     held); `activation` is the gate's (SiLU: SwiGLU experts; ReLU: ReGLU).
     Returns (y (N, D) in x's dtype, rows (held,) int32: how many tokens each
-    held expert computed)."""
+    held expert computed, tight: whether the local pairs fit the buffers of
+    `buffer_rows`; where not, the tokens went through in chunks)."""
     n, _ = x.shape
     top_k = experts.shape[-1]
     held = w_gate.shape[0]
     worst = n * min(top_k, held)
-    expected = n * top_k * held / num_experts
-    bound = min(worst, -(-int(SLACK * expected) // ROW_TILE) * ROW_TILE)
+    bound = buffer_rows(n, top_k, held, num_experts)
     args = (w_gate, w_up, w_down, expert_offset)
     if bound == worst:
-        return _share(x, weights, experts, *args, bound, activation)
+        return (*_share(x, weights, experts, *args, bound, activation),
+                jnp.bool_(True))
     chunks = -(-worst // bound)
     per = -(-n // chunks)                      # tokens a chunk; its worst case
     chunk_bound = per * min(top_k, held)       # fits `bound` rows
@@ -163,7 +176,23 @@ def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
         return y.reshape(chunks * per, -1)[:n], rows.sum(axis=0)
 
     local = experts - expert_offset
-    count = jnp.sum((local >= 0) & (local < held))
-    return lax.cond(count <= bound,
-                    lambda *a: _share(*a, *args, bound, activation), in_chunks,
-                    x, weights, experts)
+    tight = jnp.sum((local >= 0) & (local < held)) <= bound
+    y, rows = lax.cond(tight,
+                       _way(lambda *a: _share(*a, *args, bound, activation)),
+                       _way(in_chunks), x, weights, experts)
+    return y, rows, tight
+
+
+def _way(way):
+    """A branch of `expert_share`'s `cond`, rematerialised: the backward pass
+    keeps the cond's inputs, where it would keep the taken way's rows (and
+    zeros for the other's): 15 ms a step and 1.8 GB of peak memory in
+    `smallthinker_21b_a3b.train_16k`, 4 ms in `qwen3_next_80b_a3b.train_8k`
+    (PERF.md §6, PR 37), and no third forward run under the layer's remat.
+    Its ops open `moe/` again after the cond's `cond/branch_<i>_fun/`, so
+    that a trace's scope tables find `moe/dispatch/`, `moe/experts/`,
+    `moe/combine/` whichever way ran."""
+    def scoped(*operands):
+        with jax.named_scope("moe"):
+            return way(*operands)
+    return jax.checkpoint(scoped)

@@ -503,6 +503,7 @@ LM_LOG_KEYS = {
     "moe_expert_rows_mean": "moe_expert_rows_mean",
     "moe_local_pair_share": "obs/moe_local_pair_share",
     "moe_expert_load_max_over_mean": "obs/moe_expert_load_max_over_mean",
+    "moe_tight_buffer_share": "obs/moe_tight_buffer_share",
 }
 
 
@@ -548,7 +549,10 @@ def make_lm_step(
     `moe_expert_rows_mean` (the fullest held expert's rows and the mean, the
     layer where the ratio is worst), and the two ratios a log window reports,
     `moe_local_pair_share` (pairs a token a layer) and
-    `moe_expert_load_max_over_mean`. A model without experts returns no
+    `moe_expert_load_max_over_mean`; and `moe_tight_buffer_share`, the share
+    of the step's mixture layers that took the tight row buffers
+    (`ops/moe.py` `expert_share`: the way a layer takes unless its routing
+    is skewed toward the held experts; the others went through in chunks). A model without experts returns no
     `expert_rows`: its step puts out `moe_local_pairs` 0 (no pair was computed)
     and none of the other `moe_*`. A looped model (models/ouro.py) returns
     `ut`, sums over the scored positions by pass: its step puts out
@@ -577,6 +581,8 @@ def make_lm_step(
                 "moe_expert_rows_mean": rows.mean(axis=1)[worst],
                 "moe_local_pair_share": rows.sum() / (positions * rows.shape[0]),
                 "moe_expert_load_max_over_mean": ratio[worst],
+                "moe_tight_buffer_share": jnp.mean(
+                    out["expert_tight"].astype(jnp.float32)),
             }
         else:
             outputs = {"tokens": positions,

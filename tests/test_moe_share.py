@@ -39,7 +39,7 @@ def _program_share(p, x, offset, held, experts=None):
         chosen = experts
     return moe.expert_share(flat, weights, chosen, p["w_gate"][offset:offset + held],
                             p["w_up"][offset:offset + held],
-                            p["w_down"][offset:offset + held], offset, E)
+                            p["w_down"][offset:offset + held], offset, E)[:2]
 
 
 def _shared_part(p, x):
@@ -165,7 +165,7 @@ def test_relu_shares_add_up_to_the_uncut_reference_layer(layer, shares):
     total, rows = jnp.zeros_like(flat), []
     for s in range(shares):
         cut = slice(s * held, (s + 1) * held)
-        y, r = moe.expert_share(flat, weights, chosen, p["w_gate"][cut],
+        y, r, _ = moe.expert_share(flat, weights, chosen, p["w_gate"][cut],
                                 p["w_up"][cut], p["w_down"][cut], s * held, E,
                                 activation=jax.nn.relu)
         total = total + y
@@ -179,7 +179,138 @@ def test_relu_shares_add_up_to_the_uncut_reference_layer(layer, shares):
     assert bool(jnp.all(jnp.concatenate(rows) == rows_whole))
     assert int(jnp.concatenate(rows).sum()) == N * K  # every pair, once
     # and the gate is the ReLU: the SiLU layer is another layer
-    silu, _ = moe.expert_share(flat, weights, chosen, p["w_gate"], p["w_up"],
-                               p["w_down"], 0, E)
+    silu, _, _ = moe.expert_share(flat, weights, chosen, p["w_gate"],
+                                  p["w_up"], p["w_down"], 0, E)
     if shares == 1:
         assert float(jnp.abs(silu - whole).max()) > 1e-2
+
+
+# --- the row buffers a layer takes, by the step's own count (PR 37)
+
+@pytest.mark.parametrize("geometry,rows", [
+    pytest.param((16384, 6, 16, 64), 49152, id="smallthinker_cell"),
+    pytest.param((16384, 10, 32, 512), 20480, id="qwen3_next_cell"),
+    pytest.param((128, 2, 2, 8), 256, id="toy_worst_case_alone"),
+])
+def test_buffer_rows_at_the_cells_shapes(geometry, rows):
+    """Twice the expected local pairs in whole tiles, at most the worst case:
+    half of the worst case where a quarter of the experts is held (before PR
+    37 the buffers were the worst case there, with no `cond`), an eighth of
+    it at 32 of 512; a toy with a quarter held and 128 tokens reaches the
+    worst case, and takes no `cond`."""
+    assert moe.buffer_rows(*geometry) == rows
+
+
+def _planted(local_counts, k, offset, held, num_experts):
+    """(N, k) experts: token i chooses `local_counts[i]` held experts and the
+    rest outside the share, all k distinct."""
+    rows = []
+    for i, n_local in enumerate(local_counts):
+        outside = [e for e in range(num_experts)
+                   if not offset <= e < offset + held]
+        rows.append([offset + (i + j) % held for j in range(n_local)]
+                    + [outside[(i + j) % len(outside)]
+                       for j in range(k - n_local)])
+    return jnp.asarray(rows, jnp.int32)
+
+
+# (top-k, held, row tile, local pairs, tight buffers taken): a quarter of 16
+# experts held, top-4, is the geometry that had no `cond` before (buffers of
+# 512 rows, worst case 768: two chunks); 2 of 16 held, top-2, under a 16-row
+# tile (buffers of 96, worst case 384: four chunks)
+PATHS = [
+    pytest.param(4, 4, 512, 512, True, id="quarter_held_at_the_tight_size"),
+    pytest.param(4, 4, 512, 513, False, id="quarter_held_one_pair_past_it"),
+    pytest.param(4, 4, 512, 768, False, id="quarter_held_worst_case"),
+    pytest.param(2, 2, 16, 96, True, id="eighth_held_at_the_tight_size"),
+    pytest.param(2, 2, 16, 97, False, id="eighth_held_one_pair_past_it"),
+    pytest.param(2, 2, 16, 193, False, id="eighth_held_twice_the_tight_size"),
+    pytest.param(2, 2, 16, 384, False, id="eighth_held_worst_case"),
+]
+
+
+@pytest.mark.parametrize("k,held,tile,local,tight", PATHS)
+def test_both_ways_are_the_uncut_reference_layer(layer, monkeypatch, k, held,
+                                                 tile, local, tight):
+    """Routers planted so that a share's local pairs land at the tight
+    buffers' size, one past it, or at the worst case: whichever way the count
+    sends the layer (the tight buffers or the chunks, as its third output
+    says), its y, rows and gradients (x, routing weights, the three expert
+    matrices) are the plain reference's (`benchmarks/reference/
+    smallthinker.py` `mixture`, a loop over the held experts)."""
+    from benchmarks.reference import smallthinker as st
+
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    p, flat = layer["p"], layer["x"].reshape(-1, D)
+    offset = 4
+    counts = [min(k, max(0, local - k * i)) for i in range(N)]
+    experts = _planted(counts, k, offset, held, E)
+    assert int(((experts >= offset) & (experts < offset + held)).sum()) == local
+    weights, _ = moe.route(flat, p["router"], k)
+    cut = {name: p[name][offset:offset + held]
+           for name in ("w_gate", "w_up", "w_down")}
+    arch = {**_reglu_arch(held, offset), "moe_num_active_primary_experts": k}
+
+    def program(x, weights, mats):
+        y, rows, took = moe.expert_share(x, weights, experts, mats["w_gate"],
+                                         mats["w_up"], mats["w_down"], offset,
+                                         E, activation=jax.nn.relu)
+        return jnp.sum(jnp.sin(y)), (y, rows, took)
+
+    def reference(x, weights, mats):
+        y, rows = st.mixture(mats, x, weights, experts, arch, None, False,
+                             False, None)
+        return jnp.sum(jnp.sin(y)), (y, rows)
+
+    grad = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2),  # noqa: E731
+                                        has_aux=True)(flat, weights, cut)
+    (_, (y, rows, took)), g = grad(program)
+    (_, (y_ref, rows_ref)), g_ref = grad(reference)
+    assert bool(took) == tight
+    assert rows.tolist() == rows_ref.tolist() and int(rows.sum()) == local
+    assert float(jnp.abs(y - y_ref).max()) < 1e-5
+    for got, want in zip(jax.tree.leaves(g), jax.tree.leaves(g_ref)):
+        scale = max(float(jnp.abs(want).max()), 1e-6)
+        assert float(jnp.abs(got - want).max()) < 1e-5 * scale
+
+
+@pytest.mark.parametrize("first_of,want", [
+    # token i chooses experts 2i, 2i + 1 (mod 8): a quarter of its pairs local
+    pytest.param(lambda i: (2 * i) % 8, 1.0, id="balanced_routing"),
+    pytest.param(lambda i: 0 * i, 0.0, id="every_token_to_the_held_experts"),
+])
+def test_step_counts_the_layers_that_fit_the_tight_buffers(monkeypatch,
+                                                           first_of, want):
+    """`obs/moe_tight_buffer_share` through `make_lm_step` on the toy
+    SmallThinker (8 experts, top-2, experts 0 and 1 held; 8 x 64 tokens:
+    tight buffers of 512 rows, worst case 1024) under planted routers: every
+    layer fits where the pairs spread over all experts, none where every
+    token goes to the two held experts. A model without experts puts out no
+    such share (tests/test_ouro.py `test_step_outputs_by_family`)."""
+    import optax
+
+    from pytorchvideo_accelerate_tpu.config import MeshConfig, ModelConfig
+    from pytorchvideo_accelerate_tpu.models import create_model, smallthinker
+    from pytorchvideo_accelerate_tpu.parallel.mesh import make_train_mesh
+    from pytorchvideo_accelerate_tpu.trainer.steps import (lm_log_values,
+                                                           make_lm_step)
+    from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
+
+    def planted(x, kernel, top_k, norm_topk=True):
+        weights, _ = moe.route(x, kernel, top_k, norm_topk)
+        first = first_of(jnp.arange(x.shape[0], dtype=jnp.int32))
+        return weights, first[:, None] + jnp.arange(top_k, dtype=jnp.int32)
+
+    monkeypatch.setattr(smallthinker, "route", planted)
+    model = create_model(ModelConfig(name="smallthinker_t", experts_held=2),
+                         "fp32")
+    tx = optax.adamw(1e-3)
+    mesh = make_train_mesh(MeshConfig(data=len(jax.devices())))
+    tokens = jax.random.randint(jax.random.key(1), (8, 64), 0, 256)
+    assert moe.buffer_rows(tokens.size, 2, 2, 8) == 512
+    variables = model.init(jax.random.key(0), tokens[:1])
+    state = TrainState.create(variables["params"], {}, tx)
+    _, metrics = make_lm_step(model, tx, mesh)(state, {"tokens": tokens},
+                                               jax.random.key(0))
+    assert float(metrics["moe_tight_buffer_share"]) == want
+    assert float(lm_log_values(metrics)["obs/moe_tight_buffer_share"]) == want

@@ -261,7 +261,7 @@ def _step_metric_keys(name, **model_kw):
 
 COMMON = {"loss", "grad_norm", "accuracy", "tokens", "moe_local_pairs"}
 MOE = {"moe_expert_rows_max", "moe_expert_rows_mean", "moe_local_pair_share",
-       "moe_expert_load_max_over_mean"}
+       "moe_expert_load_max_over_mean", "moe_tight_buffer_share"}
 
 
 @pytest.mark.parametrize("name,kw,want", [
