@@ -15,8 +15,10 @@ import jax.numpy as jnp
 
 from pytorchvideo_accelerate_tpu.ops.attention import (
     causal_gqa_attention,
+    keeping_kernel_results,
     rotate_half,
 )
+from pytorchvideo_accelerate_tpu.ops.pallas_attention import KEPT_NAMES
 from pytorchvideo_accelerate_tpu.precision import end_island, f32_island
 
 
@@ -42,6 +44,30 @@ class Norm(nn.Module):
     def __call__(self, x):
         w = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return end_island(rms(x, self.eps) * w, x.dtype)
+
+
+# a remat unit's policy: keep the flash forward's `o` and `lse`, nothing else
+KEEP_ATTENTION = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+
+
+def remat_keeping_attention(cls):
+    """`nn.remat(cls)` under `KEEP_ATTENTION`: the backward pass recomputes
+    the unit's norms and projections but not the attention's forward kernel,
+    which runs once a unit execution. Where the kernels are not taken (the
+    CPU, heads not a multiple of 128 wide) no value carries the names, and
+    the unit keeps nothing, as a plain `nn.remat` does (docs/KERNELS.md).
+    The unit is traced inside `keeping_kernel_results`, for the kept-sites
+    gauge."""
+
+    class Unit(cls):
+        # not wrapped: flax wraps `cls.__call__` once, as under a plain
+        # `nn.remat`, so the unit's scopes are the same
+        @nn.nowrap
+        def __call__(self, *args, **kwargs):
+            with keeping_kernel_results():
+                return super().__call__(*args, **kwargs)
+
+    return nn.remat(Unit, policy=KEEP_ATTENTION)
 
 
 class Attention(nn.Module):

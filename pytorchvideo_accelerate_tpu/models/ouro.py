@@ -57,6 +57,7 @@ from pytorchvideo_accelerate_tpu.models.lm_common import (
     _dense,
     _normal,
     looped_lm_outputs,
+    remat_keeping_attention,
 )
 
 
@@ -120,7 +121,7 @@ class _Stack(nn.Module):
     @nn.compact
     def __call__(self, x):
         a = self.arch
-        layer_cls = nn.remat(_Layer) if self.remat else _Layer
+        layer_cls = remat_keeping_attention(_Layer) if self.remat else _Layer
         for i in range(a.num_hidden_layers):
             x = layer_cls(a, self.dtype, name=f"layer_{i}")(x)
         x = Norm(a.rms_norm_eps, name="final_norm")(x)

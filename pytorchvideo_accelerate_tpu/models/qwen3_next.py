@@ -39,6 +39,7 @@ from pytorchvideo_accelerate_tpu.models.lm_common import (
     _normal,
     held_experts,
     lm_outputs,
+    remat_keeping_attention,
     rms,
 )
 from pytorchvideo_accelerate_tpu.ops.attention import (
@@ -250,7 +251,7 @@ class Qwen3Next(nn.Module):
         a = self.arch
         embed = self.param("embed", _normal(), (a.vocab_size, a.hidden_size))
         x = jnp.take(embed, tokens, axis=0).astype(self.dtype)
-        mixer_cls = nn.remat(_Mixer) if self.remat else _Mixer
+        mixer_cls = remat_keeping_attention(_Mixer) if self.remat else _Mixer
         mixture_cls = nn.remat(_Mixture) if self.remat else _Mixture
         shares = []
         for i in range(a.num_hidden_layers):

@@ -49,6 +49,7 @@ from pytorchvideo_accelerate_tpu.models.lm_common import (
     _normal,
     held_experts,
     lm_outputs,
+    remat_keeping_attention,
 )
 from pytorchvideo_accelerate_tpu.ops.moe import expert_share, route
 
@@ -171,7 +172,7 @@ class SmallThinker(nn.Module):
         a = self.arch
         embed = self.param("embed", _normal(), (a.vocab_size, a.hidden_size))
         x = jnp.take(embed, tokens, axis=0).astype(self.dtype)
-        mixer_cls = nn.remat(_Mixer) if self.remat else _Mixer
+        mixer_cls = remat_keeping_attention(_Mixer) if self.remat else _Mixer
         mixture_cls = nn.remat(_Mixture) if self.remat else _Mixture
         shares = []
         for i in range(a.num_hidden_layers):

@@ -84,6 +84,8 @@ _window_sites: contextvars.ContextVar = contextvars.ContextVar(
     "pva_attn_window_sites", default=None)
 _kernel_sites: contextvars.ContextVar = contextvars.ContextVar(
     "pva_attn_kernel_sites", default=None)
+_keeping: contextvars.ContextVar = contextvars.ContextVar(
+    "pva_attn_keeping", default=False)
 
 
 @contextlib.contextmanager
@@ -105,10 +107,24 @@ def count_window_sites():
 
 def count_kernel_sites():
     """The same for every `causal_gqa_attention` call that took the Pallas
-    flash kernels, one entry (q's shape, window): the lowering is static, so
-    its engagement is a fact of the trace (the `pva_attn_kernel_sites`
-    gauge)."""
+    flash kernels, one entry (q's shape, window, kept): the lowering is
+    static, so its engagement is a fact of the trace (the
+    `pva_attn_kernel_sites` gauge). `kept`: traced inside a remat unit whose
+    policy keeps the forward's `o` and `lse` (`keeping_kernel_results`; the
+    `pva_attn_kept_sites` gauge)."""
     return _collect(_kernel_sites)
+
+
+@contextlib.contextmanager
+def keeping_kernel_results() -> Iterator[None]:
+    """Around the trace of a remat unit whose policy keeps the names of
+    `pallas_attention.KEPT_NAMES` (models/lm_common.py): the kernel sites
+    traced inside are counted as kept."""
+    token = _keeping.set(True)
+    try:
+        yield
+    finally:
+        _keeping.reset(token)
 
 
 def takes_kernel() -> bool:
@@ -166,7 +182,7 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
     if takes_kernel() and kernel_shapes(t, d):
         sites = _kernel_sites.get()
         if sites is not None:
-            sites.append((q.shape, window))
+            sites.append((q.shape, window, _keeping.get()))
         return pallas_attention.causal_flash_attention(
             q, k, v, scale, window, _interpret())
     return blocked_causal_attention(q, k, v, scale, block_q, window)

@@ -43,7 +43,10 @@ log(sum)` a row, float32, (B, key heads, group, T); both backward kernels
 recompute `p = exp(s - lse)` a tile; `delta = rowsum(dO * O)` is an XLA
 reduction beside them. With `ds = p * (dO V^T - delta)`: dq += ds K, dk +=
 ds^T Q, dv += p^T dO; the softmax's scale multiplies dq's and dk's sums once,
-not every `ds`.
+not every `ds`. The forward runs outside the `custom_vjp`, whose operands
+are q, k, v and the forward's `o` and `lse`: the token models' remat units
+keep those two by name and do not run the forward again (`KEPT_NAMES`,
+docs/KERNELS.md).
 
 **Precision.** Operands enter the MXU in their own dtype (bfloat16 under a
 bfloat16 policy; float32 operands multiply at `Precision.HIGHEST`, so a
@@ -69,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -359,22 +363,43 @@ class _Calls:
         return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash(q, k, v, spec: Spec):
-    return _Calls(q, k, spec).forward(q, k, v)[0]
+# the names a remat policy keeps the forward's `o` and `lse` by
+# (models/lm_common.py `remat_keeping_attention`; docs/KERNELS.md)
+KEPT_NAMES = ("pva_attn_o", "pva_attn_lse")
 
 
-def _flash_fwd(q, k, v, spec):
-    o, lse = _Calls(q, k, spec).forward(q, k, v)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _attended(q, k, v, o, lse, spec: Spec):
+    """`o`, the forward kernel's output over q, k and v, as their function:
+    the backward kernels are its rule. The forward runs outside, so its `o`
+    and `lse` enter here as operands and the rule's residuals ARE the values
+    a remat policy sees: returned from a rule that ran the forward itself,
+    they would be copies the policy cannot keep (docs/KERNELS.md)."""
+    return o
+
+
+def _attended_fwd(q, k, v, o, lse, spec):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(spec, residuals, do):
+def _attended_bwd(spec, residuals, do):
     q, k, v, o, lse = residuals
-    return _Calls(q, k, spec).backward(q, k, v, o, lse, do)
+    # o and lse are functions of q, k and v, whose cotangents carry them
+    return (*_Calls(q, k, spec).backward(q, k, v, o, lse, do), None, None)
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_attended.defvjp(_attended_fwd, _attended_bwd)
+
+
+def _flash(q, k, v, spec: Spec, named: bool):
+    """The forward kernel once, differentiable through the backward pair;
+    `named`: `o` and `lse` under KEPT_NAMES, for a remat policy to keep."""
+    o, lse = _Calls(q, k, spec).forward(
+        *(lax.stop_gradient(x) for x in (q, k, v)))
+    if named:
+        o, lse = (checkpoint_name(x, name) for x, name in zip((o, lse),
+                                                              KEPT_NAMES))
+    return _attended(q, k, v, o, lse, spec)
 
 
 def _pad_rows(x, block):
@@ -411,7 +436,8 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
 
     mask = Mask(keys=nkv if nkv % block_k else None)
     out = _flash(fold(q, block_q), fold(k, block_k), fold(v, block_k),
-                 Spec(1, float(scale), block_q, block_k, mask, bool(interpret)))
+                 Spec(1, float(scale), block_q, block_k, mask, bool(interpret)),
+                 named=False)
     return out[:, :nq].reshape(B, H, nq, D).transpose(0, 2, 1, 3)
 
 
@@ -423,7 +449,8 @@ def causal_flash_attention(q, k, v, scale: float, window: Optional[int],
     The blocks are multiples of 128, one a multiple of the other; a sequence
     shorter than a block is one block of 128 times a power of two; a T the
     blocks do not divide is padded inside (a padded key lies above every real
-    query's diagonal; a padded query's row is cut off)."""
+    query's diagonal; a padded query's row is cut off). The forward's `o` and
+    `lse` carry `KEPT_NAMES`, for a remat policy to keep."""
     b, t, hq, d = q.shape
     # 128 times the least power of two that holds the sequence
     whole = LANES << max(-(-t // LANES) - 1, 0).bit_length()
@@ -431,5 +458,6 @@ def causal_flash_attention(q, k, v, scale: float, window: Optional[int],
     q, k, v = (_pad_rows(x.reshape(b, t, -1), max(block_q, block_k))
                for x in (q, k, v))
     out = _flash(q, k, v, Spec(k.shape[2] // d, float(scale), block_q, block_k,
-                               Mask(causal=True, window=window), interpret))
+                               Mask(causal=True, window=window), interpret),
+                 named=True)
     return out[:, :t].reshape(b, t, hq, d)

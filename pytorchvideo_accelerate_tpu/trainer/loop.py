@@ -1087,6 +1087,7 @@ class Trainer:
                        "obs/gdn_scan_kernel_sites": "pva_gdn_scan_kernel_sites",
                        "obs/attn_window_sites": "pva_attn_window_sites",
                        "obs/attn_kernel_sites": "pva_attn_kernel_sites",
+                       "obs/attn_kept_sites": "pva_attn_kept_sites",
                        "obs/ut_steps": "pva_ut_steps"}
         loop_thread = threading.get_ident()
         tokens_per_step = (

@@ -339,6 +339,11 @@ def _count_lowering_sites():
         "pva_attn_kernel_sites",
         "causal_gqa_attention calls of the traced train step lowered as the "
         "Pallas flash kernels (ops/pallas_attention.py)").set(len(flashes))
+    registry.gauge(
+        "pva_attn_kept_sites",
+        "of those, the calls traced inside a remat unit that keeps the "
+        "forward kernel's o and lse (models/lm_common.py)").set(
+            sum(kept for _shape, _window, kept in flashes))
 
 
 def make_train_step(

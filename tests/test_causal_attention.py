@@ -334,7 +334,7 @@ def test_kernel_reads_no_key_above_the_diagonal_or_behind_the_band(
     with kernel_forced.count_kernel_sites() as sites:
         run = jax.jit(lambda k, v: causal_gqa_attention(q, k, v, window=window))
         base = run(k, v)
-    assert sites == [(q.shape, window)]
+    assert sites == [(q.shape, window, False)]  # no remat unit keeps it
     later = run(k.at[:, 400:].add(3.0), v.at[:, 400:].add(3.0))
     assert float(jnp.abs(later[:, :400] - base[:, :400]).max()) == 0.0
     assert float(jnp.abs(later[:, 400:] - base[:, 400:]).max()) > 0.1

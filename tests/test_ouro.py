@@ -379,6 +379,7 @@ def test_three_fit_steps_reproduce_the_references_losses(tmp_path):
     once = lambda k: [v[k] for _s, v in got["logged"] if k in v]  # noqa: E731
     assert once("obs/ut_steps") == [3]
     assert once("obs/attn_kernel_sites") == [0]
+    assert once("obs/attn_kept_sites") == [0]
     assert once("obs/attn_window_sites") == [0]
 
 
@@ -419,4 +420,22 @@ def test_kernel_sites_are_counted_a_layer(params, tokens, monkeypatch):
         lambda q, k, v, scale, window, interpret: q)
     with attention.count_kernel_sites() as sites:
         jax.eval_shape(lambda: model.apply({"params": params}, tokens))
-    assert len(sites) == 2 and {w for _s, w in sites} == {None}
+    assert len(sites) == 2 and {w for _s, w, _k in sites} == {None}
+
+
+@pytest.mark.parametrize("forced", [True, False], ids=["kernel", "xla_form"])
+def test_layers_keep_the_flash_forwards_results(monkeypatch, forced):
+    """Heads 128 wide over 1024 tokens, the kernels taken as on a TPU: the
+    scan's body holds one forward kernel a layer, kept for the backward pass
+    of every pass (the parent's plain `nn.remat` ran it again in the
+    transposed scan); on the CPU's own rule the step is a plain
+    `nn.remat`'s."""
+    from kept_attention import check_units_keep_the_forward
+
+    def model():
+        return ouro.Ouro(ouro.OuroArch(
+            hidden_size=64, num_hidden_layers=2, num_attention_heads=2,
+            num_key_value_heads=2, head_dim=128, intermediate_size=96,
+            vocab_size=256, total_ut_steps=3), dtype=jnp.float32)
+
+    check_units_keep_the_forward(monkeypatch, ouro, model, forced, 2)

@@ -104,3 +104,98 @@ def test_grad_ragged_lengths():
     for got, want, name in zip(g, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
+
+
+def _old_flash(spec):
+    """The custom VJP `flash_attention` ran before the forward moved out of
+    it: the forward kernel in the rule, its `o` and `lse` as residuals."""
+    import jax
+
+    from pytorchvideo_accelerate_tpu.ops.pallas_attention import _Calls
+
+    @jax.custom_vjp
+    def flash(q, k, v):
+        return _Calls(q, k, spec).forward(q, k, v)[0]
+
+    def fwd(q, k, v):
+        o, lse = _Calls(q, k, spec).forward(q, k, v)
+        return o, (q, k, v, o, lse)
+
+    def bwd(residuals, do):
+        q, k, v, o, lse = residuals
+        return _Calls(q, k, spec).backward(q, k, v, o, lse, do)
+
+    flash.defvjp(fwd, bwd)
+    return flash
+
+
+def test_vit_output_and_gradient_unchanged_by_the_forward_outside_the_rule():
+    """`flash_attention` (the ViT trunks, ragged lengths: padded and masked)
+    against the custom VJP it had, on the same folded operands: the same
+    output and gradients, bit for bit, and no name a remat policy could
+    keep."""
+    import jax
+
+    from pytorchvideo_accelerate_tpu.ops import pallas_attention as pa
+
+    q, k, v = _qkv(B=1, Nq=50, Nk=77, H=2, D=16)
+    cot = jnp.asarray(np.random.default_rng(3).standard_normal(q.shape),
+                      jnp.float32)
+    spec = pa.Spec(1, 16 ** -0.5, 32, 32, pa.Mask(keys=77), True)
+
+    def fold(x, block):
+        x = x.transpose(0, 2, 1, 3).reshape(2, x.shape[1], 16)
+        return pa._pad_rows(x, block)
+
+    def old(q, k, v):
+        out = _old_flash(spec)(fold(q, 32), fold(k, 32), fold(v, 32))
+        return out[:, :50].reshape(1, 2, 50, 16).transpose(0, 2, 1, 3)
+
+    def new(q, k, v):
+        return flash_attention(q, k, v, block_q=32, block_k=32)
+
+    def both(fn):
+        loss = lambda *a: jnp.sum(fn(*a) * cot)  # noqa: E731
+        return (jax.jit(fn)(q, k, v),
+                *jax.jit(jax.grad(loss, (0, 1, 2)))(q, k, v))
+
+    for got, want in zip(both(new), both(old)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    grad = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(new(*a)), (0, 1, 2)))
+    assert " name[" not in str(grad(q, k, v))
+
+
+@pytest.mark.parametrize("window", [None, 100], ids=["causal", "window"])
+def test_remat_unit_keeping_o_and_lse_runs_the_forward_kernel_once(window):
+    """A `jax.checkpoint` unit around `causal_flash_attention`: under the
+    policy that keeps `KEPT_NAMES` its gradient's program holds one forward
+    kernel and one dq kernel; without a policy the unit's recomputation runs
+    the forward again. The gradients agree bit for bit: the kept `o` and
+    `lse` are the values the recomputation produced."""
+    import jax
+
+    from kept_attention import pallas_calls
+    from pytorchvideo_accelerate_tpu.ops import pallas_attention as pa
+
+    rng = np.random.default_rng(17)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 300, h, 128)), jnp.float32)
+               for h in (7, 1, 1))
+    w = jnp.asarray(rng.standard_normal((128, 128)) / 12, jnp.float32)
+    cot = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+
+    def unit(q, k, v):  # a projection before the kernel, as a layer has
+        return pa.causal_flash_attention(q @ w, k, v, 128 ** -0.5, window,
+                                         True, 128, 128)
+
+    def grads(policy):
+        kept = jax.checkpoint(unit, policy=policy)
+        return jax.grad(lambda *a: jnp.sum(kept(*a) * cot), (0, 1, 2))
+
+    keep = jax.checkpoint_policies.save_only_these_names(*pa.KEPT_NAMES)
+    for policy, forwards in ((keep, 1), (None, 2)):
+        jaxpr = jax.make_jaxpr(grads(policy))(q, k, v).jaxpr
+        assert pallas_calls(jaxpr, "pva_attn_fwd") == forwards
+        assert pallas_calls(jaxpr, "pva_attn_dq") == 1
+    for got, want in zip(jax.jit(grads(keep))(q, k, v),
+                         jax.jit(grads(None))(q, k, v)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

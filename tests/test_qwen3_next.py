@@ -230,3 +230,25 @@ def test_model_input_spec_and_share_validation():
     full = create_model(ModelConfig(name="qwen3_next_80b_a3b"), "bf16").arch
     assert (full.num_hidden_layers, full.num_experts, full.held,
             full.vocab_size) == (48, 512, 512, 151936)
+
+
+@pytest.mark.parametrize("forced", [True, False], ids=["kernel", "xla_form"])
+def test_mixers_keep_the_flash_forwards_results(monkeypatch, forced):
+    """Heads 128 wide over 1024 tokens, the kernels taken as on a TPU: the
+    period's one attention mixer runs the forward kernel once and keeps its
+    `o` and `lse` for the backward pass (the three DeltaNet mixers have
+    nothing so named); on the CPU's own rule the step is a plain
+    `nn.remat`'s."""
+    from kept_attention import check_units_keep_the_forward
+    from pytorchvideo_accelerate_tpu.models import qwen3_next
+
+    def model():
+        return qwen3_next.Qwen3Next(qwen3_next.Qwen3NextArch(
+            hidden_size=64, num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=128, linear_num_key_heads=1,
+            linear_num_value_heads=2, linear_key_head_dim=16,
+            linear_value_head_dim=16, num_experts=8, num_experts_per_tok=2,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32,
+            vocab_size=256), dtype=jnp.float32)
+
+    check_units_keep_the_forward(monkeypatch, qwen3_next, model, forced, 1)

@@ -225,6 +225,8 @@ def test_three_fit_steps_reproduce_the_references_losses(tmp_path):
     # and none took the flash kernels: not on the CPU, not at 16-wide heads
     assert [v["obs/attn_kernel_sites"] for _s, v in got["logged"]
             if "obs/attn_kernel_sites" in v] == [0]
+    assert [v["obs/attn_kept_sites"] for _s, v in got["logged"]
+            if "obs/attn_kept_sites" in v] == [0]
 
 
 def test_model_input_spec_and_share_validation():
@@ -259,3 +261,23 @@ def test_window_sites_are_counted_while_the_model_is_traced(params, tokens):
     with attention.count_window_sites() as sites:  # no longer than the window
         jax.eval_shape(lambda: model.apply({"params": params}, tokens[:, :32]))
     assert sites == []
+
+
+@pytest.mark.parametrize("forced", [True, False], ids=["kernel", "xla_form"])
+def test_mixers_keep_the_flash_forwards_results(monkeypatch, forced):
+    """Heads 128 wide over 1024 tokens, the kernels taken as on a TPU: the
+    four mixers (one full layer, three under the band) run the forward kernel
+    once each and keep its `o` and `lse` for the backward pass; on the CPU's
+    own rule the step is a plain `nn.remat`'s."""
+    from kept_attention import check_units_keep_the_forward
+    from pytorchvideo_accelerate_tpu.models import smallthinker
+
+    def model():
+        return smallthinker.SmallThinker(smallthinker.SmallThinkerArch(
+            hidden_size=64, num_hidden_layers=4, num_attention_heads=7,
+            num_key_value_heads=1, head_dim=128, sliding_window_size=32,
+            rope_layout=(0, 1, 1, 1), sliding_window_layout=(0, 1, 1, 1),
+            moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+            moe_ffn_hidden_size=32, vocab_size=256), dtype=jnp.float32)
+
+    check_units_keep_the_forward(monkeypatch, smallthinker, model, forced, 4)

@@ -267,34 +267,36 @@ def test_gated_delta_kernels_sit_under_the_scan_scope(v5e, monkeypatch):
 def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
                                                     scope):
     """An attention layer's gradient at each token cell's shapes, inside the
-    family's rematerialised mixer unit, compiled for the chip with the rule's
-    backend half forced: the forward, the rematerialised forward, dq and dk/dv
-    `pallas_call`s are all there and each sits under an `op_name` that contains
-    `attn/core/` (`swa/core/` for a windowed layer), the rule by which
+    family's rematerialised mixer unit (which keeps the forward's `o` and
+    `lse`), compiled for the chip with the rule's backend half forced: the
+    forward (once: the unit's recomputation does not run it again), dq and
+    dk/dv `pallas_call`s are all there and each sits under an `op_name` that
+    contains `attn/core/` (`swa/core/` for a windowed layer), the rule by which
     `causal_attention_roofline` and `window_attention_roofline` select device
     time and raise over 100% (benchmarks/lib/scoped.py): a backward kernel
     outside the scope would turn a gain into `output_malformed`."""
     import re
 
-    import flax.linen as nn
-
     from pytorchvideo_accelerate_tpu.models import ouro, qwen3_next, smallthinker
+    from pytorchvideo_accelerate_tpu.models.lm_common import (
+        remat_keeping_attention,
+    )
 
     monkeypatch.setattr(attention, "takes_kernel", lambda: True)
     monkeypatch.setattr(attention, "_interpret", lambda: False)
     if family == "qwen3_next":
-        layer = nn.remat(qwen3_next._Mixer)(
+        layer = remat_keeping_attention(qwen3_next._Mixer)(
             qwen3_next.Qwen3NextArch(), jnp.bfloat16, "full_attention",
             name="mixer_3")
         x_shape, window = (2, 8192, 2048), None
     elif family == "ouro":   # one layer execution: the loop's remat unit
-        layer = nn.remat(ouro._Layer)(ouro.OuroArch(), jnp.bfloat16,
-                                      name="layer_0")
+        layer = remat_keeping_attention(ouro._Layer)(
+            ouro.OuroArch(), jnp.bfloat16, name="layer_0")
         x_shape, window = (1, 4096, 2048), None
     else:
         arch = smallthinker.SmallThinkerArch()
         window = arch.sliding_window_size if family.endswith("window") else None
-        layer = nn.remat(smallthinker._Mixer)(
+        layer = remat_keeping_attention(smallthinker._Mixer)(
             arch, jnp.bfloat16, rotary=window is not None, window=window,
             name="mixer_0")
         x_shape = (1, 16384, arch.hidden_size)
@@ -305,7 +307,7 @@ def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
     def loss(params, x):
         with attention.count_kernel_sites() as sites:
             y = layer.apply({"params": params}, x)
-        assert [w for _shape, w in sites] == [window]
+        assert [(w, kept) for _shape, w, kept in sites] == [(window, True)]
         return sum(jnp.sum(leaf.astype(F32) ** 2)
                    for leaf in jax.tree.leaves(y)
                    if jnp.issubdtype(leaf.dtype, jnp.floating))
@@ -319,5 +321,5 @@ def test_attention_kernels_sit_under_the_core_scope(v5e, monkeypatch, family,
                 for line in calls]
     assert sorted(re.search(r"pva_attn_\w+", n).group(0)
                   for n in op_names) == ["pva_attn_dkv", "pva_attn_dq",
-                                         "pva_attn_fwd", "pva_attn_fwd"], op_names
+                                         "pva_attn_fwd"], op_names
     assert all(scope in n for n in op_names), op_names

@@ -839,6 +839,9 @@ def test_zz_train_smoke_window_breakdown(tmp_path, _tiny_slow_r50):
     # kernels (ops/attention.py; a conv model has none)
     assert [ln["obs/attn_kernel_sites"] for ln in lines
             if "obs/attn_kernel_sites" in ln] == [0.0]
+    # and of those, the ones a remat unit keeps the forward's results of
+    assert [ln["obs/attn_kept_sites"] for ln in lines
+            if "obs/attn_kept_sites" in ln] == [0.0]
 
 
 def _toy_token_model(family, head_dim):
@@ -887,7 +890,8 @@ def test_attn_kernel_sites_gauge(monkeypatch, family, forced, head_dim,
     one attention layer of a Qwen3-Next period, all four of a SmallThinker
     period (the three under the band still count as `pva_attn_window_sites`)
     where the rule holds; none by the CPU's own rule, none at the toy models'
-    16-wide heads."""
+    16-wide heads. `pva_attn_kept_sites` counts the same sites: each is in a
+    mixer whose remat policy keeps the forward's `o` and `lse`."""
     import jax
     import optax
 
@@ -910,6 +914,8 @@ def test_attn_kernel_sites_gauge(monkeypatch, family, forced, head_dim,
     make_lm_step(model, tx, mesh).trace(state, batch, jax.random.key(0))
     registry = obs.get_registry()
     assert registry.get("pva_attn_kernel_sites").value() == kernels
+    # every site sits in a mixer that keeps the forward kernel's o and lse
+    assert registry.get("pva_attn_kept_sites").value() == kernels
     assert registry.get("pva_attn_window_sites").value() == bands
 
 
