@@ -411,6 +411,60 @@ def _ouro_t(cfg: ModelConfig, dtype, mesh=None):
         vocab_size=256, total_ut_steps=3))
 
 
+def _nemotron_h(cfg: ModelConfig, dtype, published: dict):
+    """The held share of a Nemotron-H decoder: published widths, with the
+    depth (the pattern's first layers), the vocabulary slice and the experts
+    held as the config says."""
+    from pytorchvideo_accelerate_tpu.models.lm_common import check_share
+    from pytorchvideo_accelerate_tpu.models.nemotron_h import (
+        KINDS,
+        NemotronH,
+        NemotronHArch,
+    )
+
+    arch = NemotronHArch(**published)
+    layers = cfg.num_layers or arch.num_hidden_layers
+    pattern = arch.hybrid_override_pattern
+    if layers > len(pattern):
+        raise ValueError(f"model.num_layers={layers} is more than the "
+                         f"{len(pattern)} layers of the pattern {pattern!r}")
+    if set(pattern) - set(KINDS):
+        raise ValueError(f"hybrid_override_pattern {pattern!r} holds a layer "
+                         f"kind other than {KINDS!r}")
+    arch = dataclasses.replace(
+        arch, num_hidden_layers=layers,
+        vocab_size=cfg.vocab_size or arch.vocab_size,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset)
+    check_share(layers, 1, arch.expert_offset, arch.held,
+                arch.n_routed_experts)
+    return NemotronH(arch, dtype=dtype, remat=True)
+
+
+@register_model("nemotron_3_nano_30b_a3b", task="next_token")
+def _nemotron_3_nano_30b_a3b(cfg: ModelConfig, dtype, mesh=None):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths (the defaults
+    of `NemotronHArch`: huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
+    config.json). One chip holds a share: `--model.num_layers` (the pattern's
+    first layers), `--model.vocab_size`, `--model.experts_held`
+    (docs/TOKENS.md)."""
+    return _nemotron_h(cfg, dtype, {})
+
+
+@register_model("nemotron_h_t", task="next_token")
+def _nemotron_h_t(cfg: ModelConfig, dtype, mesh=None):
+    """Deliberately tiny Nemotron-H: every mechanism in the published
+    pattern's first six layers (`MEMEM*`: three Mamba-2 mixers of 4 heads in
+    2 groups, two sigmoid-routed relu^2 mixtures with a shared expert,
+    attention without positions at 4 query heads on 1 key-value head), toy
+    widths — tests and the CPU rehearsal. Not a reference architecture."""
+    return _nemotron_h(cfg, dtype, dict(
+        hidden_size=64, num_hidden_layers=6, num_attention_heads=4, num_key_value_heads=1, head_dim=16,
+        mamba_num_heads=4, mamba_head_dim=16, n_groups=2, ssm_state_size=16,
+        chunk_size=32, n_routed_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=32, moe_shared_expert_intermediate_size=48,
+        vocab_size=256))
+
+
 def available_models():
     return sorted(_REGISTRY)
 

@@ -32,6 +32,14 @@ def rms(x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
+def causal_conv(x, w):
+    """The causal depthwise conv over time of x (B, T, C) by the taps w (K,
+    C), oldest first: out_t = sum_i w_i x_{t-K+1+i}, zeros before the start."""
+    taps, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, i:i + t] * w[i] for i in range(taps))
+
+
 def _dense(mod, name, x, features, dtype):
     w = mod.param(name, _normal(), (x.shape[-1], features))
     return jnp.dot(x, w.astype(dtype))
@@ -164,7 +172,9 @@ def lm_outputs(x, head, targets, weights, loss_block: int, shares):
     """What a token model returns after its last norm: the logits (B, T, V)
     in float32 without `targets`, else the dict `make_lm_step` reads.
     `shares`: each mixture layer's (rows by held expert, whether they fit
-    the tight row buffers), as `ops/moe.py` `expert_share` returns them."""
+    the tight row buffers), as `ops/moe.py` `expert_share` returns them;
+    where there are none (no layer is a mixture) the dict has no expert
+    rows."""
     if targets is None:
         with jax.named_scope("lm_head"):
             return f32_island(jnp.dot(x, head.astype(x.dtype)))
@@ -174,10 +184,11 @@ def lm_outputs(x, head, targets, weights, loss_block: int, shares):
     loss_sum, correct = next_token_loss(
         x.reshape(b * t, d), head, targets.reshape(b * t),
         weights.reshape(b * t), loss_block)
-    return {"loss_sum": loss_sum, "correct": correct,
-            "count": weights.sum(),
-            "expert_rows": jnp.stack([r for r, _ in shares]),  # (layers, held)
-            "expert_tight": jnp.stack([t for _, t in shares])}  # (layers,)
+    out = {"loss_sum": loss_sum, "correct": correct, "count": weights.sum()}
+    if shares:
+        out["expert_rows"] = jnp.stack([r for r, _ in shares])  # (layers, held)
+        out["expert_tight"] = jnp.stack([t for _, t in shares])  # (layers,)
+    return out
 
 
 def exit_weighted_loss(hidden, head_kernel, gate_kernel, gate_bias, targets,

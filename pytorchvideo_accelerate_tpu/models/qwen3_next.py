@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from pytorchvideo_accelerate_tpu.models.lm_common import (
     _dense,
+    causal_conv,
     _normal,
     held_experts,
     lm_outputs,
@@ -113,11 +114,9 @@ class GatedDeltaNet(nn.Module):
             ba = _dense(self, "in_proj_ba", x, 2 * hv, dt)
             qkv, z = qkvz[..., :2 * kdim + vdim], qkvz[..., 2 * kdim + vdim:]
         with jax.named_scope("conv"):
-            # causal depthwise conv over time, kernel taps oldest first
-            taps = a.linear_conv_kernel_dim
-            w = self.param("conv", _normal(), (taps, qkv.shape[-1])).astype(dt)
-            padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
-            qkv = jax.nn.silu(sum(padded[:, i:i + t] * w[i] for i in range(taps)))
+            w = self.param("conv", _normal(),
+                           (a.linear_conv_kernel_dim, qkv.shape[-1])).astype(dt)
+            qkv = jax.nn.silu(causal_conv(qkv, w))
         with jax.named_scope("scan"):
             a_log = self.param(
                 "A_log", lambda key, shape: jnp.log(

@@ -60,7 +60,7 @@ from pytorchvideo_accelerate_tpu.ops import pallas_gated_delta as kernel
 from pytorchvideo_accelerate_tpu.precision import end_island, f32_island
 
 CHUNK = 64
-_MASKED = -1e30  # exponent of an entry above the diagonal: exp gives 0
+MASKED = -1e30  # exponent of an entry above the diagonal: exp gives 0
 
 _sites: contextvars.ContextVar = contextvars.ContextVar(
     "pva_gdn_scan_kernel_sites", default=None)
@@ -156,7 +156,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     out_dtype = v.dtype
-    q, k, v, g, beta = _pad_tokens(-t % chunk, q, k, v, g, beta)
+    q, k, v, g, beta = pad_tokens(-t % chunk, q, k, v, g, beta)
     n = q.shape[1] // chunk
 
     def chunks(x):  # (B, T, H, ...) -> (N, B, H, C, ...)
@@ -168,7 +168,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     beta_c = chunks(f32_island(beta))
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     decay = jnp.exp(jnp.where(
-        lower, gamma[..., :, None] - gamma[..., None, :], _MASKED))
+        lower, gamma[..., :, None] - gamma[..., None, :], MASKED))
 
     precision = (lax.Precision.HIGHEST if q.dtype == jnp.float32
                  else lax.Precision.DEFAULT)
@@ -209,7 +209,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     return end_island(o, out_dtype), state
 
 
-def _pad_tokens(pad, *arrays):
+def pad_tokens(pad, *arrays):
     if not pad:
         return arrays
     return tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
@@ -226,7 +226,7 @@ def _kernel_rule(q, k, v, g, beta):
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     c, out_dtype = kernel.CHUNK, v.dtype
-    q, k, v, g, beta = _pad_tokens(-t % c, q, end_island(k, q.dtype),
+    q, k, v, g, beta = pad_tokens(-t % c, q, end_island(k, q.dtype),
                                    end_island(v, q.dtype), g, beta)
     tp = q.shape[1]
 

@@ -12,6 +12,13 @@ there is no exchange; the partial sum is what goes on (docs/TOKENS.md).
             w_k * (act(x W_gate[e_k]) * (x W_up[e_k])) W_down[e_k]
                                         (act: SiLU by default, ReLU for ReGLU experts)
 
+or, for a sigmoid router with a selection bias b (nemotron_h) and experts of
+two products:
+
+    s = sigmoid_f32(x W_r);  e_k = top-k of s + b
+    w_k = scale * s_{e_k} / (sum_k s_{e_k} + 1e-20)
+    y = sum over the held e_k of w_k * act(x W_up[e_k]) W_down[e_k]   (act: relu^2)
+
 No capacity factor and no dropped token: every (token, held expert) pair is
 computed, whatever the imbalance. The pairs are sorted by expert, so each held
 expert's rows are contiguous and the three products are grouped matrix
@@ -47,16 +54,34 @@ from jax import lax
 from pytorchvideo_accelerate_tpu.precision import end_island, f32_island
 
 
-def route(x, router_kernel, top_k: int, norm_topk: bool = True):
+def route(x, router_kernel, top_k: int, norm_topk: bool = True,
+          score: str = "softmax", selection_bias=None, scale: float = 1.0):
     """Router in float32: (weights (N, k) float32, experts (N, k) int32).
     `Precision.HIGHEST`, because the top-k is a comparison: logits rounded to
-    bfloat16 choose other experts for the tokens near a tie."""
+    bfloat16 choose other experts for the tokens near a tie. `score`: the
+    experts' scores, "softmax" over all or an independent "sigmoid" each;
+    `selection_bias` (num_experts,) is added to the scores to CHOOSE the
+    experts and not to weigh them; the weights are times `scale`."""
     logits = jnp.dot(f32_island(x), f32_island(router_kernel),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(probs, top_k)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"score must be 'softmax' or 'sigmoid', got {score!r}")
+    if selection_bias is None:
+        weights, experts = lax.top_k(probs, top_k)
+    else:
+        _, experts = lax.top_k(probs + f32_island(selection_bias), top_k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk:
-        weights = weights / weights.sum(axis=-1, keepdims=True)
+        total = weights.sum(axis=-1, keepdims=True)
+        if score == "sigmoid":  # independent scores may all be near 0
+            total = total + 1e-20
+        weights = weights / total
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
@@ -109,7 +134,7 @@ def _share(x, weights, experts, w_gate, w_up, w_down, expert_offset, bound,
     rows (held,) int32)."""
     n, d = x.shape
     top_k = experts.shape[-1]
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     pairs = n * top_k
     with jax.named_scope("dispatch"):
         local = experts.reshape(pairs) - expert_offset
@@ -133,8 +158,11 @@ def _share(x, weights, experts, w_gate, w_up, w_down, expert_offset, bound,
             return lax.ragged_dot(lhs, w.astype(x.dtype), rows,
                                   precision=precision)
 
-        ys = grouped(activation(grouped(xs, w_gate)) * grouped(xs, w_up),
-                     w_down)
+        if w_gate is None:  # two products: act(x U) V
+            ys = grouped(activation(grouped(xs, w_up)), w_down)
+        else:
+            ys = grouped(activation(grouped(xs, w_gate)) * grouped(xs, w_up),
+                         w_down)
     with jax.named_scope("combine"):
         w_rows = jnp.where(valid, weights.reshape(pairs)[order], 0.0)
         ys = jnp.where(valid[:, None], f32_island(ys), 0.0) * w_rows[:, None]
@@ -148,12 +176,13 @@ def expert_share(x, weights, experts, w_gate, w_up, w_down, expert_offset: int,
     routing (`route`) over `num_experts`. `w_gate`, `w_up` (held, D, F) and
     `w_down` (held, F, D) are the experts [expert_offset, expert_offset +
     held); `activation` is the gate's (SiLU: SwiGLU experts; ReLU: ReGLU).
+    `w_gate` None: experts of two products, act(x w_up) w_down.
     Returns (y (N, D) in x's dtype, rows (held,) int32: how many tokens each
     held expert computed, tight: whether the local pairs fit the buffers of
     `buffer_rows`; where not, the tokens went through in chunks)."""
     n, _ = x.shape
     top_k = experts.shape[-1]
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     worst = n * min(top_k, held)
     bound = buffer_rows(n, top_k, held, num_experts)
     args = (w_gate, w_up, w_down, expert_offset)

@@ -35,7 +35,12 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pytorchvideo_accelerate_tpu.obs.registry import get_registry
-from pytorchvideo_accelerate_tpu.ops import attention, gated_delta, lane_fold
+from pytorchvideo_accelerate_tpu.ops import (
+    attention,
+    gated_delta,
+    lane_fold,
+    ssd,
+)
 from pytorchvideo_accelerate_tpu.parallel.mesh import batch_axes
 from pytorchvideo_accelerate_tpu.trainer.train_state import TrainState
 
@@ -320,7 +325,8 @@ def _count_lowering_sites():
     with lane_fold.count_sites() as folded, \
             gated_delta.count_sites() as scans, \
             attention.count_window_sites() as bands, \
-            attention.count_kernel_sites() as flashes:
+            attention.count_kernel_sites() as flashes, \
+            ssd.count_sites() as ssd_scans:
         yield
     registry = get_registry()
     registry.gauge(
@@ -344,6 +350,10 @@ def _count_lowering_sites():
         "of those, the calls traced inside a remat unit that keeps the "
         "forward kernel's o and lse (models/lm_common.py)").set(
             sum(kept for _shape, _window, kept in flashes))
+    registry.gauge(
+        "pva_ssd_scan_sites",
+        "Mamba-2 mixers of the traced train step lowered through the "
+        "chunked state-space scan (ops/ssd.py)").set(len(ssd_scans))
 
 
 def make_train_step(
