@@ -24,11 +24,11 @@ def _dense(q, k, v, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision="highest")
 
 
-def _qkv(t, hq=4, hkv=2, d=256, seed=0):
+def _qkv(t, hq=4, hkv=2, d=256, seed=0, b=2):
     ks = jax.random.split(jax.random.key(seed), 3)
-    return (jax.random.normal(ks[0], (2, t, hq, d)),
-            jax.random.normal(ks[1], (2, t, hkv, d)),
-            jax.random.normal(ks[2], (2, t, hkv, d)))
+    return (jax.random.normal(ks[0], (b, t, hq, d)),
+            jax.random.normal(ks[1], (b, t, hkv, d)),
+            jax.random.normal(ks[2], (b, t, hkv, d)))
 
 
 # float32 on the CPU: blocks change the order of the softmax's sums only
@@ -39,8 +39,9 @@ def _qkv(t, hq=4, hkv=2, d=256, seed=0):
 ])
 def test_blocks_equal_the_dense_masked_product(t, block_q):
     q, k, v = _qkv(t)
-    want = _dense(q, k, v, 256 ** -0.5)
-    got = causal_gqa_attention(q, k, v, scale=256 ** -0.5, block_q=block_q)
+    want = jax.jit(_dense, static_argnums=3)(q, k, v, 256 ** -0.5)
+    got = jax.jit(lambda *a: causal_gqa_attention(
+        *a, scale=256 ** -0.5, block_q=block_q))(q, k, v)
     assert got.shape == q.shape
     assert float(jnp.abs(got - want).max()) < 2e-5
 
@@ -52,19 +53,21 @@ def test_gradients_equal_the_dense_masked_product():
     def loss(fn):
         return lambda *a: jnp.sum(fn(*a) * cot)
 
-    want = jax.grad(loss(lambda *a: _dense(*a, 256 ** -0.5)), (0, 1, 2))(q, k, v)
-    got = jax.grad(loss(lambda *a: causal_gqa_attention(
-        *a, scale=256 ** -0.5, block_q=32)), (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(loss(lambda *a: _dense(*a, 256 ** -0.5)),
+                            (0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.grad(loss(lambda *a: causal_gqa_attention(
+        *a, scale=256 ** -0.5, block_q=32)), (0, 1, 2)))(q, k, v)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) < 1e-4 * float(jnp.abs(b).max() + 1)
 
 
 def test_token_reads_no_later_key():
     q, k, v = _qkv(64, seed=2)
-    base = causal_gqa_attention(q, k, v, block_q=16)
+    run = jax.jit(lambda *a: causal_gqa_attention(*a, block_q=16))
+    base = run(q, k, v)
     k2 = k.at[:, 40:].add(3.0)
     v2 = v.at[:, 40:].add(3.0)
-    moved = causal_gqa_attention(q, k2, v2, block_q=16)
+    moved = run(q, k2, v2)
     assert float(jnp.abs(moved[:, :40] - base[:, :40]).max()) == 0.0
     assert float(jnp.abs(moved[:, 40:] - base[:, 40:]).max()) > 0.1
 
@@ -121,9 +124,9 @@ WINDOWS = [
 @pytest.mark.parametrize("t,block_q,window,hq,hkv", WINDOWS)
 def test_window_equals_the_dense_banded_product(t, block_q, window, hq, hkv):
     q, k, v = _qkv(t, hq=hq, hkv=hkv, d=64, seed=4)
-    want = _dense_banded(q, k, v, 0.125, window)
-    got = causal_gqa_attention(q, k, v, scale=0.125, block_q=block_q,
-                               window=window)
+    want = jax.jit(lambda *a: _dense_banded(*a, 0.125, window))(q, k, v)
+    got = jax.jit(lambda *a: causal_gqa_attention(
+        *a, scale=0.125, block_q=block_q, window=window))(q, k, v)
     assert got.shape == q.shape
     assert float(jnp.abs(got - want).max()) < 2e-5
 
@@ -138,10 +141,10 @@ def test_window_gradients_equal_the_dense_banded_product(t, block_q, window,
     def loss(fn):
         return lambda *a: jnp.sum(fn(*a) * cot)
 
-    want = jax.grad(loss(lambda *a: _dense_banded(*a, 0.125, window)),
-                    (0, 1, 2))(q, k, v)
-    got = jax.grad(loss(lambda *a: causal_gqa_attention(
-        *a, scale=0.125, block_q=block_q, window=window)), (0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(loss(lambda *a: _dense_banded(*a, 0.125, window)),
+                            (0, 1, 2)))(q, k, v)
+    got = jax.jit(jax.grad(loss(lambda *a: causal_gqa_attention(
+        *a, scale=0.125, block_q=block_q, window=window)), (0, 1, 2)))(q, k, v)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) < 1e-4 * float(jnp.abs(b).max() + 1)
 
@@ -152,8 +155,8 @@ def test_query_reads_no_key_at_a_distance_of_the_window_or_more(block_q, window)
     query that may read one of them (t < 20 + window) and no other, though
     some of those keys lie in the key blocks the later queries' blocks read."""
     q, k, v = _qkv(96, d=64, seed=6)
-    run = lambda v: causal_gqa_attention(  # noqa: E731
-        q, k, v, block_q=block_q, window=window)
+    run = jax.jit(lambda v: causal_gqa_attention(
+        q, k, v, block_q=block_q, window=window))
     base, moved = run(v), run(v.at[:, :21].add(1e4))
     reach = 20 + window
     assert float(jnp.abs(moved[:, reach:] - base[:, reach:]).max()) == 0.0
@@ -211,7 +214,8 @@ def test_no_band_traces_to_the_jaxpr_it_had_before_the_window(window):
 #
 # `causal_gqa_attention`'s lowering on a TPU for heads a multiple of 128 wide,
 # held here to the XLA form above and to `dense_attention` under the same
-# mask. Blocks of 128 so that 300 tokens are three blocks, the last ragged.
+# mask. Blocks of 128 so that 300 tokens are three blocks, the last ragged;
+# one sequence, as the interpreted kernels' time grows with their grid.
 
 def _mask(t, window):
     delta = jnp.arange(t)[:, None] - jnp.arange(t)[None, :]
@@ -255,11 +259,13 @@ KERNEL_GRADS = [KERNEL[i] for i in (0, 1, 4, 5, 8, 9)]
 @pytest.mark.parametrize("t,hq,hkv,d,window,block_q,block_k", KERNEL)
 def test_kernel_equals_the_xla_form_and_the_dense_masked_product(
         t, hq, hkv, d, window, block_q, block_k):
-    q, k, v = _qkv(t, hq=hq, hkv=hkv, d=d, seed=11)
+    q, k, v = _qkv(t, hq=hq, hkv=hkv, d=d, seed=11, b=1)
     got = jax.jit(_kernel(window, block_q, block_k))(q, k, v)
     assert got.shape == q.shape and got.dtype == q.dtype
-    xla = causal_gqa_attention(q, k, v, block_q=64, window=window)
-    dense = _dense_attention(q, k, v, d ** -0.5, window)
+    xla = jax.jit(lambda *a: causal_gqa_attention(
+        *a, block_q=64, window=window))(q, k, v)
+    dense = jax.jit(lambda *a: _dense_attention(
+        *a, d ** -0.5, window))(q, k, v)
     assert float(jnp.abs(got - xla).max()) < 2e-5
     assert float(jnp.abs(got - dense).max()) < 2e-5
 
@@ -267,7 +273,7 @@ def test_kernel_equals_the_xla_form_and_the_dense_masked_product(
 @pytest.mark.parametrize("t,hq,hkv,d,window,block_q,block_k", KERNEL_GRADS)
 def test_kernel_gradients_equal_the_xla_forms_and_the_dense_products(
         t, hq, hkv, d, window, block_q, block_k):
-    q, k, v = _qkv(t, hq=hq, hkv=hkv, d=d, seed=12)
+    q, k, v = _qkv(t, hq=hq, hkv=hkv, d=d, seed=12, b=1)
     cot = jax.random.normal(jax.random.key(9), q.shape)
 
     def grads(fn):
@@ -290,7 +296,7 @@ def test_kernel_in_bfloat16_rounds_where_the_xla_form_rounds(window):
     gradients within bfloat16's rounding (2^-8) of the XLA form's, which
     rounds at the same places in another order, and of the float32 product."""
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(300, hq=7, hkv=1, d=128,
-                                                    seed=13))
+                                                    seed=13, b=1))
     cot = jax.random.normal(jax.random.key(9), q.shape, jnp.bfloat16)
 
     def both(fn, *args):
@@ -330,7 +336,7 @@ def test_kernel_reads_no_key_above_the_diagonal_or_behind_the_band(
     in the values up to position 20 reaches every query that may read one of
     them and no other, and keys moved from position 400 on reach no query
     before it."""
-    q, k, v = _qkv(600, hq=7, hkv=1, d=128, seed=14)
+    q, k, v = _qkv(600, hq=7, hkv=1, d=128, seed=14, b=1)
     with kernel_forced.count_kernel_sites() as sites:
         run = jax.jit(lambda k, v: causal_gqa_attention(q, k, v, window=window))
         base = run(k, v)

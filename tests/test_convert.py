@@ -50,13 +50,13 @@ def _fake_torch_sd(variables, model, seed=0):
 @pytest.fixture(scope="module")
 def slow_vars():
     model = SlowR50(num_classes=7, depths=(1, 1, 1, 1), stem_features=8)
-    return model.init(jax.random.key(0), jnp.zeros((1, 2, 32, 32, 3)))
+    return jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 2, 32, 32, 3)))
 
 
 @pytest.fixture(scope="module")
 def slowfast_vars():
     model = SlowFast(num_classes=7, depths=(1, 1, 1, 1), stem_features=8)
-    return model.init(
+    return jax.jit(model.init)(
         jax.random.key(0),
         (jnp.zeros((1, 2, 32, 32, 3)), jnp.zeros((1, 8, 32, 32, 3))),
     )
@@ -68,7 +68,7 @@ def r2plus1d_vars():
 
     model = R2Plus1D(num_classes=7, depths=(1, 1), stem_features=8,
                      spatial_strides=(1, 2), temporal_strides=(1, 2))
-    return model.init(jax.random.key(0), jnp.zeros((1, 4, 32, 32, 3)))
+    return jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 4, 32, 32, 3)))
 
 
 @pytest.fixture(scope="module")
@@ -77,7 +77,7 @@ def csn_vars():
 
     model = CSN(num_classes=7, depths=(1, 1), stem_features=8,
                 spatial_strides=(1, 2), temporal_strides=(1, 2))
-    return model.init(jax.random.key(0), jnp.zeros((1, 4, 32, 32, 3)))
+    return jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 4, 32, 32, 3)))
 
 
 @pytest.mark.parametrize("fixture,model", [
@@ -173,7 +173,8 @@ def test_head_swap_keeps_fresh_head(tmp_path, slow_vars):
     path = str(tmp_path / "slow.npz")
     save_converted(tree, path)
 
-    target = SlowR50(num_classes=11, depths=(1, 1, 1, 1), stem_features=8).init(
+    target = jax.jit(SlowR50(num_classes=11, depths=(1, 1, 1, 1),
+                             stem_features=8).init)(
         jax.random.key(1), jnp.zeros((1, 2, 32, 32, 3))
     )
     merged, report = load_pretrained(path, target)
@@ -199,7 +200,7 @@ def x3d_vars():
     from pytorchvideo_accelerate_tpu.models.x3d import X3D
 
     model = X3D(num_classes=7, depths=(1, 1, 1, 1))
-    return model.init(jax.random.key(0), jnp.zeros((1, 4, 32, 32, 3)))
+    return jax.jit(model.init)(jax.random.key(0), jnp.zeros((1, 4, 32, 32, 3)))
 
 
 def test_x3d_full_tree_round_trip(x3d_vars):
@@ -246,7 +247,7 @@ def test_x3d_merge_head_swap(tmp_path, x3d_vars):
     tree = convert_state_dict(sd, "x3d_s")
     path = str(tmp_path / "x3d.npz")
     save_converted(tree, path)
-    target = X3D(num_classes=11, depths=(1, 1, 1, 1)).init(
+    target = jax.jit(X3D(num_classes=11, depths=(1, 1, 1, 1)).init)(
         jax.random.key(1), jnp.zeros((1, 4, 32, 32, 3))
     )
     merged, report = load_pretrained(path, target)
@@ -404,8 +405,8 @@ class TestMViTConvert:
         model = MViT(num_classes=7, depth=2, embed_dim=16, num_heads=2,
                      stage_starts=(1,), initial_kv_stride=(1, 2, 2),
                      drop_path_rate=0.0, dropout_rate=0.0)
-        variables = model.init(jax.random.key(0),
-                               jnp.zeros((1, 4, 32, 32, 3)))
+        variables = jax.jit(model.init)(jax.random.key(0),
+                                        jnp.zeros((1, 4, 32, 32, 3)))
         merged, report = load_pretrained(path, variables)
         assert report["kept"] == [], report["kept"]
 
@@ -416,8 +417,8 @@ class TestMViTConvert:
         path = str(tmp_path / "mvit.npz")
         save_converted(tree, path)
         model = self._model()
-        variables = model.init(jax.random.key(0),
-                               jnp.zeros((1, self.T, self.S, self.S, 3)))
+        variables = jax.jit(model.init)(jax.random.key(0),
+                                        jnp.zeros((1, self.T, self.S, self.S, 3)))
         merged, report = load_pretrained(path, variables)
         loaded = set(report["loaded"])
         for want in ("params/block0/attn/qkv/kernel",

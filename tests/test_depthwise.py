@@ -23,12 +23,12 @@ def test_shift_matches_grouped_conv(stride, kernel):
     x = np.random.default_rng(0).standard_normal((2, 6, 8, 8, 6)).astype(np.float32)
     mc = DepthwiseConv3D(6, kernel, stride, impl="conv")
     ms = DepthwiseConv3D(6, kernel, stride, impl="shift")
-    v = mc.init(jax.random.key(0), jnp.asarray(x))
+    v = jax.jit(mc.init)(jax.random.key(0), jnp.asarray(x))
     # identical param trees: the impl is a lowering choice, not a model change
     assert jax.tree.structure(v) == jax.tree.structure(
         ms.init(jax.random.key(0), jnp.asarray(x)))
-    a = mc.apply(v, jnp.asarray(x))
-    b = ms.apply(v, jnp.asarray(x))
+    a = jax.jit(mc.apply)(v, jnp.asarray(x))
+    b = jax.jit(ms.apply)(v, jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
 
@@ -41,9 +41,9 @@ def test_shift_matches_conv_under_bf16():
                          dtype=jnp.bfloat16)
     ms = DepthwiseConv3D(16, (3, 3, 3), (1, 1, 1), impl="shift",
                          dtype=jnp.bfloat16)
-    v = mc.init(jax.random.key(0), jnp.asarray(x))
-    a = np.asarray(mc.apply(v, jnp.asarray(x)), np.float32)
-    b = np.asarray(ms.apply(v, jnp.asarray(x)), np.float32)
+    v = jax.jit(mc.init)(jax.random.key(0), jnp.asarray(x))
+    a = np.asarray(jax.jit(mc.apply)(v, jnp.asarray(x)), np.float32)
+    b = np.asarray(jax.jit(ms.apply)(v, jnp.asarray(x)), np.float32)
     # both accumulate f32 then round once to bf16: worst case one ulp apart
     np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
     assert np.mean(a == b) > 0.95  # overwhelmingly identical after rounding
@@ -53,13 +53,14 @@ def test_shift_gradients_match():
     x = np.random.default_rng(1).standard_normal((1, 4, 6, 6, 4)).astype(np.float32)
     mc = DepthwiseConv3D(4, (3, 3, 3), (1, 2, 2), impl="conv")
     ms = DepthwiseConv3D(4, (3, 3, 3), (1, 2, 2), impl="shift")
-    v = mc.init(jax.random.key(0), jnp.asarray(x))
+    v = jax.jit(mc.init)(jax.random.key(0), jnp.asarray(x))
 
-    def loss(variables, model):
-        return jnp.sum(model.apply(variables, jnp.asarray(x)) ** 2)
+    def grad(model):
+        return jax.jit(jax.grad(lambda variables: jnp.sum(
+            model.apply(variables, jnp.asarray(x)) ** 2)))(v)
 
-    ga = jax.grad(loss)(v, mc)
-    gb = jax.grad(loss)(v, ms)
+    ga = grad(mc)
+    gb = grad(ms)
     for a, b in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
@@ -73,9 +74,9 @@ def test_x3d_model_equivalent_under_both_impls():
               stage_features=(8, 16), head_features=32, dropout_rate=0.0)
     mc = X3D(depthwise_impl="conv", **kw)
     ms = X3D(depthwise_impl="shift", **kw)
-    v = mc.init(jax.random.key(0), jnp.asarray(x))
-    a = mc.apply(v, jnp.asarray(x))
-    b = ms.apply(v, jnp.asarray(x))  # same variables: same param tree
+    v = jax.jit(mc.init)(jax.random.key(0), jnp.asarray(x))
+    a = jax.jit(mc.apply)(v, jnp.asarray(x))
+    b = jax.jit(ms.apply)(v, jnp.asarray(x))  # same variables: same param tree
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-4)
 
@@ -89,9 +90,9 @@ def test_mvit_model_equivalent_under_both_impls():
               drop_path_rate=0.0, dropout_rate=0.0)
     mc = MViT(depthwise_impl="conv", **kw)
     ms = MViT(depthwise_impl="shift", **kw)
-    v = mc.init(jax.random.key(0), jnp.asarray(x))
-    a = mc.apply(v, jnp.asarray(x))
-    b = ms.apply(v, jnp.asarray(x))
+    v = jax.jit(mc.init)(jax.random.key(0), jnp.asarray(x))
+    a = jax.jit(mc.apply)(v, jnp.asarray(x))
+    b = jax.jit(ms.apply)(v, jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-4)
 
@@ -150,8 +151,8 @@ def test_pallas_gradients_match():
             feature_group_count=C)
         return jnp.sum(y ** 2)
 
-    gp = jax.grad(loss_p, (0, 1))(x, k)
-    gr = jax.grad(loss_r, (0, 1))(x, k)
+    gp = jax.jit(jax.grad(loss_p, (0, 1)))(x, k)
+    gr = jax.jit(jax.grad(loss_r, (0, 1)))(x, k)
     np.testing.assert_allclose(np.asarray(gp[0]), np.asarray(gr[0]),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(gp[1]), np.asarray(gr[1]),
@@ -170,17 +171,18 @@ def test_x3d_model_equivalent_under_pallas_impl():
               stage_features=(8, 16), head_features=32, dropout_rate=0.0)
     mc = X3D(depthwise_impl="conv", **kw)
     mp = X3D(depthwise_impl="pallas", **kw)
-    v = mc.init(jax.random.key(0), jnp.asarray(x))
-    a = mc.apply(v, jnp.asarray(x))
-    b = mp.apply(v, jnp.asarray(x))
+    v = jax.jit(mc.init)(jax.random.key(0), jnp.asarray(x))
+    a = jax.jit(mc.apply)(v, jnp.asarray(x))
+    b = jax.jit(mp.apply)(v, jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-4, atol=1e-4)
 
-    def loss(variables, model):
-        return jnp.sum(model.apply(variables, jnp.asarray(x)) ** 2)
+    def grad(model):
+        return jax.jit(jax.grad(lambda variables: jnp.sum(
+            model.apply(variables, jnp.asarray(x)) ** 2)))(v)
 
-    ga = jax.grad(loss)(v, mc)
-    gb = jax.grad(loss)(v, mp)
+    ga = grad(mc)
+    gb = grad(mp)
     for p, q in zip(jax.tree.leaves(ga), jax.tree.leaves(gb)):
         np.testing.assert_allclose(np.asarray(p), np.asarray(q),
                                    rtol=1e-4, atol=1e-4)
@@ -194,7 +196,7 @@ def test_pallas_even_kernel_falls_back_to_conv():
     x = jnp.asarray(rng.standard_normal((1, 5, 8, 8, 4)), jnp.float32)
     mc = DepthwiseConv3D(4, (2, 3, 3), impl="conv")
     mp = DepthwiseConv3D(4, (2, 3, 3), impl="pallas")
-    v = mc.init(jax.random.key(0), x)
-    np.testing.assert_allclose(np.asarray(mc.apply(v, x)),
-                               np.asarray(mp.apply(v, x)),
+    v = jax.jit(mc.init)(jax.random.key(0), x)
+    np.testing.assert_allclose(np.asarray(jax.jit(mc.apply)(v, x)),
+                               np.asarray(jax.jit(mp.apply)(v, x)),
                                rtol=1e-5, atol=1e-5)

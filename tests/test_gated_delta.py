@@ -46,6 +46,12 @@ def _sized(lowering, t, decay, beta, seed=0):
     return _inputs(t, decay, beta, seed, b=b, h=h, dk=dk, dv=dv, hk=hk)
 
 
+def _jit(fn):
+    """`fn` jitted as a new function: traced at its first call, so the rule
+    is read as the test has set it."""
+    return jax.jit(lambda *a: fn(*a))
+
+
 def _took_kernel(fn, *args):
     """(fn's result, the number of `gated_delta_rule` calls that took the
     kernel while it ran)."""
@@ -72,8 +78,8 @@ CASES = [
 @pytest.mark.parametrize("t,decay,beta", CASES)
 def test_chunked_forward_equals_recurrence(lowering, t, decay, beta):
     args = _sized(lowering, t, decay, beta)
-    o_ref, s_ref = gated_delta_recurrence(*args)
-    (o, s), took = _took_kernel(gated_delta_rule, *args)
+    o_ref, s_ref = _jit(gated_delta_recurrence)(*args)
+    (o, s), took = _took_kernel(_jit(gated_delta_rule), *args)
     assert took == (lowering == "kernel")
     assert o.shape == o_ref.shape == args[2].shape
     assert float(jnp.abs(o - o_ref).max()) < 2e-5
@@ -95,9 +101,9 @@ def _loss(fn):
 @pytest.mark.parametrize("t,decay,beta", CASES)
 def test_chunked_gradients_equal_recurrence(lowering, t, decay, beta):
     args = _sized(lowering, t, decay, beta, seed=1)
-    want = jax.grad(_loss(gated_delta_recurrence), argnums=range(5))(*args)
+    want = _jit(jax.grad(_loss(gated_delta_recurrence), argnums=range(5)))(*args)
     got, took = _took_kernel(
-        jax.grad(_loss(gated_delta_rule), argnums=range(5)), *args)
+        _jit(jax.grad(_loss(gated_delta_rule), argnums=range(5))), *args)
     assert took == (lowering == "kernel")
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert a.shape == b.shape, name
@@ -113,9 +119,9 @@ def test_bfloat16_inputs_keep_a_float32_state(lowering):
     them): 3e-2 of the largest output."""
     q, k, v, g, beta = _sized(lowering, 200, 1.0, None, seed=2)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    o, state = gated_delta_rule(qb, kb, vb, g, beta)
+    o, state = _jit(gated_delta_rule)(qb, kb, vb, g, beta)
     assert o.dtype == jnp.bfloat16 and state.dtype == jnp.float32
-    o_ref, s_ref = gated_delta_recurrence(qb, kb, vb, g, beta)
+    o_ref, s_ref = _jit(gated_delta_recurrence)(qb, kb, vb, g, beta)
     assert float(jnp.abs(o.astype(jnp.float32) - o_ref).max()) \
         < 3e-2 * float(jnp.abs(o_ref).max())
     assert float(jnp.abs(state - s_ref).max()) \
@@ -128,8 +134,8 @@ def test_bfloat16_gradients_keep_their_operands_dtype(lowering):
     of the float32 recurrence's on the same rounded inputs."""
     q, k, v, g, beta = _sized(lowering, 200, 1.0, None, seed=3)
     args = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, beta)
-    want = jax.grad(_loss(gated_delta_recurrence), argnums=range(5))(*args)
-    got = jax.grad(_loss(gated_delta_rule), argnums=range(5))(*args)
+    want = _jit(jax.grad(_loss(gated_delta_recurrence), argnums=range(5)))(*args)
+    got = _jit(jax.grad(_loss(gated_delta_rule), argnums=range(5)))(*args)
     for name, a, b, x in zip("q k v g beta".split(), got, want, args):
         assert a.dtype == x.dtype, name
         scale = float(jnp.abs(b.astype(jnp.float32)).max())

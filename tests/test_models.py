@@ -18,8 +18,8 @@ def _count(params):
 def test_slow_r50_forward_and_param_count():
     model = SlowR50(num_classes=10)
     x = jnp.zeros((2, 8, 64, 64, 3))
-    variables = model.init(jax.random.key(0), x)
-    out = model.apply(variables, x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
+    out = jax.eval_shape(model.apply, variables, x)
     assert out.shape == (2, 10)
     n = _count(variables["params"])
     # 3D ResNet-50 backbone is ~31.7M; head adds 2048*10. Sanity band.
@@ -32,7 +32,7 @@ def test_slow_r50_feature_widths():
     porting."""
     model = SlowR50(num_classes=4)
     x = jnp.zeros((1, 4, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
     kernel = variables["params"]["head"]["proj"]["kernel"]
     assert kernel.shape == (2048, 4)
 
@@ -41,8 +41,8 @@ def test_slowfast_forward_and_head_width():
     model = SlowFast(num_classes=7)
     slow = jnp.zeros((2, 2, 64, 64, 3))
     fast = jnp.zeros((2, 8, 64, 64, 3))
-    variables = model.init(jax.random.key(0), (slow, fast))
-    out = model.apply(variables, (slow, fast))
+    variables = jax.eval_shape(model.init, jax.random.key(0), (slow, fast))
+    out = jax.eval_shape(model.apply, variables, (slow, fast))
     assert out.shape == (2, 7)
     # concat(2048 slow, 256 fast) = 2304 = reference in_features (run.py:109)
     kernel = variables["params"]["head"]["proj"]["kernel"]
@@ -56,22 +56,22 @@ def test_slowfast_temporal_shapes_respect_alpha():
     model = SlowFast(num_classes=3, alpha=4)
     slow = jnp.zeros((1, 2, 32, 32, 3))
     fast = jnp.zeros((1, 8, 32, 32, 3))
-    variables = model.init(jax.random.key(0), (slow, fast))
-    out = model.apply(variables, (slow, fast))
+    variables = jax.eval_shape(model.init, jax.random.key(0), (slow, fast))
+    out = jax.eval_shape(model.apply, variables, (slow, fast))
     assert out.shape == (1, 3)
 
 
 def test_dropout_train_mode_needs_rng():
     model = SlowR50(num_classes=5, dropout_rate=0.5)
     x = jnp.ones((1, 4, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x)
-    out, updates = model.apply(
-        variables,
+    variables = jax.jit(model.init)(jax.random.key(0), x)
+    out, updates = jax.jit(lambda v, x: model.apply(
+        v,
         x,
         train=True,
         rngs={"dropout": jax.random.key(1)},
         mutable=["batch_stats"],
-    )
+    ))(variables, x)
     assert out.shape == (1, 5)
     assert "batch_stats" in updates
 
@@ -79,11 +79,11 @@ def test_dropout_train_mode_needs_rng():
 def test_batch_stats_update_in_train_mode():
     model = SlowR50(num_classes=2)
     x = jnp.ones((2, 4, 32, 32, 3)) * 3.0
-    variables = model.init(jax.random.key(0), x)
-    _, updates = model.apply(
-        variables, x, train=True,
+    variables = jax.jit(model.init)(jax.random.key(0), x)
+    _, updates = jax.jit(lambda v, x: model.apply(
+        v, x, train=True,
         rngs={"dropout": jax.random.key(1)}, mutable=["batch_stats"],
-    )
+    ))(variables, x)
     before = variables["batch_stats"]["stem"]["norm"]["mean"]
     after = updates["batch_stats"]["stem"]["norm"]["mean"]
     assert not np.allclose(np.asarray(before), np.asarray(after))
@@ -108,10 +108,10 @@ def test_backbone_filter():
 def test_bf16_compute_fp32_params():
     model = create_model(ModelConfig(name="slow_r50", num_classes=3), "bf16")
     x = jnp.zeros((1, 4, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
     # params stay fp32; logits come out fp32 (head projects in fp32)
     assert variables["params"]["stem"]["conv"]["kernel"].dtype == jnp.float32
-    out = model.apply(variables, x)
+    out = jax.eval_shape(model.apply, variables, x)
     assert out.dtype == jnp.float32
 
 
@@ -131,8 +131,8 @@ def test_r2plus1d_forward_param_count_and_geometry():
     tiny = R2Plus1D(num_classes=6, depths=(1, 1), stem_features=8,
                     spatial_strides=(2, 2), temporal_strides=(1, 2))
     x = jnp.zeros((2, 4, 32, 32, 3))
-    v = tiny.init(jax.random.key(0), x)
-    out = tiny.apply(v, x)
+    v = jax.jit(tiny.init)(jax.random.key(0), x)
+    out = jax.jit(tiny.apply)(v, x)
     assert out.shape == (2, 6)
     assert tiny.backbone_param_filter(("res2_block0", "conv_a"))
     assert not tiny.backbone_param_filter(("head", "proj"))
@@ -160,8 +160,8 @@ def test_csn_r101_forward_param_count_and_geometry():
     tiny = CSN(num_classes=6, depths=(1, 1), stem_features=8,
                spatial_strides=(1, 2), temporal_strides=(1, 2))
     x = jnp.zeros((2, 4, 32, 32, 3))
-    v = tiny.init(jax.random.key(0), x)
-    out = tiny.apply(v, x)
+    v = jax.jit(tiny.init)(jax.random.key(0), x)
+    out = jax.jit(tiny.apply)(v, x)
     assert out.shape == (2, 6)
     assert tiny.backbone_param_filter(("res2", "block0", "conv_b"))
     assert not tiny.backbone_param_filter(("head", "proj"))
@@ -206,6 +206,6 @@ def test_c2d_r50_param_count_and_detection():
                    temporal_kernels=(1, 1), stage1_temporal_pool=True,
                    dropout_rate=0.0)
     x = jnp.zeros((1, 4, 32, 32, 3))
-    v = tiny.init(jax.random.key(0), x)
-    out = tiny.apply(v, x)
+    v = jax.jit(tiny.init)(jax.random.key(0), x)
+    out = jax.jit(tiny.apply)(v, x)
     assert out.shape == (1, 3)

@@ -21,8 +21,8 @@ def test_round_width():
 def test_x3d_forward_and_params():
     model = X3D(num_classes=7, depths=(1, 1, 1, 1), dropout_rate=0.0)
     x = jnp.zeros((2, 4, 64, 64, 3))
-    variables = model.init(jax.random.key(0), x)
-    out = model.apply(variables, x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
+    out = jax.eval_shape(model.apply, variables, x)
     assert out.shape == (2, 7)
 
 
@@ -31,7 +31,7 @@ def test_x3d_s_param_count():
     sanity band with a 700-class head."""
     model = X3D(num_classes=700)
     x = jnp.zeros((1, 4, 64, 64, 3))
-    variables = model.init(jax.random.key(0), x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
     n = _count(variables["params"])
     assert 3e6 < n < 7e6, n
 
@@ -43,7 +43,8 @@ def test_x3d_l_registry_and_param_count():
 
     model = create_model(ModelConfig(name="x3d_l", num_classes=400), "bf16")
     assert model.depths == (5, 10, 25, 15)
-    variables = model.init(jax.random.key(0), jnp.zeros((1, 4, 64, 64, 3)))
+    variables = jax.eval_shape(model.init, jax.random.key(0),
+                               jnp.zeros((1, 4, 64, 64, 3)))
     n = _count(variables["params"])
     assert 5e6 < n < 8e6, n
 
@@ -52,8 +53,8 @@ def test_mvit_multiscale_geometry():
     """Grid halves spatially at each stage; dims 96->192->384->768."""
     model = MViT(num_classes=5, depth=16, drop_path_rate=0.0, dropout_rate=0.0)
     x = jnp.zeros((1, 8, 64, 64, 3))
-    variables = model.init(jax.random.key(0), x)
-    out = model.apply(variables, x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
+    out = jax.eval_shape(model.apply, variables, x)
     assert out.shape == (1, 5)
     p = variables["params"]
     # final block dim = 768 (96 * 2^3)
@@ -91,7 +92,7 @@ def test_mvit_b_param_count():
     """MViT-B/16 is ~36.6M (paper Table 2)."""
     model = MViT(num_classes=400)
     x = jnp.zeros((1, 8, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x)
+    variables = jax.eval_shape(model.init, jax.random.key(0), x)
     n = _count(variables["params"])
     assert 30e6 < n < 45e6, n
 
@@ -100,9 +101,9 @@ def test_mvit_droppath_train_mode():
     model = MViT(num_classes=3, depth=4, stage_starts=(1, 2, 3),
                  drop_path_rate=0.5, dropout_rate=0.5)
     x = jnp.ones((2, 4, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x)
-    out = model.apply(variables, x, train=True,
-                      rngs={"dropout": jax.random.key(1)})
+    variables = jax.jit(model.init)(jax.random.key(0), x)
+    out = jax.jit(lambda v, x, rng: model.apply(
+        v, x, train=True, rngs={"dropout": rng}))(variables, x, jax.random.key(1))
     assert out.shape == (2, 3)
     assert np.all(np.isfinite(np.asarray(out)))
 
@@ -118,16 +119,16 @@ class TestRemat:
         x = np.random.default_rng(0).standard_normal(
             (2, 4, 32, 32, 3)).astype(np.float32)
         m0, m1 = mk(False), mk(True)
-        v = m0.init({"params": jax.random.key(0), "mask": jax.random.key(1)},
-                    jnp.asarray(x))
+        v = jax.jit(m0.init)({"params": jax.random.key(0),
+                              "mask": jax.random.key(1)}, jnp.asarray(x))
 
         def loss(m, p):
             out = m.apply({"params": p}, jnp.asarray(x),
                           rngs={"mask": jax.random.key(2)})
             return out["loss"] if isinstance(out, dict) else jnp.sum(out)
 
-        l0, g0 = jax.value_and_grad(lambda p: loss(m0, p))(v["params"])
-        l1, g1 = jax.value_and_grad(lambda p: loss(m1, p))(v["params"])
+        l0, g0 = jax.jit(jax.value_and_grad(lambda p: loss(m0, p)))(v["params"])
+        l1, g1 = jax.jit(jax.value_and_grad(lambda p: loss(m1, p)))(v["params"])
         np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(

@@ -308,7 +308,7 @@ def test_step_counts_the_layers_that_fit_the_tight_buffers(monkeypatch,
     mesh = make_train_mesh(MeshConfig(data=len(jax.devices())))
     tokens = jax.random.randint(jax.random.key(1), (8, 64), 0, 256)
     assert moe.buffer_rows(tokens.size, 2, 2, 8) == 512
-    variables = model.init(jax.random.key(0), tokens[:1])
+    variables = jax.jit(model.init)(jax.random.key(0), tokens[:1])
     state = TrainState.create(variables["params"], {}, tx)
     _, metrics = make_lm_step(model, tx, mesh)(state, {"tokens": tokens},
                                                jax.random.key(0))

@@ -41,7 +41,7 @@ POLICIES = [pytest.param("fp32", 1e-5, 1e-6, id="float32_tight"),
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(ARCH, 3)
+    return jax.jit(lambda: ref.init_params(ARCH, 3))()
 
 
 @pytest.fixture(scope="module")
@@ -73,17 +73,18 @@ def _leaves(tree):
 
 @pytest.fixture(scope="module")
 def want(params, tokens):
-    """The reference's loss, rows and gradient (float32)."""
+    """The reference's loss, rows, gradient and logits (float32)."""
     (loss, rows), grads = jax.jit(jax.value_and_grad(
         lambda p: ref.loss_and_rows(p, tokens, ARCH), has_aux=True))(params)
-    return loss, rows, grads
+    return loss, rows, grads, jax.jit(lambda p: ref.logits(p, tokens, ARCH))(params)
 
 
 def test_registry_declares_the_task_and_the_tree_is_the_references(params):
     assert model_task("nemotron_h_t") == model_task(
         "nemotron_3_nano_30b_a3b") == "next_token"
     model = _model()
-    made = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    made = jax.jit(model.init)(jax.random.key(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"]
     got = {n: x.shape for n, x in _leaves(made).items()}
     assert got == {n: x.shape for n, x in _leaves(params).items()}
     assert [k for k in made if k.startswith("layer_")] == [
@@ -109,13 +110,12 @@ def test_registry_declares_the_task_and_the_tree_is_the_references(params):
 def test_logits_and_loss_against_the_reference(params, tokens, want, policy,
                                                logit_tol, loss_tol):
     model = _model(policy)
-    want_logits = ref.logits(params, tokens, ARCH)
+    want_loss, want_rows, _, want_logits = want
     got = jax.jit(lambda p: model.apply({"params": p}, tokens))(params)
     assert got.dtype == jnp.float32 and got.shape == (2, T, 256)
     assert float(jnp.abs(got - want_logits).max()) < \
         logit_tol * float(jnp.abs(want_logits).max())
     loss, out = jax.jit(lambda p: _program_loss(model, p, tokens))(params)
-    want_loss, want_rows, _ = want
     assert abs(float(loss) - float(want_loss)) < loss_tol * float(want_loss)
     if policy == "fp32":   # the same routing, token for token
         assert bool(jnp.all(out["expert_rows"] == want_rows))
@@ -141,14 +141,22 @@ def test_every_leafs_gradient_against_the_reference(params, tokens, want,
             assert abs(float(jnp.linalg.norm(g)) - norm) <= 0.1 * norm, name
 
 
+@pytest.fixture(scope="module")
+def faulty(tokens):
+    """The reference's loss and gradient with a fault switched on by a
+    traced vector over `ref.SWITCHED`: one compile serves every fault."""
+    return jax.jit(jax.value_and_grad(
+        lambda p, flags: ref.loss_and_rows(p, tokens, ARCH, flags=flags)[0]))
+
+
 @pytest.mark.parametrize("fault", ref.FAULTS)
-def test_planted_fault_is_told_apart_from_the_sound_program(params, tokens,
-                                                            want, fault):
+def test_planted_fault_is_told_apart_from_the_sound_program(params, want,
+                                                            faulty, fault):
     """Each of the family's faults moves the toy's loss or a gradient leaf by
     far more than the float32 tolerance above (1e-5)."""
-    want_loss, _, want_grads = want
-    loss, grads = jax.jit(jax.value_and_grad(
-        lambda p: ref.loss_and_rows(p, tokens, ARCH, fault=fault)[0]))(params)
+    want_loss, _, want_grads, _ = want
+    loss, grads = faulty(params, jnp.array([name == fault
+                                            for name in ref.SWITCHED]))
     expected = _leaves(want_grads)
     moved = max(float(jnp.abs(g - expected[n]).max())
                 / max(float(jnp.abs(expected[n]).max()), 1e-30)
@@ -202,8 +210,9 @@ def test_two_product_relu2_experts_against_a_dense_loop():
     w_down = 0.3 * jax.random.normal(k[2], (held, f, d))
     weights, experts = moe.route(x, jax.random.normal(k[3], (d, 8)), 2,
                                  score="sigmoid", scale=2.5)
-    y, rows, _ = moe.expert_share(x, weights, experts, None, w_up, w_down, 2, 8,
-                                  activation=nemotron_h.relu_squared)
+    y, rows, _ = jax.jit(lambda *a: moe.expert_share(
+        *a, None, w_up, w_down, 2, 8, activation=nemotron_h.relu_squared))(
+            x, weights, experts)
     want = jnp.zeros_like(x)
     for j in range(held):
         w_e = jnp.sum(jnp.where(experts == 2 + j, weights, 0.0), axis=-1)
@@ -234,15 +243,15 @@ def test_shares_add_up_to_the_uncut_reference_layer(shares):
             nemotron_h.NemotronHArch(**{**arch.__dict__, "experts_held": per,
                                         "expert_offset": s * per}),
             jnp.float32)
-        y, _ = layer.apply({"params": cut}, x)
+        y, _ = jax.jit(layer.apply)({"params": cut}, x)
         total = total + y
         flat = x.reshape(-1, 64)
         shared = (jnp.square(jax.nn.relu(flat @ whole["shared_up_proj"]))
                   @ whole["shared_down_proj"]).reshape(x.shape)
     routed = total - shares * shared + shared          # the shared part once
-    want, _ = ref.mixture(whole, x.reshape(-1, 64),
-                          {**ARCH, "experts_held": 8}, None, False, False,
-                          ref.switches())
+    want, _ = jax.jit(lambda w, x: ref.mixture(
+        w, x, {**ARCH, "experts_held": 8}, None, False, False,
+        ref.switches()))(whole, x.reshape(-1, 64))
     assert float(jnp.abs(routed - want.reshape(x.shape)).max()) < \
         1e-5 * float(jnp.abs(want).max())
 

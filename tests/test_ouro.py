@@ -42,7 +42,7 @@ FAULTS = ("one_pass", "norm_once", "pre_norm_only", "gate_ignored",
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(ARCH, 3)
+    return jax.jit(lambda: ref.init_params(ARCH, 3))()
 
 
 @pytest.fixture(scope="module")
@@ -51,8 +51,13 @@ def tokens():
 
 
 @pytest.fixture(scope="module")
-def want_grads(params, tokens):
-    return jax.grad(lambda p: ref.loss_and_ut(p, tokens, ARCH)[0])(params)
+def want(params, tokens):
+    """The reference's last pass's logits, loss, by-pass means and gradient
+    (float32)."""
+    logits = jax.jit(lambda p: ref.logits(p, tokens, ARCH))(params)
+    (loss, ut), grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss_and_ut(p, tokens, ARCH), has_aux=True))(params)
+    return logits, loss, ut, grads
 
 
 def _model(policy="fp32"):
@@ -92,25 +97,26 @@ def test_registry_declares_the_task_and_the_tree_is_the_references(params):
     assert got["['exit_gate']"] == (64,) and got["['exit_bias']"] == ()
     # the program's own initialiser: every matrix N(0, 0.02), the embedding
     # too (the benchmark's unit-variance embedding is its reference's)
-    made = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    made = jax.jit(model.init)(jax.random.key(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"]
     for name in ("embed", "lm_head"):
         assert 0.018 < float(jnp.std(made[name])) < 0.022, name
     assert float(made["exit_bias"]) == 0.0
 
 
 @pytest.mark.parametrize("policy,logit_tol,loss_tol", POLICIES)
-def test_logits_and_loss_against_the_reference(params, tokens, policy,
+def test_logits_and_loss_against_the_reference(params, tokens, want, policy,
                                                logit_tol, loss_tol):
     model = _model(policy)
-    want = ref.logits(params, tokens, ARCH)       # the LAST pass's
+    want, want_loss, want_ut, _ = want            # the LAST pass's logits
     # (the reference's scan over the passes is its Python loop over them)
-    assert float(jnp.abs(ref.logits(params, tokens, ARCH, remat=False)
-                         - want).max()) < 1e-5 * float(jnp.abs(want).max())
-    got = model.apply({"params": params}, tokens)
+    assert float(jnp.abs(jax.jit(lambda p: ref.logits(
+        p, tokens, ARCH, remat=False))(params) - want).max()) < \
+        1e-5 * float(jnp.abs(want).max())
+    got = jax.jit(lambda p: model.apply({"params": p}, tokens))(params)
     assert got.dtype == jnp.float32 and got.shape == (2, T, 256)
     assert float(jnp.abs(got - want).max()) < logit_tol * float(jnp.abs(want).max())
-    loss, out = _program_loss(model, params, tokens)
-    want_loss, want_ut = ref.loss_and_ut(params, tokens, ARCH)
+    loss, out = jax.jit(lambda p: _program_loss(model, p, tokens))(params)
     assert abs(float(loss) - float(want_loss)) < loss_tol * float(want_loss)
     for name in ("exit_mass", "loss"):            # by pass, what the logger gets
         got_ut = out["ut"][name] / out["count"]
@@ -120,11 +126,12 @@ def test_logits_and_loss_against_the_reference(params, tokens, policy,
 
 
 @pytest.mark.parametrize("policy", ["fp32", "bf16"])
-def test_every_leafs_gradient_against_the_reference(params, tokens, want_grads,
+def test_every_leafs_gradient_against_the_reference(params, tokens, want,
                                                     policy):
     model = _model(policy)
-    got = _leaves(jax.grad(lambda p: _program_loss(model, p, tokens)[0])(params))
-    want = _leaves(want_grads)
+    got = _leaves(jax.jit(jax.grad(
+        lambda p: _program_loss(model, p, tokens)[0]))(params))
+    want = _leaves(want[3])
     assert got.keys() == want.keys() and len(want) == 2 * 11 + 5
     for name, w in want.items():
         g = got[name]
@@ -137,14 +144,14 @@ def test_every_leafs_gradient_against_the_reference(params, tokens, want_grads,
 
 @pytest.mark.parametrize("fault", FAULTS)
 def test_planted_fault_is_told_apart_from_the_sound_program(params, tokens,
-                                                            want_grads, fault):
+                                                            want, fault):
     """Each of the loop's faults moves the toy's loss or a gradient leaf by
     far more than the float32 tolerance above (1e-5), so the comparison can
     tell it from the sound program (read: 1.0 to 3.2 of a leaf's largest
     entry)."""
-    want_loss, _ = ref.loss_and_ut(params, tokens, ARCH)
-    loss, grads = jax.value_and_grad(
-        lambda p: ref.loss_and_ut(p, tokens, ARCH, fault=fault)[0])(params)
+    _, want_loss, _, want_grads = want
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss_and_ut(p, tokens, ARCH, fault=fault)[0]))(params)
     want = _leaves(want_grads)
     moved = max(float(jnp.abs(g - want[n]).max()) / float(jnp.abs(want[n]).max())
                 for n, g in _leaves(grads).items())
@@ -174,9 +181,10 @@ def test_shared_leafs_gradient_is_the_sum_over_the_passes(params, tokens):
 
     rest = {k: v for k, v in params.items() if k != "stack"}
     copies = [params["stack"]] * arch.total_ut_steps
-    loss, (per_pass, _) = jax.value_and_grad(untied, argnums=(0, 1))(copies, rest)
-    tied_loss, tied = jax.value_and_grad(
-        lambda p: _program_loss(model, p, tokens)[0])(params)
+    loss, (per_pass, _) = jax.jit(jax.value_and_grad(
+        untied, argnums=(0, 1)))(copies, rest)
+    tied_loss, tied = jax.jit(jax.value_and_grad(
+        lambda p: _program_loss(model, p, tokens)[0]))(params)
     assert abs(float(loss) - float(tied_loss)) < 1e-6 * float(tied_loss)
     assert len(per_pass) == 3
     summed = jax.tree.map(lambda *g: sum(g), *per_pass)
@@ -191,15 +199,16 @@ def test_shared_leafs_gradient_is_the_sum_over_the_passes(params, tokens):
 def test_one_pass_without_entropy_is_a_plain_decoders_loss(params, tokens):
     arch = ouro.OuroArch(**{**ARCH, "total_ut_steps": 1, "exit_entropy_beta": 0.0})
     model = ouro.Ouro(arch, dtype=jnp.float32)
-    loss, out = _program_loss(model, params, tokens)
-    logits = model.apply({"params": params}, tokens)
+    loss, out = jax.jit(lambda p: _program_loss(model, p, tokens))(params)
+    logits = jax.jit(lambda p: model.apply({"params": p}, tokens))(params)
     targets, weights = _scored(tokens)
     ce = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
     plain = (ce * weights).sum() / weights.sum()
     assert abs(float(loss) - float(plain)) < 1e-6 * float(plain)
     assert float(out["ut"]["exit_mass"][0]) == pytest.approx(float(out["count"]))
     # lm_common's one-pass loss, which the other families train on, agrees
-    hidden = ref.trunk(params, tokens, {**ARCH, "total_ut_steps": 1})[0]
+    hidden = jax.jit(lambda p: ref.trunk(
+        p, tokens, {**ARCH, "total_ut_steps": 1})[0])(params)
     summed, _hits = lm_common.next_token_loss(
         hidden.reshape(-1, 64), params["lm_head"], targets.reshape(-1),
         weights.reshape(-1), 64)
@@ -307,7 +316,7 @@ cfg = config_from_dict({{
     "tracking": {{"with_tracking": True, "trackers": "jsonl",
                   "logging_dir": {out!r} + "/runs", "log_every": 1}}}})
 trainer = Trainer(cfg)
-params0 = ref.init_params(arch, 4)
+params0 = jax.jit(lambda: ref.init_params(arch, 4))()
 trainer.state = trainer.state.replace(params=jax.tree.map(jnp.copy, params0))
 batches = [{{"tokens": jnp.asarray(b["tokens"])}}
            for b in trainer.train_loader.epoch(0)]
@@ -327,7 +336,8 @@ fit = trainer.fit()
 optim = {{"lr": 3e-3, "weight_decay": 0.1, "grad_clip_norm": 1.0,
           "total_steps": 3}}
 want = ref.follow(arch, optim, params0, batches)
-ut = [jax.device_get(ref.loss_and_ut(params0, batches[0]["tokens"], arch)[1])]
+ut = [jax.device_get(jax.jit(lambda p, t: ref.loss_and_ut(p, t, arch)[1])(
+    params0, batches[0]["tokens"]))]
 print(json.dumps({{"task": trainer.task, "steps": fit["steps"], "logged": logged,
                    "losses": want["losses"], "pairs": want["pairs"],
                    "exit_mass": [float(x) for x in ut[0]["exit_mass"]],

@@ -86,8 +86,8 @@ def test_grad_matches_dense():
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v) ** 2)
 
-    g = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for got, want, name in zip(g, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-4, rtol=1e-4, err_msg=f"d{name}")
@@ -97,10 +97,11 @@ def test_grad_ragged_lengths():
     import jax
 
     q, k, v = _qkv(B=1, Nq=50, Nk=77, H=2, D=16)
-    g = jax.grad(lambda q, k, v: jnp.sum(
-        flash_attention(q, k, v, block_q=32, block_k=32) ** 2), argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(lambda q, k, v: jnp.sum(
-        dense_attention(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, block_q=32, block_k=32) ** 2),
+        argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        dense_attention(q, k, v) ** 2), argnums=(0, 1, 2)))(q, k, v)
     for got, want, name in zip(g, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-4, rtol=1e-4, err_msg=f"d{name}")

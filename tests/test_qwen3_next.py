@@ -42,12 +42,21 @@ POLICIES = [pytest.param("fp32", 1e-5, 1e-6, id="float32_tight"),
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(ARCH, 3)
+    return jax.jit(lambda: ref.init_params(ARCH, 3))()
 
 
 @pytest.fixture(scope="module")
 def tokens():
     return jax.random.randint(jax.random.key(1), (2, T), 0, ARCH["vocab_size"])
+
+
+@pytest.fixture(scope="module")
+def want(params, tokens):
+    """The reference's logits, loss, rows and gradient (float32)."""
+    logits = jax.jit(lambda p: ref.logits(p, tokens, ARCH))(params)
+    (loss, rows), grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss_and_rows(p, tokens, ARCH), has_aux=True))(params)
+    return logits, loss, rows, grads
 
 
 def _model(policy):
@@ -82,15 +91,14 @@ def test_registry_declares_the_task_and_the_tree_is_the_references(params):
 
 
 @pytest.mark.parametrize("policy,logit_tol,loss_tol", POLICIES)
-def test_logits_and_loss_against_the_reference(params, tokens, policy,
+def test_logits_and_loss_against_the_reference(params, tokens, want, policy,
                                                logit_tol, loss_tol):
     model = _model(policy)
-    want = ref.logits(params, tokens, ARCH)
-    got = model.apply({"params": params}, tokens)
+    want, want_loss, want_rows, _ = want
+    got = jax.jit(lambda p: model.apply({"params": p}, tokens))(params)
     assert got.dtype == jnp.float32 and got.shape == (2, T, 256)
     assert float(jnp.abs(got - want).max()) < logit_tol * float(jnp.abs(want).max())
-    loss, rows = _program_loss(model, params, tokens)
-    want_loss, want_rows = ref.loss_and_rows(params, tokens, ARCH)
+    loss, rows = jax.jit(lambda p: _program_loss(model, p, tokens))(params)
     assert abs(float(loss) - float(want_loss)) < loss_tol * float(want_loss)
     if policy == "fp32":
         assert bool(jnp.all(rows == want_rows))  # the same routing, pair by pair
@@ -99,10 +107,12 @@ def test_logits_and_loss_against_the_reference(params, tokens, policy,
 
 
 @pytest.mark.parametrize("policy", ["fp32", "bf16"])
-def test_every_leafs_gradient_against_the_reference(params, tokens, policy):
+def test_every_leafs_gradient_against_the_reference(params, tokens, want,
+                                                    policy):
     model = _model(policy)
-    got = jax.grad(lambda p: _program_loss(model, p, tokens)[0])(params)
-    want = jax.grad(lambda p: ref.loss_and_rows(p, tokens, ARCH)[0])(params)
+    got = jax.jit(jax.grad(
+        lambda p: _program_loss(model, p, tokens)[0]))(params)
+    want = want[3]
     got, want = (dict(jax.tree_util.tree_flatten_with_path(t)[0]) for t in (got, want))
     assert got.keys() == want.keys() and len(want) == 70
     for path, w in want.items():
@@ -161,7 +171,7 @@ cfg = config_from_dict({{
     "tracking": {{"with_tracking": True, "trackers": "jsonl",
                   "logging_dir": {out!r} + "/runs", "log_every": 1}}}})
 trainer = Trainer(cfg)
-params0 = ref.init_params(arch, 4)
+params0 = jax.jit(lambda: ref.init_params(arch, 4))()
 trainer.state = trainer.state.replace(params=jax.tree.map(jnp.copy, params0))
 batches = [{{"tokens": jnp.asarray(b["tokens"])}}
            for b in trainer.train_loader.epoch(0)]

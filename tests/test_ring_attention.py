@@ -105,7 +105,7 @@ def test_mvit_with_ring_backend_under_jit(cp_mesh):
     model = create_model(cfg, "fp32", mesh=cp_mesh)
     # tiny clip: 4 frames 32^2 -> token grid (2, 8, 8) = 128 tokens, /8 devices
     x = jnp.zeros((2, 4, 32, 32, 3), jnp.float32)
-    variables = model.init(jax.random.key(0), x)
+    variables = jax.jit(model.init)(jax.random.key(0), x)
     with cp_mesh:
         out = jax.jit(lambda v, x: model.apply(v, x))(variables, x)
     assert out.shape == (2, 5)

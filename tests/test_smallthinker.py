@@ -42,7 +42,7 @@ FAULTS = ("window_ignored", "rope_everywhere", "router_after_attention",
 
 @pytest.fixture(scope="module")
 def params():
-    return ref.init_params(ARCH, 3)
+    return jax.jit(lambda: ref.init_params(ARCH, 3))()
 
 
 @pytest.fixture(scope="module")
@@ -51,8 +51,12 @@ def tokens():
 
 
 @pytest.fixture(scope="module")
-def want_grads(params, tokens):
-    return jax.grad(lambda p: ref.loss_and_rows(p, tokens, ARCH)[0])(params)
+def want(params, tokens):
+    """The reference's logits, loss, rows and gradient (float32)."""
+    logits = jax.jit(lambda p: ref.logits(p, tokens, ARCH))(params)
+    (loss, rows), grads = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss_and_rows(p, tokens, ARCH), has_aux=True))(params)
+    return logits, loss, rows, grads
 
 
 def _model(policy):
@@ -90,21 +94,21 @@ def test_registry_declares_the_task_and_the_tree_is_the_references(params):
     assert "['mixture_3']['moe']['w_gate']" in got
     # the program's own initialiser: every matrix N(0, 0.02), the embedding
     # too (the benchmark's unit-variance embedding is its reference's)
-    made = model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    made = jax.jit(model.init)(jax.random.key(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"]
     for name in ("embed", "lm_head"):
         assert 0.018 < float(jnp.std(made[name])) < 0.022, name
 
 
 @pytest.mark.parametrize("policy,logit_tol,loss_tol", POLICIES)
-def test_logits_and_loss_against_the_reference(params, tokens, policy,
+def test_logits_and_loss_against_the_reference(params, tokens, want, policy,
                                                logit_tol, loss_tol):
     model = _model(policy)
-    want = ref.logits(params, tokens, ARCH)
-    got = model.apply({"params": params}, tokens)
+    want, want_loss, want_rows, _ = want
+    got = jax.jit(lambda p: model.apply({"params": p}, tokens))(params)
     assert got.dtype == jnp.float32 and got.shape == (2, T, 256)
     assert float(jnp.abs(got - want).max()) < logit_tol * float(jnp.abs(want).max())
-    loss, rows = _program_loss(model, params, tokens)
-    want_loss, want_rows = ref.loss_and_rows(params, tokens, ARCH)
+    loss, rows = jax.jit(lambda p: _program_loss(model, p, tokens))(params)
     assert abs(float(loss) - float(want_loss)) < loss_tol * float(want_loss)
     if policy == "fp32":
         assert bool(jnp.all(rows == want_rows))  # the same routing, pair by pair
@@ -113,11 +117,12 @@ def test_logits_and_loss_against_the_reference(params, tokens, policy,
 
 
 @pytest.mark.parametrize("policy", ["fp32", "bf16"])
-def test_every_leafs_gradient_against_the_reference(params, tokens, want_grads,
+def test_every_leafs_gradient_against_the_reference(params, tokens, want,
                                                     policy):
     model = _model(policy)
-    got = _leaves(jax.grad(lambda p: _program_loss(model, p, tokens)[0])(params))
-    want = _leaves(want_grads)
+    got = _leaves(jax.jit(jax.grad(
+        lambda p: _program_loss(model, p, tokens)[0]))(params))
+    want = _leaves(want[3])
     assert got.keys() == want.keys() and len(want) == 43
     for name, w in want.items():
         g = got[name]
@@ -130,15 +135,15 @@ def test_every_leafs_gradient_against_the_reference(params, tokens, want_grads,
 
 @pytest.mark.parametrize("fault", FAULTS)
 def test_planted_fault_is_told_apart_from_the_sound_program(params, tokens,
-                                                            want_grads, fault):
+                                                            want, fault):
     """Each of the family's faults moves the toy's loss or a gradient leaf by
     far more than the float32 tolerance above (1e-5), so the comparison can
     tell it from the sound program (read: 0.8 to 1.4 of a leaf's largest
     entry)."""
-    want_loss, _ = ref.loss_and_rows(params, tokens, ARCH)
-    (loss, _), grads = jax.value_and_grad(
+    _, want_loss, _, want_grads = want
+    (loss, _), grads = jax.jit(jax.value_and_grad(
         lambda p: ref.loss_and_rows(p, tokens, ARCH, fault=fault),
-        has_aux=True)(params)
+        has_aux=True))(params)
     want = _leaves(want_grads)
     moved = max(float(jnp.abs(g - want[n]).max()) / float(jnp.abs(want[n]).max())
                 for n, g in _leaves(grads).items())
@@ -167,7 +172,7 @@ cfg = config_from_dict({{
     "tracking": {{"with_tracking": True, "trackers": "jsonl",
                   "logging_dir": {out!r} + "/runs", "log_every": 1}}}})
 trainer = Trainer(cfg)
-params0 = ref.init_params(arch, 4)
+params0 = jax.jit(lambda: ref.init_params(arch, 4))()
 trainer.state = trainer.state.replace(params=jax.tree.map(jnp.copy, params0))
 batches = [{{"tokens": jnp.asarray(b["tokens"])}}
            for b in trainer.train_loader.epoch(0)]

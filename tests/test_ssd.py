@@ -31,7 +31,7 @@ def _rel(got, want):
                          ids=["tail_of_22", "one_whole_chunk", "shorter_than_a_chunk"])
 def test_chunked_form_equals_the_recurrence(t, chunk):
     args = _inputs(t)
-    want_y, want_state = ssd.ssd_recurrence(*args)
+    want_y, want_state = jax.jit(ssd.ssd_recurrence)(*args)
     y, state = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=chunk))(*args)
     assert y.shape == (B, t, H, P) and y.dtype == jnp.float32
     assert _rel(y, want_y) < 1e-5
@@ -61,6 +61,7 @@ def test_heads_read_their_groups_b_and_c():
     x, dt, a, b, c, d = _inputs(96, seed=2)
     moved = b.at[:, :, 1].multiply(3.0)
     for fn in (ssd.ssd_recurrence, lambda *v: ssd.ssd_scan(*v, chunk=32)):
+        fn = jax.jit(fn)
         y0, _ = fn(x, dt, a, b, c, d)
         y1, _ = fn(x, dt, a, moved, c, d)
         diff = jnp.abs(y1 - y0).max(axis=(0, 1, 3))      # by head
@@ -72,7 +73,7 @@ def test_bfloat16_operands_keep_float32_state():
     args32 = _inputs(150, seed=3)
     x, dt, a, b, c, d = _inputs(150, seed=3, dtype=jnp.bfloat16)
     y, state = jax.jit(lambda *v: ssd.ssd_scan(*v, chunk=32))(x, dt, a, b, c, d)
-    want, _ = ssd.ssd_recurrence(*args32)
+    want, _ = jax.jit(ssd.ssd_recurrence)(*args32)
     assert y.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     # operands rounded to 8 bits of mantissa once a product: a few 2^-8
     assert _rel(y.astype(jnp.float32), want) < 3e-2
@@ -84,10 +85,11 @@ def test_strong_decay_underflows_and_never_overflows():
     x, dt, a, b, c, d = _inputs(96, seed=4)
     dt = dt.at[:, 50].set(400.0)
     a = jnp.full((H,), -1.0)
-    y, _ = ssd.ssd_scan(x, dt, a, b, c, d, chunk=32)
-    want, _ = ssd.ssd_recurrence(x, dt, a, b, c, d)
+    y, _ = jax.jit(lambda *v: ssd.ssd_scan(*v, chunk=32))(x, dt, a, b, c, d)
+    want, _ = jax.jit(ssd.ssd_recurrence)(x, dt, a, b, c, d)
     assert bool(jnp.all(jnp.isfinite(y))) and _rel(y, want) < 5e-5
-    g = jax.grad(lambda v: ssd.ssd_scan(x, v, a, b, c, d, chunk=32)[0].sum())(dt)
+    g = jax.jit(jax.grad(
+        lambda v: ssd.ssd_scan(x, v, a, b, c, d, chunk=32)[0].sum()))(dt)
     assert bool(jnp.all(jnp.isfinite(g)))
 
 

@@ -162,20 +162,23 @@ def test_x3d_fused_knob_is_pure_lowering():
     m_off = X3D(fused="off", **kw)
     m_xla = X3D(fused="xla", **kw)
     m_pal = X3D(fused="pallas", **kw)
-    v = m_off.init(jax.random.key(0), x)
-    assert (jax.tree.structure(v)
-            == jax.tree.structure(m_xla.init(jax.random.key(0), x)))
+    v = jax.jit(m_off.init)(jax.random.key(0), x)
+    assert (jax.tree.structure(v) == jax.tree.structure(
+        jax.eval_shape(m_xla.init, jax.random.key(0), x)))
 
-    a = np.asarray(m_off.apply(v, x))
-    np.testing.assert_allclose(a, np.asarray(m_xla.apply(v, x)),
+    a = np.asarray(jax.jit(m_off.apply)(v, x))
+    np.testing.assert_allclose(a, np.asarray(jax.jit(m_xla.apply)(v, x)),
                                rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(a, np.asarray(m_pal.apply(v, x)),
+    np.testing.assert_allclose(a, np.asarray(jax.jit(m_pal.apply)(v, x)),
                                rtol=1e-4, atol=1e-4)
 
-    out0, mut0 = m_off.apply(v, x, train=True, mutable=["batch_stats"],
-                             rngs={"dropout": jax.random.key(1)})
-    out1, mut1 = m_xla.apply(v, x, train=True, mutable=["batch_stats"],
-                             rngs={"dropout": jax.random.key(1)})
+    def train(m):
+        return jax.jit(lambda vv: m.apply(
+            vv, x, train=True, mutable=["batch_stats"],
+            rngs={"dropout": jax.random.key(1)}))(v)
+
+    out0, mut0 = train(m_off)
+    out1, mut1 = train(m_xla)
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1),
                                rtol=1e-3, atol=1e-3)
     for l0, l1 in zip(jax.tree.leaves(mut0), jax.tree.leaves(mut1)):
@@ -187,8 +190,11 @@ def test_x3d_fused_knob_is_pure_lowering():
                       rngs={"dropout": jax.random.key(1)})[0]
         return jnp.sum(out ** 2)
 
-    for l0, l1 in zip(jax.tree.leaves(jax.grad(loss)(v, m_off)),
-                      jax.tree.leaves(jax.grad(loss)(v, m_xla))):
+    def grad(m):
+        return jax.jit(jax.grad(lambda vv: loss(vv, m)))(v)
+
+    for l0, l1 in zip(jax.tree.leaves(grad(m_off)),
+                      jax.tree.leaves(grad(m_xla))):
         np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),
                                    rtol=5e-3, atol=5e-3)
 
@@ -208,11 +214,11 @@ def test_csn_and_r2plus1d_fused_knob_is_pure_lowering():
                                     stem_features=8, dropout_rate=0.0))):
         m_off = cls(fused="off", **kw)
         m_on = cls(fused="xla", **kw)
-        v = m_off.init(jax.random.key(0), x)
-        assert (jax.tree.structure(v)
-                == jax.tree.structure(m_on.init(jax.random.key(0), x)))
-        np.testing.assert_allclose(np.asarray(m_off.apply(v, x)),
-                                   np.asarray(m_on.apply(v, x)),
+        v = jax.jit(m_off.init)(jax.random.key(0), x)
+        assert (jax.tree.structure(v) == jax.tree.structure(
+            jax.eval_shape(m_on.init, jax.random.key(0), x)))
+        np.testing.assert_allclose(np.asarray(jax.jit(m_off.apply)(v, x)),
+                                   np.asarray(jax.jit(m_on.apply)(v, x)),
                                    rtol=1e-4, atol=1e-4)
 
 
@@ -415,33 +421,33 @@ def test_lane_fold_matches_conv_general_dilated(fold_forced, site, policy):
             return fold_forced.unfold(fold_forced.lane_fold_conv3d(
                 x.astype(dtype), w.astype(dtype), stride, group), group)
 
-        ref = np.asarray(plain(w), np.float32)
-        got = np.asarray(folded(w), np.float32)
+        ref = np.asarray(jax.jit(plain)(w), np.float32)
+        got = np.asarray(jax.jit(folded)(w), np.float32)
         assert got.shape == ref.shape
         np.testing.assert_allclose(got, ref, rtol=tol,
                                    atol=tol * np.abs(ref).max())
         ct = _x(rng, ref.shape)
-        g_ref, g_got = (np.asarray(jax.grad(
-            lambda w: jnp.sum(f(w).astype(jnp.float32) * ct))(w))
+        g_ref, g_got = (np.asarray(jax.jit(jax.grad(
+            lambda w: jnp.sum(f(w).astype(jnp.float32) * ct)))(w))
             for f in (plain, folded))
         assert (np.linalg.norm(g_got - g_ref)
                 <= tol * np.linalg.norm(g_ref))
 
     site_mod = ConvBNAct(cout, kernel=kernel, stride=stride, dtype=dtype)
-    v = site_mod.init(jax.random.key(0), x)
+    v = jax.jit(site_mod.init)(jax.random.key(0), x)
 
-    def run(params, fold):
-        fold_forced.takes_fold = lambda: fold
-        with fold_forced.count_sites() as sites:
-            y, mut = site_mod.apply(
-                {"params": params, "batch_stats": v["batch_stats"]}, x,
-                train=True, mutable=["batch_stats"])
-        return jnp.sum(y.astype(jnp.float32) ** 2), (y, mut, sites)
+    def run(params):
+        y, mut = site_mod.apply(
+            {"params": params, "batch_stats": v["batch_stats"]}, x,
+            train=True, mutable=["batch_stats"])
+        return jnp.sum(y.astype(jnp.float32) ** 2), (y, mut)
 
     outs = {}
     for fold in (False, True):
-        (_, (y, mut, sites)), grads = jax.value_and_grad(
-            run, has_aux=True)(v["params"], fold)
+        fold_forced.takes_fold = lambda: fold
+        with fold_forced.count_sites() as sites:
+            (_, (y, mut)), grads = jax.jit(jax.value_and_grad(
+                run, has_aux=True))(v["params"])
         outs[fold] = (y, mut, grads)
         assert len(sites) == (1 if fold and group else 0)
     for a, b in zip(jax.tree.leaves(outs[False]), jax.tree.leaves(outs[True])):
@@ -450,11 +456,15 @@ def test_lane_fold_matches_conv_general_dilated(fold_forced, site, policy):
             np.testing.assert_array_equal(a, b)  # the same nn.Conv graph
         else:
             assert np.linalg.norm(a - b) <= 10 * tol * np.linalg.norm(a)
+
     # eval mode (serving): running statistics, the same lowering
+    def infer():    # a new function each time: each traces the rule anew
+        return np.asarray(jax.jit(lambda v: site_mod.apply(v, x))(v), np.float32)
+
     fold_forced.takes_fold = lambda: True
-    e1 = np.asarray(site_mod.apply(v, x), np.float32)
+    e1 = infer()
     fold_forced.takes_fold = lambda: False
-    e0 = np.asarray(site_mod.apply(v, x), np.float32)
+    e0 = infer()
     np.testing.assert_allclose(e1, e0, rtol=10 * tol,
                                atol=10 * tol * np.abs(e0).max())
 
@@ -476,25 +486,32 @@ def test_slowfast_lane_fold_is_pure_lowering(fold_forced):
     the same key), eval/train parity on the same variables, matching
     running-stat updates and gradients; both stems take it, nothing else."""
     model, pathways = _toy_slowfast()
-    v = model.init(jax.random.key(0), pathways)
+
+    def init():     # a new function each time: each traces the rule anew
+        return jax.jit(lambda: model.init(jax.random.key(0), pathways))()
+
+    v = init()
     fold_forced.takes_fold = lambda: False
-    v_off = model.init(jax.random.key(0), pathways)
+    v_off = init()
     assert jax.tree.structure(v) == jax.tree.structure(v_off)
     for a, b in zip(jax.tree.leaves(v), jax.tree.leaves(v_off)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def loss(params, fold):
+    def loss(params):
+        out, mut = model.apply(
+            {"params": params, "batch_stats": v["batch_stats"]},
+            pathways, train=True, mutable=["batch_stats"])
+        return jnp.sum(out ** 2), (out, mut)
+
+    def run(fold):
         fold_forced.takes_fold = lambda: fold
         with fold_forced.count_sites() as sites:
-            out, mut = model.apply(
-                {"params": params, "batch_stats": v["batch_stats"]},
-                pathways, train=True, mutable=["batch_stats"])
-        return jnp.sum(out ** 2), (out, mut, sites)
+            (_, (out, mut)), g = jax.jit(jax.value_and_grad(
+                loss, has_aux=True))(v["params"])
+        return out, mut, sites, g
 
-    (_, (out0, mut0, sites0)), g0 = jax.value_and_grad(
-        loss, has_aux=True)(v["params"], False)
-    (_, (out1, mut1, sites1)), g1 = jax.value_and_grad(
-        loss, has_aux=True)(v["params"], True)
+    out0, mut0, sites0, g0 = run(False)
+    out1, mut1, sites1, g1 = run(True)
     assert sites0 == set()
     assert sites1 == {("slow_stem",), ("fast_stem",)}
     np.testing.assert_allclose(np.asarray(out0), np.asarray(out1),
@@ -502,11 +519,14 @@ def test_slowfast_lane_fold_is_pure_lowering(fold_forced):
     for a, b in zip(jax.tree.leaves((mut0, g0)), jax.tree.leaves((mut1, g1))):
         a, b = np.asarray(a), np.asarray(b)
         assert np.linalg.norm(a - b) <= 1e-3 * max(np.linalg.norm(a), 1e-6)
+
+    def infer():
+        return np.asarray(jax.jit(lambda v: model.apply(v, pathways))(v))
+
     fold_forced.takes_fold = lambda: True
-    e1 = np.asarray(model.apply(v, pathways))
+    e1 = infer()
     fold_forced.takes_fold = lambda: False
-    np.testing.assert_allclose(e1, np.asarray(model.apply(v, pathways)),
-                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(e1, infer(), rtol=1e-4, atol=1e-4)
 
 
 def _lowered_toy_step(model, batch):

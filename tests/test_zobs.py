@@ -542,7 +542,7 @@ def test_health_gauges_match_hand_computed(mesh8):
     video = rng.randn(8, 4, 16, 16, 3).astype(np.float32)
     labels = rng.randint(0, 4, size=8).astype(np.int32)
     batch = {"video": video, "label": labels}
-    variables = model.init(jax.random.key(0), jnp.asarray(video))
+    variables = jax.jit(model.init)(jax.random.key(0), jnp.asarray(video))
     tx = build_optimizer(OptimConfig(lr=0.1, weight_decay=0.0),
                          total_steps=10)
     key = jax.random.key(7)
@@ -558,7 +558,7 @@ def test_health_gauges_match_hand_computed(mesh8):
         loss, _, _ = _loss_and_metrics(logits, jnp.asarray(labels), mask, 0.0)
         return loss
 
-    expected_grad_norm = float(optax.global_norm(jax.grad(loss_fn)(
+    expected_grad_norm = float(optax.global_norm(jax.jit(jax.grad(loss_fn))(
         jax.tree.map(jnp.copy, variables["params"]))))
 
     state = TrainState.create(variables["params"], variables["batch_stats"],
@@ -594,7 +594,7 @@ def test_health_gauges_absent_when_disabled(mesh8):
     model = create_model(ModelConfig(name="tiny3d", num_classes=4,
                                      dropout_rate=0.0), "fp32")
     video = np.zeros((8, 4, 16, 16, 3), np.float32)
-    variables = model.init(jax.random.key(0), jnp.asarray(video))
+    variables = jax.jit(model.init)(jax.random.key(0), jnp.asarray(video))
     tx = build_optimizer(OptimConfig(lr=0.1, weight_decay=0.0),
                          total_steps=10)
     state = TrainState.create(variables["params"], variables["batch_stats"],
