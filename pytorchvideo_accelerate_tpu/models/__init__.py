@@ -465,6 +465,51 @@ def _nemotron_h_t(cfg: ModelConfig, dtype, mesh=None):
         vocab_size=256))
 
 
+def _granite_h(cfg: ModelConfig, dtype, published: dict):
+    """A Granite-4.0-H decoder: published widths, with the depth (the first
+    layers of `layer_types`) and the vocabulary slice as the config says. It
+    has no experts to hold a share of."""
+    from pytorchvideo_accelerate_tpu.models.granite_h import GraniteH, GraniteHArch
+
+    if cfg.experts_held or cfg.expert_offset:
+        raise ValueError(
+            f"model {cfg.name!r} has no experts: model.experts_held="
+            f"{cfg.experts_held} and model.expert_offset={cfg.expert_offset} "
+            "must stay 0")
+    arch = GraniteHArch(**published)
+    layers = cfg.num_layers or arch.num_hidden_layers
+    if layers > len(arch.layer_types):
+        raise ValueError(f"model.num_layers={layers} is more than the "
+                         f"{len(arch.layer_types)} layers of layer_types")
+    arch = dataclasses.replace(arch, num_hidden_layers=layers,
+                               vocab_size=cfg.vocab_size or arch.vocab_size)
+    return GraniteH(arch, dtype=dtype, remat=True)
+
+
+@register_model("granite_4_0_h_micro", task="next_token")
+def _granite_4_0_h_micro(cfg: ModelConfig, dtype, mesh=None):
+    """Granite-4.0-H-Micro at its published widths (the defaults of
+    `GraniteHArch`: huggingface.co/ibm-granite/granite-4.0-h-micro
+    config.json). One chip holds a pipeline stage's layers and a slice of the
+    vocabulary: `--model.num_layers` (the first layers of `layer_types`),
+    `--model.vocab_size` (docs/TOKENS.md)."""
+    return _granite_h(cfg, dtype, {})
+
+
+@register_model("granite_h_t", task="next_token")
+def _granite_h_t(cfg: ModelConfig, dtype, mesh=None):
+    """Deliberately tiny Granite-4.0-H: every mechanism in the published
+    pattern's first ten layers (nine Mamba-2 mixers of 4 heads in ONE group,
+    attention at layer 5 with 4 query heads on 2 key-value heads, the SwiGLU
+    MLP after every mixer, the four multipliers, the tied table), toy widths
+    — tests and the CPU rehearsal. Not a reference architecture."""
+    return _granite_h(cfg, dtype, dict(
+        hidden_size=64, num_hidden_layers=10, num_attention_heads=4,
+        num_key_value_heads=2, shared_intermediate_size=96, mamba_n_heads=4,
+        mamba_d_head=16, mamba_d_state=16, mamba_chunk_size=32,
+        vocab_size=256))
+
+
 def available_models():
     return sorted(_REGISTRY)
 

@@ -81,12 +81,15 @@ def remat_keeping_attention(cls):
 class Attention(nn.Module):
     """Causal grouped-query attention, rotary or not, banded or not. `arch`
     gives `hidden_size`, `num_attention_heads`, `num_key_value_heads`,
-    `head_dim` and `rope_theta` (models/smallthinker.py, models/ouro.py)."""
+    `head_dim` and `rope_theta` (models/smallthinker.py, models/ouro.py).
+    `scale`: the softmax's, `head_dim ** -0.5` where None (a family with a
+    published multiplier gives its own: models/granite_h.py)."""
 
     arch: Any
     dtype: Any
     rotary: bool
     window: Optional[int]
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x):
@@ -102,8 +105,8 @@ class Attention(nn.Module):
                 q = rotate_half(q, positions, a.rope_theta, d)
                 k = rotate_half(k, positions, a.rope_theta, d)
         with jax.named_scope("core"):
-            o = causal_gqa_attention(q, k, v, scale=d ** -0.5,
-                                     window=self.window)
+            scale = d ** -0.5 if self.scale is None else self.scale
+            o = causal_gqa_attention(q, k, v, scale=scale, window=self.window)
         with jax.named_scope("out"):
             return _dense(self, "o_proj", o.reshape(b, t, hq * d),
                           a.hidden_size, dt)

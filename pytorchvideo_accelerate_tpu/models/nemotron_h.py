@@ -8,7 +8,8 @@ experts with one shared expert, `*` causal grouped-query attention. Every
 layer is `x + mixer(norm(x))`, `norm(x) = w * x * rsqrt(mean(x^2) + eps)`;
 after the last, `norm_f` and the untied head. No biases but the conv's.
 
-    M:  [z | xBC | dt] = u W_in;  xBC = silu(causal_depthwise_conv(xBC) + b)
+    M:  the Mamba-2 mixer of models/mamba2.py at `n_groups` groups:
+        [z | xBC | dt] = u W_in;  xBC = silu(causal_depthwise_conv(xBC) + b)
         = [x | B | C];  dt = softplus(dt + dt_bias);  a = -exp(A_log)
         y = ssd(x, dt, a, B, C, D)  (ops/ssd.py: head h reads group h // (H/G))
         out = (grouped_rms(y * silu(z)) * w) W_out   (n_groups groups)
@@ -41,7 +42,6 @@ Scopes of the compiled step (benchmarks/metrics read device time by them):
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -53,15 +53,12 @@ from pytorchvideo_accelerate_tpu.models.lm_common import (
     Norm,
     _dense,
     _normal,
-    causal_conv,
     held_experts,
     lm_outputs,
     remat_keeping_attention,
-    rms,
 )
+from pytorchvideo_accelerate_tpu.models.mamba2 import Mamba
 from pytorchvideo_accelerate_tpu.ops.moe import expert_share, route
-from pytorchvideo_accelerate_tpu.ops.ssd import ssd_scan
-from pytorchvideo_accelerate_tpu.precision import end_island, f32_island
 
 KINDS = "ME*"  # a Mamba-2 mixer, a mixture of experts, attention
 
@@ -105,66 +102,6 @@ class NemotronHArch:
 
 def relu_squared(x):
     return jnp.square(jax.nn.relu(x))
-
-
-def _dt_bias(arch):
-    """The Mamba-2 rule: dt log-uniform in [time_step_min, time_step_max],
-    at least time_step_floor, stored as softplus's inverse."""
-    def init(key, shape):
-        lo, hi = math.log(arch.time_step_min), math.log(arch.time_step_max)
-        dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape) * (hi - lo) + lo),
-                         arch.time_step_floor)
-        return dt + jnp.log(-jnp.expm1(-dt))
-    return init
-
-
-def _conv_init(taps):
-    bound = taps ** -0.5  # PyTorch's Conv1d default at fan-in `taps`
-    return lambda key, shape: jax.random.uniform(key, shape, minval=-bound,
-                                                 maxval=bound)
-
-
-class Mamba(nn.Module):
-    arch: NemotronHArch
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        a, dt = self.arch, self.dtype
-        b, t, _ = x.shape
-        h, p = a.mamba_num_heads, a.mamba_head_dim
-        g, n = a.n_groups, a.ssm_state_size
-        inner = h * p
-        conv_dim = inner + 2 * g * n
-        with jax.named_scope("in_proj"):
-            zxbcdt = _dense(self, "in_proj", x, inner + conv_dim + h, dt)
-            z, xbc, step = (zxbcdt[..., :inner],
-                            zxbcdt[..., inner:inner + conv_dim],
-                            zxbcdt[..., inner + conv_dim:])
-        with jax.named_scope("conv"):
-            taps = a.conv_kernel
-            w = self.param("conv", _conv_init(taps), (taps, conv_dim)).astype(dt)
-            bias = self.param("conv_bias", _conv_init(taps), (conv_dim,))
-            xbc = jax.nn.silu(causal_conv(xbc, w) + bias.astype(dt))
-        with jax.named_scope("scan"):
-            a_log = self.param(
-                "A_log", lambda key, shape: jnp.log(
-                    jnp.arange(1, shape[0] + 1, dtype=jnp.float32)), (h,))
-            dt_bias = self.param("dt_bias", _dt_bias(a), (h,))
-            skip = self.param("D", nn.initializers.ones, (h,))
-            delta = jax.nn.softplus(f32_island(step) + dt_bias)
-            y, _ = ssd_scan(xbc[..., :inner].reshape(b, t, h, p), delta,
-                            -jnp.exp(a_log),
-                            xbc[..., inner:inner + g * n].reshape(b, t, g, n),
-                            xbc[..., inner + g * n:].reshape(b, t, g, n),
-                            skip, a.chunk_size)
-        with jax.named_scope("out"):
-            # the gated norm: y * silu(z), normed over each group's columns
-            scale = self.param("norm", nn.initializers.ones, (inner,))
-            y = f32_island(y).reshape(b, t, inner) * jax.nn.silu(f32_island(z))
-            y = rms(y.reshape(b, t, g, inner // g), a.layer_norm_epsilon)
-            y = end_island(y.reshape(b, t, inner) * scale, dt)
-            return _dense(self, "out_proj", y, a.hidden_size, dt)
 
 
 class Mixture(nn.Module):
@@ -214,7 +151,12 @@ class _Layer(nn.Module):
         a = self.arch
         normed = Norm(a.layer_norm_epsilon, name="norm")(x)
         if self.kind == "M":
-            return x + Mamba(a, self.dtype, name="mamba")(normed)
+            return x + Mamba(
+                a.hidden_size, a.mamba_num_heads, a.mamba_head_dim, a.n_groups,
+                a.ssm_state_size, a.conv_kernel, a.chunk_size,
+                a.layer_norm_epsilon,
+                (a.time_step_min, a.time_step_max, a.time_step_floor),
+                self.dtype, name="mamba")(normed)
         if self.kind == "*":
             return x + Attention(a, self.dtype, rotary=False, window=None,
                                  name="attn")(normed)

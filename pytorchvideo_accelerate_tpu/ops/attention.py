@@ -84,6 +84,8 @@ _window_sites: contextvars.ContextVar = contextvars.ContextVar(
     "pva_attn_window_sites", default=None)
 _kernel_sites: contextvars.ContextVar = contextvars.ContextVar(
     "pva_attn_kernel_sites", default=None)
+_xla_sites: contextvars.ContextVar = contextvars.ContextVar(
+    "pva_attn_xla_sites", default=None)
 _keeping: contextvars.ContextVar = contextvars.ContextVar(
     "pva_attn_keeping", default=False)
 
@@ -113,6 +115,15 @@ def count_kernel_sites():
     policy keeps the forward's `o` and `lse` (`keeping_kernel_results`; the
     `pva_attn_kept_sites` gauge)."""
     return _collect(_kernel_sites)
+
+
+def count_xla_sites():
+    """The same for every `causal_gqa_attention` call that took the XLA form
+    on a TPU backend, one entry (q's shape, cause): cause "head_width" where
+    the heads are not a multiple of 128 wide, else "length" (a sequence of
+    one block). Off the TPU every call takes the XLA form and none is counted
+    (the `pva_attn_xla_sites` gauge)."""
+    return _collect(_xla_sites)
 
 
 @contextlib.contextmanager
@@ -185,6 +196,9 @@ def causal_gqa_attention(q, k, v, scale: Optional[float] = None,
             sites.append((q.shape, window, _keeping.get()))
         return pallas_attention.causal_flash_attention(
             q, k, v, scale, window, _interpret())
+    sites = _xla_sites.get()
+    if sites is not None and takes_kernel():
+        sites.append((q.shape, "head_width" if d % 128 else "length"))
     return blocked_causal_attention(q, k, v, scale, block_q, window)
 
 

@@ -1088,6 +1088,7 @@ class Trainer:
                        "obs/attn_window_sites": "pva_attn_window_sites",
                        "obs/attn_kernel_sites": "pva_attn_kernel_sites",
                        "obs/attn_kept_sites": "pva_attn_kept_sites",
+                       "obs/attn_xla_sites": "pva_attn_xla_sites",
                        "obs/ssd_scan_sites": "pva_ssd_scan_sites",
                        "obs/ut_steps": "pva_ut_steps"}
         loop_thread = threading.get_ident()

@@ -326,6 +326,7 @@ def _count_lowering_sites():
             gated_delta.count_sites() as scans, \
             attention.count_window_sites() as bands, \
             attention.count_kernel_sites() as flashes, \
+            attention.count_xla_sites() as xla_attention, \
             ssd.count_sites() as ssd_scans:
         yield
     registry = get_registry()
@@ -350,6 +351,11 @@ def _count_lowering_sites():
         "of those, the calls traced inside a remat unit that keeps the "
         "forward kernel's o and lse (models/lm_common.py)").set(
             sum(kept for _shape, _window, kept in flashes))
+    registry.gauge(
+        "pva_attn_xla_sites",
+        "causal_gqa_attention calls of the traced train step that took the "
+        "XLA form on a TPU backend: heads not a multiple of 128 wide, or one "
+        "block of tokens (ops/attention.py)").set(len(xla_attention))
     registry.gauge(
         "pva_ssd_scan_sites",
         "Mamba-2 mixers of the traced train step lowered through the "
